@@ -41,8 +41,14 @@ def main(argv=None):
         config=Config(n_partitions=args.n_partitions,
                       heartbeat_s=args.heartbeat_s,
                       sync_log=args.sync_log))
+    import jax
+
+    # a chip belongs to one process: a second member on a one-chip host
+    # shows up here on the CPU backend, and is seen for what it is
     print(f"node {args.node_id} serving on {srv.addr[0]}:{srv.addr[1]}"
-          f" (assembled={srv.node is not None})", flush=True)
+          f" (assembled={srv.node is not None}, "
+          f"backend={jax.default_backend()}, "
+          f"device_kind={jax.devices()[0].device_kind!r})", flush=True)
     stop = threading.Event()
     signal.signal(signal.SIGTERM, lambda *_a: stop.set())
     signal.signal(signal.SIGINT, lambda *_a: stop.set())

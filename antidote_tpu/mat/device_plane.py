@@ -36,7 +36,6 @@ rare host-side repack (store.orset_grow).
 
 from __future__ import annotations
 
-import atexit
 import contextlib
 import logging
 import threading
@@ -181,18 +180,16 @@ def _pack_rows(rows: List[tuple], capacity: int, d: int,
 #: programs class-wide, so one warm pass covers every partition
 _WARMED: set = set()
 _WARM_LOCK = threading.Lock()
-_WARM_THREADS: List[threading.Thread] = []
 
 
-def _join_warm_threads() -> None:
-    # a daemon thread force-unwound MID-XLA-CALL at interpreter exit
-    # aborts the process ("terminate called ... FATAL: exception not
-    # rethrown"); give in-flight warms a bounded grace period instead
-    for t in list(_WARM_THREADS):
-        t.join(timeout=5.0)
-
-
-atexit.register(_join_warm_threads)
+def _start_warm(run, name: str) -> None:
+    """Run a warm compile off the serving threads.  NOT a daemon
+    thread: one force-unwound mid-XLA-call at interpreter exit aborts
+    the process ("terminate called ... FATAL: exception not rethrown"),
+    and on the chip a compile at deployment shapes outlasts any fixed
+    grace period — so the interpreter waits for in-flight warms, which
+    are finite (one compile and one run on a copy)."""
+    threading.Thread(target=run, name=name).start()
 
 
 class _PlaneBase:
@@ -271,6 +268,9 @@ class _PlaneBase:
         #: per-shard residency router (mat/sharded.ShardRouter), wired
         #: alongside the mesh
         self._router = None
+        #: chip this plane's state is committed to under ring placement
+        #: (set by DevicePlane.place_on; None = the default device)
+        self._device = None
 
     # -- subclass hooks -----------------------------------------------------
 
@@ -358,14 +358,13 @@ class _PlaneBase:
                                    jnp.asarray(lo),
                                    *(jnp.asarray(a) for a in arrays))
                 except Exception:  # noqa: BLE001 — warm is best-effort
-                    log.debug("append warm failed", exc_info=True)
+                    # the serving path will meet the same program: a
+                    # compiler refusal must be seen here first
+                    log.warning("append warm failed (%s, bucket %d)",
+                                self.type_name, b, exc_info=True)
                     return
 
-        _WARM_THREADS[:] = [t for t in _WARM_THREADS if t.is_alive()]
-        t = threading.Thread(target=run, daemon=True,
-                             name=f"warm:{self.type_name}")
-        _WARM_THREADS.append(t)
-        t.start()
+        _start_warm(run, f"warm:{self.type_name}")
 
     def warm_reads(self, buckets: tuple = (1, 64)) -> None:
         """Background-compile this plane's READ fold at the CURRENT
@@ -412,14 +411,11 @@ class _PlaneBase:
                 try:
                     jax.block_until_ready(fn(*args))
                 except Exception:  # noqa: BLE001 — warm is best-effort
-                    log.debug("read warm failed", exc_info=True)
+                    log.warning("read warm failed (%s)", self.type_name,
+                                exc_info=True)
                     return
 
-        _WARM_THREADS[:] = [t for t in _WARM_THREADS if t.is_alive()]
-        t = threading.Thread(target=run, daemon=True,
-                             name=f"warm-read:{self.type_name}")
-        _WARM_THREADS.append(t)
-        t.start()
+        _start_warm(run, f"warm-read:{self.type_name}")
 
     def _collective_cm(self):
         """COLLECTIVE_LOCK while mesh-sharded (every dispatch on the
@@ -441,13 +437,21 @@ class _PlaneBase:
             self.st = _sharded.place_state(self._mesh, self.st)
 
     def _post_grow(self) -> None:
-        """After any capacity growth: compile the append AND read
-        programs for the new shapes off the serving threads (or, for
-        a mesh-sharded plane, re-shard the regrown arrays in place —
-        the grow rebuilt them unsharded on the default device)."""
+        """After any capacity growth: put the regrown arrays back where
+        the plane lives — a grow is a host repack that rebuilds them on
+        the DEFAULT device, uncommitted — then compile the append AND
+        read programs for the new shapes off the serving threads.  A
+        mesh-sharded plane re-shards in place (and never warms in the
+        background); a ring-placed one re-commits to its chip: left on
+        the default device, every partition's fold would name chip 0
+        as its device until the next append moved the state back, and
+        a cross-partition read in that window fuses planes of several
+        chips into one program, which JAX refuses."""
         if self._mesh is not None:
             self._reshard()
             return
+        if self._device is not None:
+            self.st = jax.device_put(self.st, self._device)
         self.warm_appends()
         self.warm_reads()
 
@@ -2607,7 +2611,9 @@ class DevicePlane:
         partition build time pins the plane for its lifetime.  RGA
         documents (dict-of-states, created lazily per document) keep
         default placement."""
-        import jax as _jax
+        def _pin(p):
+            p._device = device  # where a grow puts the state back
+            p.st = jax.device_put(p.st, device)
 
         def _place(plane):
             if isinstance(plane, MapPlane):
@@ -2615,16 +2621,16 @@ class DevicePlane:
 
                 def placed_make(tn, _orig=orig):
                     sub = _orig(tn)
-                    sub.st = _jax.device_put(sub.st, device)
+                    _pin(sub)
                     return sub
 
                 plane._make_sub = placed_make
                 for s in plane._all_planes():
-                    s.st = _jax.device_put(s.st, device)
+                    _pin(s)
             elif isinstance(plane, RgaPlane):
                 pass  # per-document dict states: lazily created
             else:
-                plane.st = _jax.device_put(plane.st, device)
+                _pin(plane)
 
         self.device = device
         for plane in self.planes.values():

@@ -43,6 +43,7 @@ correct via log replay.
 
 from __future__ import annotations
 
+import logging
 from dataclasses import dataclass, replace
 from functools import partial
 from typing import Tuple
@@ -54,6 +55,8 @@ import numpy as np
 from antidote_tpu.clocks import dense
 from antidote_tpu.mat import kernels
 from antidote_tpu.obs.prof import kernel_span
+
+log = logging.getLogger(__name__)
 
 # packed op-tensor columns (OR-Set): scalars, then obs VV, then op SS
 _ELEM, _ISADD, _DOTDC, _DOTSEQ, _OPDC, _OPCT, _NSCAL = 0, 1, 2, 3, 4, 5, 6
@@ -287,59 +290,71 @@ def orset_read(st: OrsetShardState, read_vc: jax.Array) -> jax.Array:
     return kernels.orset_present(dots)
 
 
+def _fused_requested(fused, st) -> bool:
+    """Resolve a ``fused`` selector against a shard.  "auto" takes the
+    Pallas path only where it can be honoured — a TPU backend and int32
+    rows (the kernels compute in int32, so µs-int64 live shards would
+    truncate their timestamps).  An EXPLICIT request that cannot be
+    honoured raises: quietly answering from the jnp path would let a
+    caller believe it had measured, or validated, the kernel."""
+    if fused == "auto":
+        return (jax.default_backend() == "tpu"
+                and st.ops.dtype == jnp.int32)
+    if fused and st.ops.dtype != jnp.int32:
+        raise ValueError(
+            f"fused={fused!r} needs an int32 shard (the Pallas kernels "
+            f"compute in int32); this one is {st.ops.dtype}")
+    return bool(fused)
+
+
 def orset_read_full(st: OrsetShardState, read_vc: jax.Array,
                     fused: str | bool = "auto",
-                    block_k: int | None = None) -> jax.Array:
+                    block_k: int | None = None,
+                    interpret: bool = False) -> jax.Array:
     """bool[K, E]: full-shard presence read, flag-selecting the Pallas
     fused kernel (antidote_tpu/mat/pallas_kernels.py orset_read_packed —
     one HBM pass over the packed rows, nothing but the presence block
     leaves VMEM) over the jnp reference path (:func:`orset_read`).
 
-    ``fused``: True / False / "auto" / "hybrid" (fused on a TPU backend
-    when the shard's timestamps fit int32 — the Pallas path computes in
-    int32, so µs-int64 live shards must use the jnp path; "hybrid" runs
-    the inclusion mask in XLA and only the fold in Pallas).
+    ``fused``: True / False / "auto" / "hybrid" (see
+    :func:`_fused_requested`; "hybrid" runs the inclusion mask in XLA
+    and only the fold in Pallas).  ``interpret`` runs the kernel in
+    Pallas interpret mode — what the CPU tests ask for by name; it is
+    never selected here, so off a TPU a fused request without it fails
+    in the Pallas lowering instead of being interpreted.
     """
-    if fused == "auto":
-        fused = jax.default_backend() == "tpu"
-    # the Pallas fold computes in int32; µs-int64 shards would truncate
-    # their timestamps, so even an explicit fused request falls back
-    if not fused or st.ops.dtype != jnp.int32:
+    if not _fused_requested(fused, st):
         return orset_read(st, read_vc)
     from antidote_tpu.mat import pallas_kernels
 
     K = st.dots.shape[0]
-    interpret = jax.default_backend() != "tpu"
     args = (st.dots, st.ops, st.valid, st.base_vc, st.has_base,
             read_vc.astype(st.ops.dtype))
     if fused == "hybrid":
-        fn = pallas_kernels.orset_read_hybrid
-        if block_k is not None:
-            return fn(*args, block_k=min(block_k, K),
-                      interpret=interpret)
-        return _probe_block_k(
-            fn, args,
-            ("hybrid", jax.default_backend(), st.dots.shape,
-             st.ops.shape),
-            K, interpret)
-    return pallas_kernels.orset_read_packed(
-        *args, block_k=min(block_k or 256, K), interpret=interpret)
+        fn, ladder = pallas_kernels.orset_read_hybrid, (512, 256, 128)
+    else:
+        fn, ladder = pallas_kernels.orset_read_packed, (256, 128)
+    if block_k is not None:
+        return fn(*args, block_k=min(block_k, K), interpret=interpret)
+    return _probe_block_k(
+        fn, args, (fn.__name__, interpret, st.dots.shape, st.ops.shape),
+        K, interpret, ladder)
 
 
-#: (variant, backend, shapes) -> largest block_k that compiled there
-_BLOCK_K_CACHE: dict = {}
+#: (kernel, interpret, shapes) -> the block_k that compiled there
+BLOCK_K_CHOSEN: dict = {}
 
 
-def _probe_block_k(fn, args, cache_key, K, interpret,
-                   ladder=(512, 256, 128)):
+def _probe_block_k(fn, args, cache_key, K, interpret, ladder):
     """Call ``fn(*args, block_k=..)`` with the largest block size this
     chip's scoped-VMEM budget accepts, probing the descending ladder
     once per ``cache_key`` (budgets differ per TPU generation —
-    measured on v5 lite: block_k=512 requests 26.18M against the
-    16.00M limit).  Pallas/Mosaic raises the VMEM overflow
+    measured on v5 lite: the hybrid read at block_k=512 requests 26.18M
+    against the 16.00M limit).  Pallas/Mosaic raises the VMEM overflow
     synchronously at the dispatching call, so the probe needs no
-    execution round-trip."""
-    bk = _BLOCK_K_CACHE.get(cache_key)
+    execution round-trip; any other error is raised as it is.  The
+    block chosen is logged and kept in :data:`BLOCK_K_CHOSEN`."""
+    bk = BLOCK_K_CHOSEN.get(cache_key)
     if bk is not None:
         return fn(*args, block_k=min(bk, K), interpret=interpret)
     last = None
@@ -351,43 +366,42 @@ def _probe_block_k(fn, args, cache_key, K, interpret,
                 raise
             last = e
             continue
-        _BLOCK_K_CACHE[cache_key] = bk
+        BLOCK_K_CHOSEN[cache_key] = bk
+        log.info("%s: block_k=%d compiled (K=%d)", fn.__name__, bk, K)
         return out
     raise last
 
 
 def orset_gc_full(st: OrsetShardState, gst: jax.Array,
                   fused: str | bool = "auto",
-                  block_k: int | None = None) -> OrsetShardState:
+                  block_k: int | None = None,
+                  interpret: bool = False) -> OrsetShardState:
     """:func:`orset_gc` flag-selecting the fused Pallas fold
     (pallas_kernels.orset_gc_packed — one HBM pass over the packed rows;
     the jnp path's [K, L, D] commit-VC tensor and one-hot select
     intermediates cost ~10x the pass's bandwidth floor, measured 34 ms
     vs a ~4 ms floor per GC at 1M keys on the round-5 bench chip).
 
-    Same ``fused`` contract as :func:`orset_read_full`, EXCEPT "auto"
-    resolves to the jnp path: measured on the round-5 bench chip the
-    fused fold is SLOWER (58.8 ms vs 24.5 ms at 1M keys — XLA already
-    fuses the GC chain well, and the kernel's unrolled one-hot fold is
-    VPU-bound), unlike the read where the Pallas kernel wins 2.4x.
-    Kept for explicit fused=True use on TPU generations with more
-    VMEM/VPU headroom; the kernel is equality-tested against orset_gc
-    (tests/unit/test_pallas_kernels.py).
+    Same ``fused`` / ``interpret`` contract as :func:`orset_read_full`,
+    EXCEPT "auto" resolves to the jnp path: measured on the round-5
+    bench chip the fused fold is SLOWER (58.8 ms vs 24.5 ms at 1M keys
+    — XLA already fuses the GC chain well, and the kernel's unrolled
+    one-hot fold is VPU-bound), unlike the read where the Pallas kernel
+    wins 2.4x.  Kept for explicit fused=True use on TPU generations
+    with more VMEM/VPU headroom; the kernel is equality-tested against
+    orset_gc (tests/unit/test_pallas_kernels.py).
 
     Unlike :func:`orset_gc`, ``st`` is NOT consumed on ANY path: the
-    jnp fallback runs the non-donating jit and the fused path never
+    jnp path runs the non-donating jit and the fused path never
     donated — uniform semantics regardless of the flag (the previous
     flag-dependent donation was a use-after-donate hazard: caller code
     touching st afterwards worked under fused=True and crashed — or
     silently read donated buffers — under the default)."""
-    if fused == "auto":
-        fused = False
-    if not fused or st.ops.dtype != jnp.int32:
+    if fused == "auto" or not _fused_requested(fused, st):
         return _orset_gc_nodonate(st, gst)
     from antidote_tpu.mat import pallas_kernels
 
     K = st.dots.shape[0]
-    interpret = jax.default_backend() != "tpu"
     args = (st.dots, st.ops, st.valid, gst.astype(st.ops.dtype))
     fn = pallas_kernels.orset_gc_packed
     if block_k is not None:
@@ -396,8 +410,8 @@ def orset_gc_full(st: OrsetShardState, gst: jax.Array,
     else:
         ndots, nvalid = _probe_block_k(
             fn, args,
-            ("gc", jax.default_backend(), st.dots.shape, st.ops.shape),
-            K, interpret)
+            (fn.__name__, interpret, st.dots.shape, st.ops.shape),
+            K, interpret, (512, 256, 128))
     return replace(
         st,
         dots=ndots.astype(st.dots.dtype),

@@ -6,9 +6,10 @@ METADATA there too: each partition's stable VC row (the quantity the
 reference gossips once a second, src/meta_data_sender.erl:224-255) is
 mirrored onto the partition's own chip, and the DC's stable snapshot —
 the column-wise min over partitions (src/stable_time_functions.erl:
-39-85) — is ONE sharded XLA program whose min-reduce is a cross-device
-``pmin`` riding ICI (the ShardedOrsetStore.gc_collective pattern,
-antidote_tpu/mat/sharded.py; SURVEY §7.7).
+39-85) — is ONE sharded XLA program whose min-reduce crosses the
+devices over ICI (an all-gather of the per-chip rows; the
+ShardedOrsetStore.gc_collective pattern, antidote_tpu/mat/sharded.py;
+SURVEY §7.7).
 
 The host fold (StableTimeTracker, meta/gossip.py) stays fully wired as
 the ORACLE: every row mirrored to the device is also folded on host,
@@ -151,15 +152,24 @@ class DeviceStableTimeTracker(StableTimeTracker):
             import jax.numpy as jnp
 
             m = jnp.min(blk, axis=0, keepdims=True)  # (1, D) this chip
-            # the cross-device column min — XLA lowers this to an ICI
-            # all-reduce(min) on TPU (the gossip fold as a collective)
-            return jax.lax.pmin(m, "parts")
+            # the cross-device column min.  Rows are int64 µs clocks,
+            # and the TPU compiler lowers a 64-bit all-reduce for Sum
+            # only (a pmin here is "UNIMPLEMENTED" on a v5e, found by
+            # chip_smoke.py's ring leg) — so every chip's row crosses
+            # ICI as two 32-bit words and the min is taken locally
+            hi = jax.lax.all_gather((m >> 32).astype(jnp.int32), "parts")
+            lo = jax.lax.all_gather(
+                (m & 0xFFFFFFFF).astype(jnp.uint32), "parts")
+            rows = (hi.astype(jnp.int64) << 32) | lo.astype(jnp.int64)
+            return jnp.min(rows, axis=0)
 
         from antidote_tpu.runtime import shard_map_compat
 
+        # check_vma off: every chip gathers the same rows, so the min
+        # is replicated, which the checker cannot infer of an all_gather
         fn = jax.jit(shard_map_compat(
-            local_min, mesh=self._mesh,
-            in_specs=P("parts", None), out_specs=P(None, None)))
+            local_min, mesh=self._mesh, in_specs=P("parts", None),
+            out_specs=P(None, None), check_vma=False))
         self._fold_fn = (lambda m: fn(m)[0], sharding)
 
     def _copy_dirty_locked(self):

@@ -22,6 +22,7 @@ Two CPython knobs dominate a serving process's tail and throughput:
 from __future__ import annotations
 
 import gc
+import os
 import sys
 
 _tuned = False
@@ -40,31 +41,39 @@ def tune_runtime(switch_interval_s: float = 0.0005,
     gc.set_threshold(*gc_thresholds)
 
 
-def shard_map_compat(fn, *, mesh, in_specs, out_specs, check_vma=None):
-    """``shard_map`` across jax versions: top-level ``jax.shard_map``
-    (newer releases) vs ``jax.experimental.shard_map.shard_map``
-    (<= 0.4.x), whose replication-check kwarg is ``check_rep`` where
-    the new API says ``check_vma``.  Every collective build site goes
-    through this resolver — an AttributeError here used to take the
-    whole sharded plane (and its tier-1 tests) down on 0.4.x."""
+def shard_map_compat(fn, *, mesh, in_specs, out_specs, check_vma=True):
+    """The one ``shard_map`` build site: every collective program is
+    built here, which is the name tools/concurrency_lint.py's
+    collective-lock rule follows to its launch sites."""
     import jax
 
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
-    if check_vma is not None:
-        # the replication-check kwarg was renamed across versions
-        # (check_rep -> check_vma); the flag is semantic — call sites
-        # disable a check their programs would fail — so try BOTH
-        # spellings before ever dropping it
-        for kw in ({"check_vma": check_vma}, {"check_rep": check_vma}):
-            try:
-                return sm(fn, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, **kw)
-            except TypeError:
-                continue
-    return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its
+    directory.  Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX has
+    already taken the directory from it and none is set here, so the
+    cache can be placed from outside; otherwise it lives at
+    ``<checkout>/.jax_cache`` — a fixed path, because the path is part
+    of what a later process must find again.  A live node compiles one
+    program per plane type x dispatch bucket x key capacity, most of
+    them under JAX's default one-second caching floor, so the floor is
+    dropped: a restarted node then loads its programs instead of
+    compiling them.  Called before the first plane is built
+    (Node.__init__), and by chip_smoke.py and the benches."""
+    import jax
+
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return placed
+    path = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 #: process-wide serialization of XLA programs containing COLLECTIVES:

@@ -109,7 +109,11 @@ class DeviceFlusher:
             self._thread = None
         if t is not None:
             self._q.put(None)
-            t.join(timeout=5.0)
+            # no timeout: the queued flushes are finite, and returning
+            # while one still runs would let the caller close the logs
+            # under it and the interpreter unwind this daemon thread
+            # mid-XLA-call (an abort at exit, not an exception)
+            t.join()
 
 
 #: tag marking a deferred-op entry that carries a RAW OPERATION whose
@@ -417,10 +421,26 @@ class PartitionManager:
             return pt
 
     def _stable_for_gc(self) -> VC:
-        """Throttled GC horizon; call OUTSIDE self._lock."""
+        """Throttled GC horizon; call OUTSIDE self._lock.
+
+        The source's own-DC entry is the node's min-prepared time, and
+        a transaction prepared at exactly that time may commit AT it (a
+        single-partition commit always does: its commit time is its one
+        prepare time) and still be publishing its effects one by one
+        when a fold runs.  A fold at that horizon takes the effects
+        published so far into the base and raises the base to the
+        commit time; the same transaction's remaining effects then
+        arrive "already covered" and every later read drops them (the
+        device inclusion mask, the host store's op_covered_by).  Only
+        times strictly BELOW min-prepared are stable — the exclusive
+        reading the dependency gate already gives a peer's heartbeat
+        (interdc/dep.py)."""
         now = time.monotonic()
         if now - self._stable_cached_at > _STABLE_REFRESH_S:
-            self._stable_cache = self.stable_vc_source()
+            vc = self.stable_vc_source()
+            own = vc.get_dc(self.dc_id)
+            self._stable_cache = vc.set_dc(self.dc_id, own - 1) \
+                if own else vc
             self._stable_cached_at = now
         return self._stable_cache
 

@@ -23,6 +23,7 @@ from antidote_tpu.hooks import HookRegistry
 from antidote_tpu.oplog.log import _fsync_dir
 from antidote_tpu.oplog.partition import PartitionLog
 from antidote_tpu.oplog.records import LogRecord, commit_certified
+from antidote_tpu.runtime import enable_compile_cache
 from antidote_tpu.txn.clock import HybridClock
 from antidote_tpu.txn.coordinator import Coordinator
 from antidote_tpu.txn.manager import PartitionManager
@@ -288,6 +289,15 @@ class Node:
     def __init__(self, dc_id="dc1", config: Optional[Config] = None,
                  data_dir: Optional[str] = None,
                  on_log_append: Optional[Callable] = None):
+        # before any plane is built: every program a plane compiles
+        # from here on is kept for the next start of this node.  Not on
+        # the CPU backend: XLA:CPU logs a machine-feature error for
+        # every cached program it loads, and a CPU run is a logic check
+        # whose compiles nobody waits for twice
+        import jax
+
+        if jax.default_backend() != "cpu":
+            enable_compile_cache()
         self.dc_id = dc_id
         self.config = config or Config()
         self.clock = HybridClock()

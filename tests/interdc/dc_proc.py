@@ -24,6 +24,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
 
 import jax
 
+# pinned to the CPU by design: a chip belongs to one process, so a
+# multi-process DC needs one chip per member — this is a logic check
 jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_enable_x64", True)
 

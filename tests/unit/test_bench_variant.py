@@ -1,25 +1,15 @@
-"""The headline sweep is the single source of truth shared by
-bench_device and the phase-checkpointed hardware capture — these pin
-the contract so the two can't drift apart silently."""
+"""The headline sweep's shapes, seeds and per-variant contract
+(bench.py)."""
 
+import os
 import sys
 
 import numpy as np
 
-sys.path.insert(0, "/root/repo")
-sys.path.insert(0, "/root/repo/tools")
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__)))))
 
 import bench  # noqa: E402
-import hw_capture  # noqa: E402
-
-
-def test_sweep_names_match_capture_phases():
-    sweep = bench.headline_sweep(20)
-    phase_names = {name for name, _, _, _ in hw_capture.PHASES
-                   if name.startswith("headline_")}
-    assert phase_names == {"headline_" + w for w in sweep}
-    # exactly one variant carries the read measurements
-    assert sum(1 for v in sweep.values() if v[3]) == 1
 
 
 def test_sweep_shapes():
@@ -27,16 +17,17 @@ def test_sweep_shapes():
     assert sweep["b1"][:3] == (1, 4, 20)
     assert sweep["b4"][:3] == (4, 3, 5)
     assert sweep["b8"][:3] == (8, 2, 2)
+    # exactly one variant carries the read measurements
+    assert sum(1 for v in sweep.values() if v[3]) == 1
     # quick mode keeps every variant runnable
     for c, g, n, _r, _s in bench.headline_sweep(4).values():
         assert n >= 2 and g >= 1
 
 
 def test_sweep_seeds_deterministic_and_distinct():
-    """Both capture paths (bench_device in-process, hw_phase
-    subprocess) derive their rng from the sweep's per-variant seed —
-    the seed must be stable across calls (or the 'identical stream'
-    claim is void) and distinct per variant (or coalescing levels
+    """A variant's rng comes from the sweep's per-variant seed — the
+    seed must be stable across calls (or a variant's stream depends on
+    the run length) and distinct per variant (or coalescing levels
     replay the same ops and the comparison degenerates)."""
     a = bench.headline_sweep(20)
     b = bench.headline_sweep(4)
@@ -45,7 +36,7 @@ def test_sweep_seeds_deterministic_and_distinct():
     assert seeds_a == seeds_b  # n_steps must not perturb the seed
     assert len(set(seeds_a.values())) == len(seeds_a)
     # b1 keeps the historic stream (a fresh rng(0) is what the old
-    # thread-through handed it): BENCH_r01..r04 stay comparable
+    # thread-through handed it)
     assert seeds_a["b1"] == 0
 
 

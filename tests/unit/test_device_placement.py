@@ -74,3 +74,38 @@ def test_map_subplanes_inherit_placement(placed_db):
             if getattr(sub, "st", None) is not None and \
                     jax.tree_util.tree_leaves(sub.st):
                 assert _device_of(sub.st) == devs[p % len(devs)], p
+
+
+def test_a_grow_leaves_the_state_on_its_chip(tmp_path, caplog):
+    """A capacity grow is a host repack that rebuilds the arrays on the
+    DEFAULT device.  Left there, every partition's fold names chip 0
+    until the next append moves the state back, and a cross-partition
+    read in that window fuses planes of several chips into one program
+    — JAX refuses it and the read falls back with an ERROR record
+    (found by chip_smoke.py's ring leg after a restart).  Every leaf
+    must be back on the plane's chip when the grow returns."""
+    import logging
+
+    db = AntidoteTPU(config=Config(
+        n_partitions=4, data_dir=str(tmp_path), device_placement="ring",
+        device_key_capacity=4, device_async_flush=False))
+    try:
+        devs = jax.devices()
+        # 6 keys per partition: the 5th staged key doubles the capacity
+        # of 4, and nothing is appended after it
+        keys = [(k, "counter_pn", "b") for k in range(24)]
+        cvc = db.update_objects_static(
+            None, [(k, "increment", 1) for k in keys])
+        for p, pm in enumerate(db.node.partitions):
+            plane = pm.device.planes["counter_pn"]
+            assert plane.capacity == 8
+            placed = {d for leaf in jax.tree_util.tree_leaves(plane.st)
+                      for d in leaf.devices()}
+            assert placed == {devs[p % len(devs)]}, (p, placed)
+            pm._val_cache.clear()  # the fold, not the cache
+        with caplog.at_level(logging.ERROR, logger="antidote_tpu"):
+            vals, _ = db.read_objects_static(cvc, keys)
+        assert vals == [1] * 24
+        assert not caplog.records, caplog.records[0].getMessage()
+    finally:
+        db.close()

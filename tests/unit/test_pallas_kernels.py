@@ -1,5 +1,7 @@
-"""Fused pallas OR-Set read vs the jnp kernels path (interpret mode on
-the CPU mesh; the same mosaic path runs compiled on TPU)."""
+"""Fused pallas OR-Set read vs the jnp kernels path.  Every kernel call
+here asks for interpret mode by name: the suite runs on the CPU, and
+nothing in store.py selects interpret mode on its own.  The same kernels
+compiled by Mosaic are checked on the chip by chip_smoke.py."""
 
 import numpy as np
 import jax.numpy as jnp
@@ -64,7 +66,8 @@ def test_store_integrated_fused_read(block_k):
     bulk reader uses — matches the jnp reference path."""
     st, read_vc = _filled_store()
     want = reference_read(st, read_vc)
-    got = store.orset_read_full(st, read_vc, fused=True, block_k=block_k)
+    got = store.orset_read_full(st, read_vc, fused=True, block_k=block_k,
+                                interpret=True)
     assert (np.asarray(got) == want).all()
 
 
@@ -73,7 +76,8 @@ def test_fused_read_non_divisible_block():
     dropped on the bounds-masked write (pins the padding contract)."""
     st, read_vc = _filled_store(seed=11, K=200, B=256)
     want = reference_read(st, read_vc)
-    got = store.orset_read_full(st, read_vc, fused=True, block_k=64)
+    got = store.orset_read_full(st, read_vc, fused=True, block_k=64,
+                                interpret=True)
     assert np.asarray(got).shape == want.shape
     assert (np.asarray(got) == want).all()
 
@@ -82,10 +86,7 @@ def test_auto_falls_back_for_int64_shards():
     """µs-int64 live shards must take the jnp path (int32 pallas math
     would truncate timestamps)."""
     st, read_vc = _filled_store(seed=2, K=64, B=128)
-    st64 = store.OrsetShardState(
-        dots=st.dots.astype(jnp.int64), base_vc=st.base_vc.astype(jnp.int64),
-        has_base=st.has_base, ops=st.ops.astype(jnp.int64),
-        valid=st.valid, n_lanes=st.n_lanes)
+    st64 = _int64_twin(st)
     want = reference_read(st64, read_vc.astype(jnp.int64))
     got = store.orset_read_full(st64, read_vc.astype(jnp.int64))
     assert (np.asarray(got) == want).all()
@@ -125,7 +126,7 @@ def test_hybrid_read_matches_jnp_path(block_k):
     st, read_vc = _filled_store(seed=6)
     want = reference_read(st, read_vc)
     got = store.orset_read_full(st, read_vc, fused="hybrid",
-                                block_k=block_k)
+                                block_k=block_k, interpret=True)
     assert (np.asarray(got) == want).all()
 
 
@@ -139,7 +140,7 @@ def test_gc_matches_jnp_path(seed):
     # survive (the interesting mixed case)
     gst = (np.asarray(frontier) // 2).astype(np.int32)
     got = store.orset_gc_full(st, jnp.asarray(gst), fused=True,
-                              block_k=64)
+                              block_k=64, interpret=True)
     st2, _ = _filled_store(seed=seed + 10)  # orset_gc donates its input
     want = store.orset_gc(st2, jnp.asarray(gst))
     assert (np.asarray(got.dots) == np.asarray(want.dots)).all()
@@ -153,7 +154,8 @@ def test_gc_full_reads_agree_after_fold():
     fold is transparent to materialization)."""
     st, frontier = _filled_store(seed=21)
     gst = (np.asarray(frontier) // 2).astype(np.int32)
-    b = store.orset_gc_full(st, jnp.asarray(gst), fused=True, block_k=64)
+    b = store.orset_gc_full(st, jnp.asarray(gst), fused=True, block_k=64,
+                            interpret=True)
     st2, _ = _filled_store(seed=21)      # orset_gc donates its input
     a = store.orset_gc(st2, jnp.asarray(gst))
     ra = reference_read(a, frontier)
@@ -161,33 +163,43 @@ def test_gc_full_reads_agree_after_fold():
     assert (ra == rb).all()
 
 
-def test_gc_full_int64_falls_back():
-    """µs-int64 stores must take the jnp path even when fused is
-    requested (the kernel computes in int32)."""
-    K, D, n_dcs = 64, 8, 3
-    rng = np.random.default_rng(3)
-    clock = np.zeros(n_dcs, dtype=np.int32)
-    st = store.orset_shard_init(K, n_lanes=8, n_slots=8, n_dcs=D,
-                                dtype=jnp.int64)
-    s = orset_batch(rng, K, 128, D, n_dcs, clock, obs_lag=2)
-    lane = jnp.asarray(store.batch_lane_offsets(s["key_idx"]))
-    st, _ = store.orset_append(
-        st, jnp.asarray(s["key_idx"]), lane,
-        jnp.asarray(s["elem_slot"]), jnp.asarray(s["is_add"]),
-        jnp.asarray(s["dot_dc"]), jnp.asarray(s["dot_seq"]),
-        jnp.asarray(s["obs_vv"]), jnp.asarray(s["op_dc"]),
-        jnp.asarray(s["op_ct"]), jnp.asarray(s["op_ss"]))
-    gst = jnp.asarray(s["frontier"])
-    got = store.orset_gc_full(st, gst, fused=True)   # jnp fallback path
-    # the fallback IS orset_gc, which donates st — rebuild for `want`
-    st2 = store.orset_shard_init(K, n_lanes=8, n_slots=8, n_dcs=D,
-                                 dtype=jnp.int64)
-    st2, _ = store.orset_append(
-        st2, jnp.asarray(s["key_idx"]), lane,
-        jnp.asarray(s["elem_slot"]), jnp.asarray(s["is_add"]),
-        jnp.asarray(s["dot_dc"]), jnp.asarray(s["dot_seq"]),
-        jnp.asarray(s["obs_vv"]), jnp.asarray(s["op_dc"]),
-        jnp.asarray(s["op_ct"]), jnp.asarray(s["op_ss"]))
-    want = store.orset_gc(st2, gst)
-    assert (np.asarray(got.dots) == np.asarray(want.dots)).all()
-    assert (np.asarray(got.valid) == np.asarray(want.valid)).all()
+def _int64_twin(st):
+    return store.OrsetShardState(
+        dots=st.dots.astype(jnp.int64), base_vc=st.base_vc.astype(jnp.int64),
+        has_base=st.has_base, ops=st.ops.astype(jnp.int64),
+        valid=st.valid, n_lanes=st.n_lanes)
+
+
+@pytest.mark.parametrize("fused", [True, "hybrid"])
+def test_explicit_fused_on_int64_shard_raises(fused):
+    """An explicit fused request on a µs-int64 shard cannot be honoured
+    (the kernels compute in int32): it raises, it does not quietly
+    return the jnp answer."""
+    st, read_vc = _filled_store(seed=2, K=64, B=128)
+    st64 = _int64_twin(st)
+    with pytest.raises(ValueError, match="int32"):
+        store.orset_read_full(st64, read_vc.astype(jnp.int64),
+                              fused=fused, interpret=True)
+    if fused is True:
+        with pytest.raises(ValueError, match="int32"):
+            store.orset_gc_full(st64, read_vc.astype(jnp.int64),
+                                fused=True, interpret=True)
+
+
+def test_fused_without_interpret_is_not_interpreted_off_tpu():
+    """Off a TPU an explicit fused=True without interpret=True must
+    fail in the lowering: store.py never picks interpret mode itself."""
+    st, read_vc = _filled_store(seed=2, K=64, B=128)
+    with pytest.raises(Exception, match="(?i)interpret|tpu|mosaic"):
+        np.asarray(store.orset_read_full(st, read_vc, fused=True,
+                                         block_k=64))
+
+
+def test_probe_records_the_block_that_compiled():
+    """No block_k given: the ladder's choice is visible in
+    store.BLOCK_K_CHOSEN (and logged), not hidden in the call."""
+    st, read_vc = _filled_store(seed=5, K=64, B=128)
+    store.BLOCK_K_CHOSEN.clear()
+    got = store.orset_read_full(st, read_vc, fused=True, interpret=True)
+    assert (np.asarray(got) == reference_read(st, read_vc)).all()
+    assert list(store.BLOCK_K_CHOSEN.values()) == [256]
