@@ -1,0 +1,184 @@
+"""The comparison that decides ``correct`` has to fail what is wrong:
+the control (the reference in the program's place, one guarantee
+broken), and the rest of a run driven on the CPU with the timed path
+broken underneath — an update acknowledged with the state unchanged,
+half of a transaction's updates left out, an answer altered where it is
+produced."""
+
+import json
+import os
+import time
+
+from benchmark import harness, reference
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(os.path.dirname(HERE))
+with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+    CELL = json.load(f)["workloads"][0]["name"]
+
+
+def one_window(root, monkeypatch, seed=11):
+    """Set-up and one 2 s window at a tiny size, without the look for a
+    chip; returns (cell, reading, reduced, history)."""
+    monkeypatch.setattr(harness, "WARM_MIN_PHASES", 1)
+    monkeypatch.setattr(harness, "WARM_QUIET_PHASES", 1)
+    monkeypatch.setattr(harness, "WARM_PHASE_S", 1.0)
+    cell = harness.load_cell(root, CELL)
+    dep = harness.Deployment(cell, seed)
+    try:
+        dep.open()
+        reading = dep.measure(seed, 2.0, False, time.monotonic())
+    finally:
+        dep.close()
+    reduced = harness.reduce_reading(cell, reading, dep.history)
+    return cell, reading, reduced, dep.history
+
+
+def numbers(reduced):
+    return {n: v for n, v, _c, _l in reduced["numbers"]}
+
+
+def handlers():
+    from antidote_tpu.pb import antidote_pb2 as pb
+    from antidote_tpu.pb.server import _Connection
+
+    return pb, _Connection
+
+
+def test_the_control_comes_out_as_not_correct(tiny_root, monkeypatch):
+    _cell, reading, reduced, history = one_window(tiny_root, monkeypatch)
+    sound = numbers(reduced)
+    assert sound["reads_wrong"] == 0 == sound["acks_unreadable"]
+    assert sound["snapshots_behind_session"] == 0
+    control = reference.control_numbers(history, reading["records"],
+                                        reading["readback"])
+    # far above the limit 0 on every number
+    assert control["reads_wrong"] > 10
+    assert control["acks_unreadable"] > 10
+    assert control["snapshots_behind_session"] > 100
+    broken = [(n, control.get(n, v), c, lim)
+              for n, v, c, lim in reduced["numbers"]]
+    assert reference.judge(broken) is False
+
+
+def test_an_update_acknowledged_with_the_state_unchanged(
+        tiny_root, monkeypatch):
+    pb, conn = handlers()
+    real = conn._HANDLERS[pb.ApbStaticUpdateObjects]
+
+    def unchanged(self, req):
+        del req.updates[:]
+        return real(self, req)
+
+    monkeypatch.setitem(conn._HANDLERS, pb.ApbStaticUpdateObjects,
+                        unchanged)
+    _c, _r, reduced, _h = one_window(tiny_root, monkeypatch)
+    got = numbers(reduced)
+    assert got["acks_unreadable"] > 0 and got["failed"] == 0
+    assert reference.judge(reduced["numbers"]) is False
+
+
+def test_half_of_a_transactions_updates_left_out(tiny_root, monkeypatch):
+    pb, conn = handlers()
+    real = conn._HANDLERS[pb.ApbStaticUpdateObjects]
+
+    def half(self, req):
+        del req.updates[len(req.updates) // 2:]
+        return real(self, req)
+
+    monkeypatch.setitem(conn._HANDLERS, pb.ApbStaticUpdateObjects, half)
+    _c, _r, reduced, _h = one_window(tiny_root, monkeypatch)
+    got = numbers(reduced)
+    assert got["acks_unreadable"] > 0
+    assert reference.judge(reduced["numbers"]) is False
+
+
+def test_an_answer_altered_where_it_is_produced(tiny_root, monkeypatch):
+    from antidote_tpu.api import AntidoteTPU
+
+    real = AntidoteTPU.read_objects_static
+    calls = [0]
+
+    def altered(self, clock, objects, properties=None):
+        values, vc = real(self, clock, objects, properties)
+        calls[0] += 1
+        if calls[0] % 50 == 0 and isinstance(values[0], int):
+            values = [values[0] + 1] + list(values[1:])
+        return values, vc
+
+    monkeypatch.setattr(AntidoteTPU, "read_objects_static", altered)
+    _c, _r, reduced, _h = one_window(tiny_root, monkeypatch)
+    got = numbers(reduced)
+    assert got["reads_wrong"] > 0
+    assert reference.judge(reduced["numbers"]) is False
+
+
+def test_an_answer_at_a_snapshot_behind_the_sessions_clock(
+        tiny_root, monkeypatch):
+    """A server that forgets the clock a session sends answers at an
+    older, sound snapshot: every value is right at the snapshot it
+    names, and the session may not see its own last write."""
+    from antidote_tpu.api import AntidoteTPU
+
+    real = AntidoteTPU.read_objects_static
+    calls = [0]
+
+    def forgetful(self, clock, objects, properties=None):
+        calls[0] += 1
+        if calls[0] % 20:
+            return real(self, clock, objects, properties)
+        coord, dc = self.node.coordinator, self.node.dc_id
+        sound = type(coord).snapshot_for
+
+        def a_second_ago(client_clock, props):
+            snap = sound(coord, client_clock, props)
+            return snap.set_dc(dc, snap.get_dc(dc) - 1_000_000)
+
+        coord.snapshot_for = a_second_ago
+        try:
+            return real(self, clock, objects, properties)
+        finally:
+            coord.__dict__.pop("snapshot_for", None)
+
+    monkeypatch.setattr(AntidoteTPU, "read_objects_static", forgetful)
+    _c, _r, reduced, _h = one_window(tiny_root, monkeypatch)
+    got = numbers(reduced)
+    assert got["snapshots_behind_session"] > 0
+    assert reference.judge(reduced["numbers"]) is False
+
+
+def test_an_aborted_transaction_is_sent_again_and_commits(
+        tiny_root, monkeypatch):
+    """Certification aborts one of two writers that meet on a key; the
+    client sends it again, nothing fails, and the reference, fed by
+    commit time, still says what every read must return."""
+    pb, conn = handlers()
+    real = conn._HANDLERS[pb.ApbStaticUpdateObjects]
+    calls = [0]
+
+    def aborting(self, req):
+        calls[0] += 1
+        if calls[0] % 7 == 0:
+            return pb.ApbCommitResp(
+                success=False, error="key 1 committed after snapshot")
+        return real(self, req)
+
+    monkeypatch.setitem(conn._HANDLERS, pb.ApbStaticUpdateObjects,
+                        aborting)
+    _c, _r, reduced, _h = one_window(tiny_root, monkeypatch)
+    got = numbers(reduced)
+    assert reduced["detail"]["aborts_retried"] > 0
+    assert got["failed"] == 0 == got["reads_wrong"]
+    assert got["acks_unreadable"] == 0
+
+
+def test_a_run_whose_reads_never_reach_the_device_is_not_correct(
+        tiny_root, monkeypatch):
+    # at this size the 65,536-entry value cache answers every read
+    # (PR 21's lesson): sound answers, and still not a proof
+    _c, _r, reduced, _h = one_window(tiny_root, monkeypatch)
+    got = numbers(reduced)
+    assert got["reads_wrong"] == 0 == got["acks_unreadable"]
+    assert got["device_read_dispatches"] < 5
+    if got["device_read_dispatches"] == 0:
+        assert reference.judge(reduced["numbers"]) is False
