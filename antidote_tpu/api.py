@@ -15,6 +15,7 @@ from typing import Any, List, Optional, Tuple
 from antidote_tpu.clocks import VC
 from antidote_tpu.config import Config
 from antidote_tpu.crdt import get_type
+from antidote_tpu.obs.spans import tracer
 from antidote_tpu.txn.coordinator import (  # noqa: F401 (re-exported)
     Transaction,
     TransactionAborted,
@@ -80,6 +81,10 @@ class AntidoteTPU:
         slots (a ClusterNode coordinator) and un-normalizable objects
         fall back to the interactive path, which owns that routing and
         error shape."""
+        with tracer.span("api_static_read", "api", keys=len(objects)):
+            return self._read_objects_static(clock, objects, properties)
+
+    def _read_objects_static(self, clock, objects, properties):
         node = self.node
         plan = self._static_read_plan(objects)
         if plan is None:
@@ -89,7 +94,6 @@ class AntidoteTPU:
             return values, commit_vc
         metas, by_pm = plan
         from antidote_tpu import stats
-        from antidote_tpu.obs.spans import tracer
 
         props = properties or TxnProperties()
         coord = node.coordinator
@@ -99,14 +103,16 @@ class AntidoteTPU:
         else:
             snap = coord.snapshot_for(clock, props)
         stats.registry.operations.inc(len(objects), type="read")
-        tracer.instant("static_read", "coordinator", keys=len(objects))
         # the handoff gate is held for the batch like any txn read: a
         # cutover must not swap the partitions out mid-resolve
         node.txn_gate.enter()
         try:
             from antidote_tpu.mat.serve import read_groups
 
-            values = read_groups(list(by_pm.items()), snap)
+            # a read carries no transaction: the spans below take
+            # the id of the wire request being served, when it records
+            values = read_groups(list(by_pm.items()), snap,
+                                 txid=tracer.request_id())
         except Exception as e:
             # same error class the legacy path reports for a failed
             # read (there is no transaction here to abort)
@@ -141,9 +147,10 @@ class AntidoteTPU:
                               properties: Optional[TxnProperties] = None
                               ) -> VC:
         """One-shot update transaction (reference antidote:update_objects/3)."""
-        tx = self.start_transaction(clock, properties)
-        self.update_objects(updates, tx)
-        return self.commit_transaction(tx)
+        with tracer.span("api_static_update", "api", ops=len(updates)):
+            tx = self.start_transaction(clock, properties)
+            self.update_objects(updates, tx)
+            return self.commit_transaction(tx)
 
     # ------------------------------------------------------------- inspection
 

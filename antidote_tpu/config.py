@@ -311,8 +311,8 @@ class Config:
     #: call/dispatch timing, compile-cache-miss counters, and buffer
     #: high-watermarks on every jitted mat//interdc entry point, served
     #: at /debug/prof.  Lightweight (µs of host bookkeeping per BATCH
-    #: dispatch; honest completion fetches only for sampled txns or an
-    #: open XProf capture); False turns every hook into a passthrough.
+    #: dispatch; the host never waits for the device in order to time
+    #: it); False turns every hook into a passthrough.
     kernel_profile: bool = True
     #: flight-recorder dump directory (None = <tempdir>/antidote_obs;
     #: antidote_tpu/obs/events.py)

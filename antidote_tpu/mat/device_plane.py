@@ -123,12 +123,17 @@ def fused_read(splits: list) -> list:
                                 subsystem="mat.device_plane")
         _FUSED_CACHE[fns] = fn
     count_read_dispatch()
-    outs = fn(tuple(splits[i][0][1] for i in order))
+    # the host side of one dispatch, in two spans: enqueueing the
+    # program (the thread runs) and fetching its values (the thread
+    # waits for the device)
+    with tracer.span("device_dispatch", "device", plane="fused",
+                     folds=len(splits)):
+        outs = fn(tuple(splits[i][0][1] for i in order))
+    with tracer.wait_span("device_fetch", "device", plane="fused"):
+        outs = jax.tree_util.tree_map(np.asarray, outs)
     results: list = [None] * len(splits)
     for pos, i in enumerate(order):
-        post = splits[i][1]
-        results[i] = post(
-            jax.tree_util.tree_map(np.asarray, outs[pos]))
+        results[i] = splits[i][1](outs[pos])
     return results
 
 
@@ -476,21 +481,27 @@ class _PlaneBase:
             packed = ingest.pack_rows(rows, self.capacity,
                                       self.domain.d, self._row_cols,
                                       perm)
-            with self._collective_cm():
+            with self._collective_cm(), \
+                    tracer.span("device_dispatch", "device",
+                                plane=self.type_name, rows=n):
                 self.st, overflow = ingest.packed_append(
                     self.st, jnp.asarray(packed))
             ingest.note_dispatch(
                 n, packed.nbytes,
                 replicas=(self._mesh.shape["part"]
                           if self._mesh is not None else 1))
+        else:
+            ki, lo, arrays = _pack_rows(rows, self.capacity,
+                                        self.domain.d, self._row_cols)
+            with self._collective_cm(), \
+                    tracer.span("device_dispatch", "device",
+                                plane=self.type_name, rows=n):
+                self.st, overflow = type(self)._append_fn(
+                    self.st, jnp.asarray(ki), jnp.asarray(lo),
+                    *(jnp.asarray(a) for a in arrays))
+        with tracer.wait_span("device_fetch", "device",
+                              plane=self.type_name):
             return np.asarray(overflow)[:n]
-        ki, lo, arrays = _pack_rows(rows, self.capacity, self.domain.d,
-                                    self._row_cols)
-        with self._collective_cm():
-            self.st, overflow = type(self)._append_fn(
-                self.st, jnp.asarray(ki), jnp.asarray(lo),
-                *(jnp.asarray(a) for a in arrays))
-        return np.asarray(overflow)[:n]
 
     def _purge_idx(self, idx: int) -> None:
         raise NotImplementedError
@@ -626,12 +637,15 @@ class _PlaneBase:
         owned = [k for k in keys if k in self.key_index]
         if not owned:
             return dict
-        rv = self._read_vc_dense(read_vc)
-        idxs = np.asarray([self.key_index[k] for k in owned],
-                          dtype=np.int32)
-        pad = np.zeros(_bucket(len(idxs)), dtype=np.int32)
-        pad[:len(idxs)] = idxs
-        return self._many_reader(self.st, owned, idxs, pad, rv)
+        # argument preparation: indices and snapshot to device arrays
+        with tracer.span("device_prepare", "device",
+                         plane=self.type_name, keys=len(owned)):
+            rv = self._read_vc_dense(read_vc)
+            idxs = np.asarray([self.key_index[k] for k in owned],
+                              dtype=np.int32)
+            pad = np.zeros(_bucket(len(idxs)), dtype=np.int32)
+            pad[:len(idxs)] = idxs
+            return self._many_reader(self.st, owned, idxs, pad, rv)
 
     def _many_split(self, st, owned: list, idxs: np.ndarray,
                     pad: np.ndarray, rv):
@@ -658,8 +672,12 @@ class _PlaneBase:
         def run():
             count_read_dispatch()
             with self._collective_cm():
-                out = fn(*args)
-                out = jax.tree_util.tree_map(np.asarray, out)
+                with tracer.span("device_dispatch", "device",
+                                 plane=self.type_name):
+                    out = fn(*args)
+                with tracer.wait_span("device_fetch", "device",
+                                      plane=self.type_name):
+                    out = jax.tree_util.tree_map(np.asarray, out)
             return post(out)
 
         run.split = (spec, post)
@@ -846,9 +864,8 @@ class _PlaneBase:
         # the span and histogram cover the overflow-retry path too —
         # the forced GC + second append (possibly a fresh XLA compile)
         # dominate exactly the flushes the stage-latency panel hunts
-        with prof.annotate(f"device_flush:{self.type_name}"), \
-                tracer.span(f"device_flush:{self.type_name}", "device",
-                            rows=len(rows)):
+        with tracer.span(f"device_flush:{self.type_name}", "device",
+                         rows=len(rows)):
             for i in range(0, len(rows), step):
                 overflow[i:i + step] = self._append_rows(
                     rows[i:i + step])
@@ -923,8 +940,7 @@ class _PlaneBase:
         pairs = self._ss_pairs(stable_vc)
         if pairs is None:
             return
-        with prof.annotate(f"device_gc:{self.type_name}"), \
-                tracer.span(f"device_gc:{self.type_name}", "device"):
+        with tracer.span(f"device_gc:{self.type_name}", "device"):
             self._run_device_gc(self._dense_vc(pairs))
         self._reshard()
         if self._router is not None:

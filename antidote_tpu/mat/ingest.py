@@ -25,10 +25,13 @@ to the shard stores:
   the benches' ``gc_every``) — append cadence and fold cadence are
   deliberately decoupled, the reference's amortized ``?OPS_THRESHOLD``
   recipe.
-- **Honest completion.**  :func:`packed_append` is ``@kernel_span``
-  (antidote_tpu/obs/prof.py), so sampled-txn completion is measured by
-  the profiler's scalar device->host fetch, the same barrier the
-  benches use — a dispatch-only timing measures the enqueue.
+- **Timing.**  :func:`packed_append` is ``@kernel_span``
+  (antidote_tpu/obs/prof.py): the host's dispatch time is recorded per
+  call and says so; the scatter's device time is a profiler capture's
+  (the device plane, by program name).  The flush that calls it is the
+  ``device_flush:<type>`` span, its dispatch and its fetch of the
+  overflow mask the ``device_dispatch`` / ``device_fetch`` spans
+  beneath (mat/device_plane.py).
 
 ``ingest_from_config`` is the ONE factory every assembly must route
 through (DevicePlane and mat/sharded.py both take its settings), so a

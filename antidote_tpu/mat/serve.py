@@ -112,7 +112,7 @@ class _Waiter:
     convoys unrelated readers behind one blocked snapshot."""
 
     __slots__ = ("items", "vc", "txid", "done", "values", "error",
-                 "solo")
+                 "solo", "stamp")
 
     def __init__(self, items, vc, txid):
         self.items: List[Tuple[Any, str]] = [tuple(i) for i in items]
@@ -122,6 +122,9 @@ class _Waiter:
         self.values: Optional[Dict] = None
         self.error: Optional[BaseException] = None
         self.solo = False
+        #: when the staging call records: where its wait for a drain
+        #: began (Tracer.stamp); the drain that takes it closes it
+        self.stamp = tracer.stamp()
 
 
 def _vc_key(vc: VC) -> tuple:
@@ -189,11 +192,17 @@ class ReadServer:
         while True:
             lead = False
             with self._cond:
-                while not w.done and self._leading:
-                    remaining = deadline - time.monotonic()
-                    if remaining <= 0:
-                        break
-                    self._cond.wait(min(remaining, 0.1))
+                if not w.done and self._leading:
+                    # a follower: asleep until the drain in flight (or
+                    # the next, which takes it) has served it
+                    with tracer.wait_span("read_serve_wait", "serve",
+                                          txid=w.txid,
+                                          partition=self._pm.partition):
+                        while not w.done and self._leading:
+                            remaining = deadline - time.monotonic()
+                            if remaining <= 0:
+                                break
+                            self._cond.wait(min(remaining, 0.1))
                 if w.done:
                     break
                 if time.monotonic() >= deadline:
@@ -246,12 +255,16 @@ class ReadServer:
                 deadline = self._open_since + s.coalesce_us / 1e6
                 # hold only while there is company: a solo reader pays
                 # zero added latency, a burst is served by one fold
-                while (len(self._staged) > 1
-                       and self._staged_keys < s.key_budget):
-                    remaining = deadline - time.monotonic()
-                    if remaining <= 0:
-                        break
-                    self._cond.wait(remaining)
+                if (len(self._staged) > 1
+                        and self._staged_keys < s.key_budget):
+                    with tracer.wait_span("read_serve_hold", "serve",
+                                          partition=self._pm.partition):
+                        while (len(self._staged) > 1
+                               and self._staged_keys < s.key_budget):
+                            remaining = deadline - time.monotonic()
+                            if remaining <= 0:
+                                break
+                            self._cond.wait(remaining)
             batch, self._staged = self._staged, []
             self._staged_keys = 0
             self._open_since = None
@@ -273,6 +286,13 @@ class ReadServer:
         plane reports the SAME mesh, so the whole drain is one
         multi-chip program (the config18 bench's O(1) gate)."""
         try:
+            for w in batch:
+                if w.stamp is not None:
+                    # staged until this drain took it, on the waiter's
+                    # own thread and under its own request
+                    tracer.close_stamp(w.stamp, "read_serve_queue_wait",
+                                       "serve", txid=w.txid,
+                                       partition=self._pm.partition)
             n_keys = sum(len(w.items) for w in batch)
             # a solo drain is unambiguously that waiter's work: carry
             # its txid so the fold's kernel child-spans keep joining
@@ -283,7 +303,9 @@ class ReadServer:
             with tracer.span("read_serve_drain", "device",
                              txid=span_txid, waiters=len(batch),
                              keys=n_keys, partition=self._pm.partition):
-                groups, solos = self._classify(batch)
+                with tracer.span("read_serve_classify", "serve",
+                                 waiters=len(batch)):
+                    groups, solos = self._classify(batch)
                 if solos:
                     # release the blocked snapshots to their own
                     # threads BEFORE folding, so they wait out their
@@ -351,7 +373,7 @@ class ReadServer:
         pm = self._pm
         fr_map: Dict[Any, Any] = {}
         blocked = set()
-        with pm._lock:
+        with pm._locked:
             for w in batch:
                 for key, _t in w.items:
                     if key not in fr_map:
@@ -569,7 +591,7 @@ class ReadServer:
             # frontier-identity revalidation: a publish between the
             # classify snapshot and the fold capture may have put an
             # op beyond a waiter's snapshot into the group fold
-            with pm._lock:
+            with pm._locked:
                 for w in waiters:
                     if any(pm.key_frontier.get(k) is not fr_map[k]
                            for k, _t in w.items):
@@ -629,7 +651,9 @@ def read_groups(groups, snapshot_vc, txid=None) -> Dict:
                 with rs._cond:
                     rs._direct += 1
             try:
-                return read_many_fused(groups, snapshot_vc, txid)
+                with tracer.span("read_serve_direct", "device",
+                                 txid=txid, partitions=len(pairs)):
+                    return read_many_fused(groups, snapshot_vc, txid)
             finally:
                 for _pm, _i, rs in pairs:
                     with rs._cond:

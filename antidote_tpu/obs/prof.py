@@ -11,11 +11,13 @@ of always-on, sampled production profiling:
 - **Kernel spans** — every jitted entry point of the materializer,
   sharded store, and dependency gate is wrapped (``@kernel_span`` at
   the definition, or :meth:`DeviceProfiler.wrap` around dynamically
-  built jits).  Each call records dispatch wall time; when the call
-  runs under a *sampled* txn span (obs/spans.py) or an active capture,
-  completion is also measured — a scalar device→host fetch, the
-  benches/_util.py completion barrier — and a ``kernel:*`` child-span
-  joins the transaction's trace tree.
+  built jits).  Each call records dispatch wall time — the host's
+  time to enqueue the program, never the device's time to run it —
+  and, when it runs under a recorded span (obs/spans.py) or an open
+  capture, a ``kernel:*`` child-span of that dispatch time joins the
+  request's trace tree.  The host never waits for the device in order
+  to time it: a kernel's device time is the profiler's (the device
+  plane of a capture, by program name).
 - **Compile-cache-miss counters** — keyed by function + abstract shape
   signature (shapes/dtypes of array leaves, values of static scalars),
   so a recompilation storm is attributable to the kernel and shape
@@ -26,18 +28,21 @@ of always-on, sampled production profiling:
   co-reside; the global ``jax.live_arrays()`` census in
   :meth:`DeviceProfiler.snapshot`, served by stats.py's
   ``/debug/prof``, is the total).
-- **Capture unification** — when an XProf window is open
-  (:func:`profile`/:func:`start`), every wrapped kernel call is
-  additionally bracketed by a ``jax.profiler.TraceAnnotation`` carrying
-  the kernel name and the active txid, so the device timeline reads
-  "kernel:orset_read_keys[txid=...]" instead of anonymous XLA modules.
+- **Capture unification** — :func:`start` is the one door to a
+  profiler capture.  While it is open the span tracer records every
+  span and holds a ``jax.profiler.TraceAnnotation`` around each
+  thread's innermost work span (obs/spans.py), wrapped kernel calls
+  among them, so the ``.xplane.pb`` names the program's stages beside
+  the device plane and on its clock.  :func:`stop` keeps the spans of
+  the capture; :func:`last_capture` reduces them to one summary
+  (per-name totals and self times, per-kind request totals,
+  ``host_busy_s``).
 
 Cost discipline: with ``profiler.enabled`` False every hook is a single
 attribute check + passthrough (no tree flattening, no jnp ops, zero
 new compile-cache entries — tests/unit/test_obs_prof.py pins this).
 Enabled (the default), the per-call cost is a few µs of host
-bookkeeping on *batch-level* dispatches; the completion fetch happens
-only for sampled txns, ``detail`` mode, or open captures.  Calls made
+bookkeeping on *batch-level* dispatches.  Calls made
 while a jit trace is being staged (a wrapped store fn composed into
 fused_read / shard_map bodies) pass straight through — timing a trace
 would record compilation, not execution.
@@ -51,13 +56,17 @@ import threading
 import time
 from typing import Any, Dict, Optional
 
-from antidote_tpu.obs.spans import tracer
+from antidote_tpu.obs.spans import summarize, tracer
 
 # ------------------------------------------------------------------ capture
 # (one capture at a time, mirroring jax.profiler's own constraint)
 
 _capture_lock = threading.Lock()
 _active_dir: Optional[str] = None
+#: the last capture's spans as Tracer.capture_end left them, and their
+#: summary once last_capture() has been asked for it
+_last_raw: Optional[Dict[str, Any]] = None
+_last_summary: Optional[Dict[str, Any]] = None
 
 
 def annotate(name: str):
@@ -81,8 +90,8 @@ def profile(log_dir: str):
 
 def start(log_dir: str) -> None:
     """Begin a capture (idempotent per process: one capture at a time).
-    While the window is open, wrapped kernel calls auto-annotate the
-    device timeline with their name and active txid."""
+    While the window is open the span tracer records every span and
+    annotates the profiler's timeline with the work spans' names."""
     global _active_dir
     import jax
 
@@ -92,16 +101,21 @@ def start(log_dir: str) -> None:
                 f"profiler already capturing to {_active_dir}")
         jax.profiler.start_trace(log_dir)
         _active_dir = log_dir
+        tracer.capture_begin(jax.profiler.TraceAnnotation)
 
 
 def stop() -> str:
-    """End the capture; returns the trace directory."""
-    global _active_dir
+    """End the capture; returns the trace directory.  The spans
+    recorded since :func:`start` are kept for :func:`last_capture`."""
+    global _active_dir, _last_raw, _last_summary
     import jax
 
     with _capture_lock:
         if _active_dir is None:
             raise RuntimeError("no profiler capture active")
+        # the spans first: writing the trace out takes seconds, which
+        # are not the capture's
+        _last_raw, _last_summary = tracer.capture_end(), None
         jax.profiler.stop_trace()
         out, _active_dir = _active_dir, None
         return out
@@ -109,6 +123,18 @@ def stop() -> str:
 
 def active_dir() -> Optional[str]:
     return _active_dir
+
+
+def last_capture() -> Optional[Dict[str, Any]]:
+    """The summary of the last finished capture's spans
+    (``spans.summarize``), or None when this process has finished
+    none.  Reduced on first demand, not inside :func:`stop`, which
+    runs while the capture's traffic is still being served."""
+    global _last_summary
+    with _capture_lock:
+        if _last_summary is None and _last_raw is not None:
+            _last_summary = summarize(**_last_raw)
+        return _last_summary
 
 
 # --------------------------------------------------------- kernel-span layer
@@ -133,26 +159,6 @@ def _sig(leaves: list) -> tuple:
     return tuple(out)
 
 
-def _force(out) -> bool:
-    """Completion barrier: device→host fetch of ONE scalar of the
-    result (benches/_util.py fetch), which cannot return before the
-    program that produces it has run.  Returns False when the result
-    holds no fetchable array (pure-host outputs)."""
-    import jax
-    import numpy as np
-
-    for leaf in jax.tree_util.tree_leaves(out):
-        shape = getattr(leaf, "shape", None)
-        if shape is None or not hasattr(leaf, "dtype"):
-            continue
-        if any(s == 0 for s in shape):
-            continue
-        idx = tuple(0 for _ in shape)
-        np.asarray(leaf[idx] if shape else leaf)
-        return True
-    return False
-
-
 def _nbytes(out) -> int:
     import jax
 
@@ -175,16 +181,13 @@ class _KernelStat:
     """Aggregate for one wrapped kernel (mutated under the profiler
     lock; snapshot() copies the scalars out)."""
 
-    __slots__ = ("subsystem", "calls", "dispatch_s", "complete_s",
-                 "completions", "compile_misses", "shapes",
-                 "bytes_out_hwm", "last_call_us")
+    __slots__ = ("subsystem", "calls", "dispatch_s", "compile_misses",
+                 "shapes", "bytes_out_hwm", "last_call_us")
 
     def __init__(self, subsystem: str):
         self.subsystem = subsystem
         self.calls = 0
         self.dispatch_s = 0.0
-        self.complete_s = 0.0
-        self.completions = 0
         self.compile_misses = 0
         self.shapes: set = set()
         self.bytes_out_hwm = 0
@@ -199,21 +202,15 @@ class DeviceProfiler:
         #: master switch — False makes every wrapped call a bare
         #: passthrough (Config.kernel_profile via obs.configure)
         self.enabled = True
-        #: honest completion fetch on EVERY call, not just sampled
-        #: ones — bench/diagnosis mode, too heavy for serving
-        self.detail = False
         self._stats: Dict[str, _KernelStat] = {}
         self._subsys_hwm: Dict[str, int] = {}
         self._lock = threading.Lock()
 
     # -------------------------------------------------------- configuration
 
-    def configure(self, enabled: Optional[bool] = None,
-                  detail: Optional[bool] = None) -> None:
+    def configure(self, enabled: Optional[bool] = None) -> None:
         if enabled is not None:
             self.enabled = bool(enabled)
-        if detail is not None:
-            self.detail = bool(detail)
 
     def reset(self) -> None:
         """Drop all aggregates (test isolation)."""
@@ -278,33 +275,22 @@ class DeviceProfiler:
                     st.shapes.add(sig)
                     st.compile_misses += 1
                     reg.kernel_compile_misses.inc(kernel=kname)
-        cur = tracer.current()
-        cap = _active_dir is not None
         t0_us = time.time_ns() // 1000
         t0 = time.perf_counter()
-        if cap:
-            label = f"kernel:{kname}"
-            if cur is not None and cur.txid is not None:
-                label += f"[txid={cur.txid!r}]"
-            with annotate(label):
+        if tracer.current() is not None or tracer.capturing:
+            # a child of the stage that dispatched it; its duration is
+            # the host's dispatch, as ``timing`` says
+            with tracer.span(f"kernel:{kname}", "kernel",
+                             subsystem=subsystem, timing="dispatch"):
                 out = fn(*args, **kwargs)
         else:
             out = fn(*args, **kwargs)
         dispatch = time.perf_counter() - t0
-        dur = dispatch
-        completed = False
-        if cur is not None or cap or self.detail:
-            completed = _force(out)
-            if completed:
-                dur = time.perf_counter() - t0
         nb = _nbytes(out)
         with self._lock:
             st.calls += 1
             st.dispatch_s += dispatch
             st.last_call_us = t0_us
-            if completed:
-                st.completions += 1
-                st.complete_s += dur
             if nb > st.bytes_out_hwm:
                 st.bytes_out_hwm = nb
             if nb > self._subsys_hwm.get(subsystem, 0):
@@ -312,13 +298,6 @@ class DeviceProfiler:
                 reg.device_buffer_hwm.set(nb, subsystem=subsystem)
         reg.kernel_calls.inc(kernel=kname, subsystem=subsystem)
         reg.kernel_dispatch_latency.observe(dispatch)
-        if completed:
-            reg.kernel_complete_latency.observe(dur)
-        if cur is not None:
-            tracer.record_span(
-                f"kernel:{kname}", "kernel", cur.txid, t0_us,
-                int(dur * 1e6), parent_id=cur.span_id,
-                subsystem=subsystem, complete=completed)
         return out
 
     # -------------------------------------------------------------- queries
@@ -334,10 +313,6 @@ class DeviceProfiler:
                     "dispatch_total_s": round(st.dispatch_s, 6),
                     "dispatch_mean_s": round(
                         st.dispatch_s / st.calls, 9) if st.calls else 0.0,
-                    "completions": st.completions,
-                    "complete_mean_s": round(
-                        st.complete_s / st.completions, 9)
-                    if st.completions else None,
                     "bytes_out_hwm": st.bytes_out_hwm,
                     "last_call_us": st.last_call_us,
                 }
@@ -346,7 +321,6 @@ class DeviceProfiler:
             subsys = dict(self._subsys_hwm)
         return {
             "enabled": self.enabled,
-            "detail": self.detail,
             "capture_dir": _active_dir,
             "kernels": kernels,
             "subsystem_bytes_hwm": subsys,

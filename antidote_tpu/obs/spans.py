@@ -18,6 +18,28 @@ enough background context around the per-txn trees without letting a
 hot untagged path flood the ring — and recorded on every call only
 when the rate is 1.0.
 
+A span is **work** (its thread runs), **wait** (its thread sleeps on a
+lock, a condition or the device) or a request's **root**
+(:meth:`Tracer.root`: one wire message, frame read to answer sent).
+Every span under a root carries the root's request id in ``req``, and
+a span opened without a txid inherits its parent's, so one served
+request is one tree under one identifier.  Only the outermost site
+decides: a call chain that is being traced records all of its spans,
+and under a root that declined no untagged site records (a
+transaction's own spans still follow its txid, which every DC
+agrees on).
+
+While a profiler capture is open (``obs.prof.start`` calls
+:meth:`Tracer.capture_begin`) every span records, and the innermost
+open *work* span of each thread holds a ``jax.profiler.
+TraceAnnotation`` of its name: a child's entry closes its parent's
+annotation and its exit opens it again, so the ``.xplane.pb`` holds
+one flat run of self-time segments per thread, beside the device
+plane and on its clock.  Wait spans and roots are never annotated: a
+thread asleep under a device gap did not cause it.
+:func:`summarize` reduces a capture's spans to per-name totals, self
+times and ``host_busy_s``.
+
 Export is Chrome ``trace_event`` JSON ("X" complete events), loadable
 in Perfetto / chrome://tracing next to the JAX profiler captures
 (antidote_tpu/obs/prof.py); ``ts`` is epoch microseconds so captures
@@ -48,11 +70,11 @@ class Span:
     """One finished span (immutable once in the ring)."""
 
     __slots__ = ("span_id", "parent_id", "name", "cat", "txid",
-                 "start_us", "dur_us", "tid", "args")
+                 "start_us", "dur_us", "tid", "args", "kind", "req")
 
     def __init__(self, span_id: int, parent_id: Optional[int], name: str,
                  cat: str, txid, start_us: int, dur_us: int, tid: int,
-                 args: Dict[str, Any]):
+                 args: Dict[str, Any], kind: str = "work", req=None):
         self.span_id = span_id
         self.parent_id = parent_id
         self.name = name
@@ -62,6 +84,10 @@ class Span:
         self.dur_us = dur_us
         self.tid = tid
         self.args = args
+        #: "work", "wait" or "root" (module docstring)
+        self.kind = kind
+        #: the request id of the root this span was recorded under
+        self.req = req
 
     def __repr__(self) -> str:  # test/debug ergonomics
         return (f"Span({self.name!r}, cat={self.cat!r}, "
@@ -71,6 +97,10 @@ class Span:
         args = {k: _jsonable(v) for k, v in self.args.items()}
         if self.txid is not None:
             args["txid"] = _jsonable(self.txid)
+        if self.req is not None:
+            args["req"] = _jsonable(self.req)
+        if self.kind != "work":
+            args["kind"] = self.kind
         return {"name": self.name, "cat": self.cat, "ph": "X",
                 "ts": self.start_us, "dur": self.dur_us,
                 "pid": os.getpid(), "tid": self.tid, "args": args}
@@ -109,20 +139,57 @@ class _NullSpan:
 _NULL = _NullSpan()
 
 
+class _Declined:
+    """What :meth:`Tracer.root` hands a request that does not record:
+    until it exits, the thread's untagged sites are null contexts
+    without a sampling decision each, so a request records whole or
+    not at all and an unrecorded one pays two attribute reads a site.
+    Shared, like ``_NULL``; the count lives on the thread."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        _tls.declined = getattr(_tls, "declined", 0) + 1
+        return None
+
+    def __exit__(self, *exc):
+        _tls.declined -= 1
+        return False
+
+
+_DECLINED = _Declined()
+
+
 class _LiveSpan:
     """Open span: context manager pushing itself on the thread's stack."""
 
-    __slots__ = ("_tracer", "name", "cat", "txid", "args",
-                 "_start_ns", "_parent", "span_id")
+    __slots__ = ("_tracer", "name", "cat", "txid", "args", "kind", "req",
+                 "_start_ns", "_parent", "_ann", "span_id")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str, txid,
-                 args: Dict[str, Any]):
+                 args: Dict[str, Any], kind: str = "work"):
         self._tracer = tracer
         self.name = name
         self.cat = cat
         self.txid = txid
         self.args = args
+        self.kind = kind
+        self.req = txid if kind == "root" else None
+        self._ann = None
         self.span_id = next(_SPAN_IDS)
+
+    def _annotate(self, on: bool) -> None:
+        """Open or close this span's profiler annotation (work spans
+        inside a capture only; module docstring)."""
+        if not on:
+            if self._ann is not None:
+                self._ann.__exit__(None, None, None)
+                self._ann = None
+            return
+        factory = self._tracer._annotation
+        if factory is not None and self.kind == "work":
+            self._ann = factory(self.name)
+            self._ann.__enter__()
 
     def __enter__(self):
         stack = getattr(_tls, "stack", None)
@@ -132,20 +199,36 @@ class _LiveSpan:
         # kernel-span layer (obs/prof.py) reads the innermost open
         # span's txid/span_id via Tracer.current to attach device
         # kernels to the active txn's tree
-        self._parent = stack[-1].span_id if stack else None
+        parent = stack[-1] if stack else None
+        self._parent = parent
+        if parent is not None:
+            if self.req is None:
+                self.req = parent.req
+            if self.txid is None:
+                self.txid = parent.txid
+            parent._annotate(False)
         stack.append(self)
+        self._annotate(True)
         self._start_ns = time.time_ns()
         return self
 
     def __exit__(self, *exc):
-        dur_us = (time.time_ns() - self._start_ns) // 1000
+        end_ns = time.time_ns()
+        self._annotate(False)
         stack = getattr(_tls, "stack", None)
         if stack and stack[-1] is self:
             stack.pop()
+        parent = self._parent
+        if parent is not None:
+            parent._annotate(True)
+        # both ends floored to whole microseconds of the same clock, so
+        # spans nested or in sequence stay so in what is recorded
+        start_us = self._start_ns // 1000
         self._tracer._add(Span(
-            self.span_id, self._parent, self.name, self.cat, self.txid,
-            self._start_ns // 1000, dur_us, threading.get_ident(),
-            self.args))
+            self.span_id, parent.span_id if parent is not None else None,
+            self.name, self.cat, self.txid, start_us,
+            end_ns // 1000 - start_us, threading.get_ident(),
+            self.args, self.kind, self.req))
         return False
 
 
@@ -165,6 +248,13 @@ class Tracer:
         self._capacity = capacity
         self._spans: deque = deque(maxlen=capacity)
         self._lock = threading.Lock()
+        #: spans ever added: a capture's drops are what it added beyond
+        #: the ring's capacity
+        self._added = 0
+        #: set while a profiler capture is open (capture_begin): the
+        #: factory of the annotation a work span holds in the trace
+        self._annotation = None
+        self._capture_from = (0, 0)  # (epoch us, _added) at its start
 
     @property
     def sample_rate(self) -> float:
@@ -262,24 +352,59 @@ class Tracer:
 
     # ------------------------------------------------------------ recording
 
+    def _records(self, txid) -> bool:
+        """One site's decision: everything inside a capture, everything
+        under a span that is already being recorded (the outermost site
+        decided for the chain), nothing untagged under a request
+        that does not record, else the sampling rule."""
+        if self._annotation is not None or getattr(_tls, "stack", None):
+            return True
+        if txid is None and getattr(_tls, "declined", 0):
+            return False  # its request's root decided (_Declined)
+        return self.sampled(txid)
+
     def span(self, name: str, cat: str = "host", txid=None, **args):
-        """Context manager timing the enclosed block; no-op (shared
-        null object) when the txid is unsampled or tracing is off."""
-        if not self.sampled(txid):
+        """Context manager timing the enclosed block, in which the
+        thread runs; no-op (shared null object) when the txid is
+        unsampled or tracing is off."""
+        if not self._records(txid):
             return _NULL
         return _LiveSpan(self, name, cat, txid, args)
+
+    def wait_span(self, name: str, cat: str = "host", txid=None,
+                  **args):
+        """:meth:`span` for a block in which the thread sleeps: on a
+        lock, a condition, or the device."""
+        if not self._records(txid):
+            return _NULL
+        return _LiveSpan(self, name, cat, txid, args, "wait")
+
+    def root(self, name: str, cat: str, *request_id, **args):
+        """:meth:`span` for one served request: ``request_id`` (the
+        wire server's connection and message serials) becomes the
+        span's txid and the ``req`` of everything recorded under it.
+        Thinned like an untagged span, and the id is only built when
+        the request records."""
+        if not self._records(None):
+            return _DECLINED
+        return _LiveSpan(self, name, cat, ("req",) + request_id, args,
+                         "root")
 
     def instant(self, name: str, cat: str = "host", txid=None,
                 **args) -> None:
         """Zero-duration span — a point event on the trace timeline
         (device stage, txn abort); same sampling rule as :meth:`span`."""
-        if not self.sampled(txid):
+        if not self._records(txid):
             return
         stack = getattr(_tls, "stack", None)
+        top = stack[-1] if stack else None
+        if top is not None and txid is None:
+            txid = top.txid
         self._add(Span(
-            next(_SPAN_IDS), stack[-1].span_id if stack else None, name,
-            cat, txid, time.time_ns() // 1000, 0, threading.get_ident(),
-            args))
+            next(_SPAN_IDS), top.span_id if top is not None else None,
+            name, cat, txid, time.time_ns() // 1000, 0,
+            threading.get_ident(), args,
+            req=top.req if top is not None else None))
 
     def current(self):
         """The calling thread's innermost OPEN span, or None.  Only
@@ -287,26 +412,89 @@ class Tracer:
         stack (unsampled sites get the shared null context), so a
         non-None result means "this call chain is being traced" — the
         hook the kernel-span layer (obs/prof.py) uses to decide whether
-        to time completion and attach a kernel child-span."""
+        to attach a kernel child-span."""
         stack = getattr(_tls, "stack", None)
         return stack[-1] if stack else None
 
+    def request_id(self):
+        """The id of the request the calling thread is serving, when
+        that request is being recorded; else None."""
+        stack = getattr(_tls, "stack", None)
+        return stack[-1].req if stack else None
+
+    def stamp(self):
+        """The start of a span that another thread will end
+        (:meth:`close_stamp`): the time, and the calling thread's
+        place in its request's tree.  None when the call chain is not
+        being recorded."""
+        stack = getattr(_tls, "stack", None)
+        if stack:
+            top = stack[-1]
+            return (time.time_ns() // 1000, top.span_id, top.req,
+                    threading.get_ident())
+        if self._annotation is None:
+            return None
+        return (time.time_ns() // 1000, None, None,
+                threading.get_ident())
+
+    def close_stamp(self, stamp, name: str, cat: str, txid=None,
+                    kind: str = "wait", **args) -> None:
+        """Record the span ``stamp`` began, ending now, on the thread
+        and under the parent that took the stamp."""
+        start_us, parent_id, req, tid = stamp
+        self.record_span(name, cat, txid, start_us,
+                         time.time_ns() // 1000 - start_us, parent_id,
+                         kind, req, tid, **args)
+
     def record_span(self, name: str, cat: str, txid, start_us: int,
                     dur_us: int, parent_id: Optional[int] = None,
-                    **args) -> None:
-        """Record an externally timed, already-finished span — the
-        kernel-span layer measures dispatch→completion itself (a
-        perf_counter pair around the XLA call) and deposits the result
-        here, parented under the enclosing live span so kernels appear
-        as children in the txn tree.  No sampling check: callers gate
-        on :meth:`current`, which already encodes the decision."""
+                    kind: str = "work", req=None,
+                    tid: Optional[int] = None, **args) -> None:
+        """Record an externally timed, already-finished span: one that
+        starts on one thread and ends on another (a staged read's wait
+        for its drain) or is timed outside Python (the native planes).
+        ``tid`` is the thread it belongs to when that is not the
+        caller's.  No sampling check: callers gate on :meth:`current`
+        or on a stamp they took when the span began."""
         self._add(Span(
             next(_SPAN_IDS), parent_id, name, cat, txid, int(start_us),
-            int(dur_us), threading.get_ident(), args))
+            int(dur_us), tid if tid is not None else
+            threading.get_ident(), args, kind, req))
 
     def _add(self, span: Span) -> None:
         with self._lock:
             self._spans.append(span)
+            self._added += 1
+
+    # -------------------------------------------------------------- capture
+
+    @property
+    def capturing(self) -> bool:
+        return self._annotation is not None
+
+    def capture_begin(self, annotation) -> None:
+        """A profiler capture opened (obs.prof.start): record every
+        span from here, and hold ``annotation(name)`` (a
+        ``jax.profiler.TraceAnnotation``) around each thread's
+        innermost work span."""
+        with self._lock:
+            self._capture_from = (time.time_ns() // 1000, self._added)
+        self._annotation = annotation
+
+    def capture_end(self) -> Dict[str, Any]:
+        """The capture closed: its bounds, the spans it added that the
+        ring still holds, and how many the ring dropped.  Cheap (one
+        copy under the lock); :func:`summarize` reduces it later, off
+        the serving window."""
+        self._annotation = None
+        t0_us, added0 = self._capture_from
+        with self._lock:
+            added = self._added - added0
+            kept = min(added, len(self._spans))
+            spans = list(itertools.islice(
+                self._spans, len(self._spans) - kept, None))
+        return {"t0_us": t0_us, "t1_us": time.time_ns() // 1000,
+                "spans": spans, "dropped": added - kept}
 
     # -------------------------------------------------------------- queries
 
@@ -377,6 +565,98 @@ class Tracer:
 
 #: process-wide tracer (all DCs share it, like stats.registry)
 tracer = Tracer()
+
+
+def _merge(intervals: list) -> list:
+    """Sorted, disjoint ``(start, end)`` covering the same points."""
+    out: list = []
+    for start, end in sorted(intervals):
+        if out and start <= out[-1][1]:
+            if end > out[-1][1]:
+                out[-1] = (out[-1][0], end)
+        elif end > start:
+            out.append((start, end))
+    return out
+
+
+def _minus(keep: list, cut: list) -> list:
+    """``keep`` less ``cut``, both merged."""
+    out = []
+    for start, end in keep:
+        for c0, c1 in cut:
+            if c1 <= start or c0 >= end:
+                continue
+            if c0 > start:
+                out.append((start, c0))
+            start = max(start, c1)
+            if start >= end:
+                break
+        if start < end:
+            out.append((start, end))
+    return out
+
+
+def _length(intervals: list) -> int:
+    return sum(end - start for start, end in intervals)
+
+
+def summarize(spans: List[Span], t0_us: int, t1_us: int,
+              dropped: int = 0) -> Dict[str, Any]:
+    """Reduce the spans of one capture ``[t0_us, t1_us]``.
+
+    ``spans``: per name its category and kind, the count, total, self
+    time (duration less the part its children cover, a child being any
+    span that names it as parent, on whatever thread) and p95
+    (nearest rank).  ``requests``: per message kind the roots' count
+    and total.  ``host_busy_s``: the length of the capture in which at
+    least one thread was inside a work span and outside every wait
+    span — the time the one Python process had something to run."""
+    children: Dict[int, list] = {}
+    for s in spans:
+        if s.parent_id is not None:
+            children.setdefault(s.parent_id, []).append(s)
+    by_name: Dict[str, dict] = {}
+    durs: Dict[str, list] = {}
+    requests: Dict[str, dict] = {}
+    work: Dict[int, list] = {}
+    waits: Dict[int, list] = {}
+    for s in spans:
+        end = s.start_us + s.dur_us
+        covered = _length(_merge(
+            [(max(c.start_us, s.start_us),
+              min(c.start_us + c.dur_us, end))
+             for c in children.get(s.span_id, ())]))
+        row = by_name.setdefault(s.name, {
+            "cat": s.cat, "kind": s.kind, "count": 0, "total_s": 0.0,
+            "self_s": 0.0})
+        row["count"] += 1
+        row["total_s"] += s.dur_us / 1e6
+        row["self_s"] += (s.dur_us - covered) / 1e6
+        durs.setdefault(s.name, []).append(s.dur_us)
+        if s.kind == "root":
+            r = requests.setdefault(str(s.args.get("kind")),
+                                    {"count": 0, "total_s": 0.0})
+            r["count"] += 1
+            r["total_s"] += s.dur_us / 1e6
+        elif s.dur_us:
+            (waits if s.kind == "wait" else work).setdefault(
+                s.tid, []).append((max(s.start_us, t0_us),
+                                   min(end, t1_us)))
+    for name, row in by_name.items():
+        d = sorted(durs[name])
+        row["p95_s"] = d[max(0, -(-95 * len(d) // 100) - 1)] / 1e6
+    busy: list = []
+    for tid, intervals in work.items():
+        busy += _minus(_merge(intervals), _merge(waits.get(tid, [])))
+    return {
+        "length_s": (t1_us - t0_us) / 1e6,
+        "span_count": len(spans),
+        "dropped": dropped,
+        "spans": by_name,
+        "requests": requests,
+        "requests_answered": sum(r["count"] for r in requests.values()),
+        "host_busy_s": _length(_merge(busy)) / 1e6,
+    }
 
 
 def traced(name: str, cat: str):

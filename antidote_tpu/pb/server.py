@@ -13,6 +13,7 @@ socket process.
 
 from __future__ import annotations
 
+import itertools
 import logging
 import socketserver
 import struct
@@ -21,12 +22,17 @@ import uuid
 from typing import Dict
 
 from antidote_tpu.api import TransactionAborted
+from antidote_tpu.obs.spans import tracer
 from antidote_tpu.pb import antidote_pb2 as pb
 from antidote_tpu.pb import codec
 
 DEFAULT_PORT = 8087  # reference ?DEFAULT_PB_PORT
 
 log = logging.getLogger(__name__)
+
+#: connection serials of this process: with a connection's message
+#: serial, the request id under which a wire message's spans are kept
+_CONN_SERIALS = itertools.count(1)
 
 
 class PbServer:
@@ -42,42 +48,68 @@ class PbServer:
 
                 conn = _Connection(outer.db)
                 cconn = compat.CompatConnection(outer.db)
+                conn_serial, serial = next(_CONN_SERIALS), 0
                 try:
                     while True:
                         frame = codec.read_frame(self.request)
                         if frame is None:
                             return
-                        code, body = frame
-                        # dual-protocol dispatch by message code: the
-                        # upstream antidote_pb registry numbers from
-                        # 107, the rebuild's own protocol from 10 —
-                        # disjoint, so antidotec_pb-style clients and
-                        # native clients share the port (pb/compat.py)
-                        if compat.is_compat_code(code):
-                            try:
-                                req = compat.decode_request(code, body)
-                                resp = cconn.process(req)
-                            except Exception as e:  # noqa: BLE001
-                                log.exception("pb compat request failed")
-                                resp = compat.error_resp(str(e))
-                            ccode, cbody = compat.encode_response(resp)
-                            self.request.sendall(
-                                struct.pack(">IB", len(cbody) + 1,
-                                            ccode) + cbody)
-                            continue
-                        try:
-                            req = codec.decode_msg(code, body)
-                            resp = conn.process(req)
-                        except Exception as e:  # noqa: BLE001 — wire errors
-                            # must go back to the client, not kill the
-                            # connection (reference antidote_pb_protocol
-                            # catches and sends ApbErrorResp, :68-76)
-                            log.exception("pb request failed")
-                            resp = pb.ApbErrorResp(message=str(e))
-                        self.request.sendall(codec.encode_msg(resp))
+                        serial += 1
+                        # the request's root span: frame fully read to
+                        # answer sent.  Its id is the txid of every
+                        # span below that carries no transaction, and
+                        # the ``req`` of all of them (obs/spans.py)
+                        with tracer.root("pb_request", "wire",
+                                         conn_serial, serial) as root:
+                            kind, sent = self._answer(conn, cconn,
+                                                      compat, *frame)
+                            if root is not None:
+                                root.args.update(
+                                    kind=kind, bytes_in=len(frame[1]) + 5,
+                                    bytes_out=sent)
                 finally:
                     conn.abort_all()
                     cconn.abort_all()
+
+            def _answer(self, conn, cconn, compat, code, body):
+                """Decode, process, encode and send one message;
+                returns the message kind and the bytes sent."""
+                kind = "undecoded"
+                # dual-protocol dispatch by message code: the upstream
+                # antidote_pb registry numbers from 107, the rebuild's
+                # own protocol from 10 — disjoint, so antidotec_pb-
+                # style clients and native clients share the port
+                # (pb/compat.py)
+                if compat.is_compat_code(code):
+                    try:
+                        with tracer.span("pb_decode", "wire"):
+                            req = compat.decode_request(code, body)
+                        kind = type(req).__name__
+                        resp = cconn.process(req)
+                    except Exception as e:  # noqa: BLE001
+                        log.exception("pb compat request failed")
+                        resp = compat.error_resp(str(e))
+                    with tracer.span("pb_encode_send", "wire"):
+                        ccode, cbody = compat.encode_response(resp)
+                        out = struct.pack(">IB", len(cbody) + 1,
+                                          ccode) + cbody
+                        self.request.sendall(out)
+                    return kind, len(out)
+                try:
+                    with tracer.span("pb_decode", "wire"):
+                        req = codec.decode_msg(code, body)
+                    kind = type(req).__name__
+                    resp = conn.process(req)
+                except Exception as e:  # noqa: BLE001 — wire errors
+                    # must go back to the client, not kill the
+                    # connection (reference antidote_pb_protocol
+                    # catches and sends ApbErrorResp, :68-76)
+                    log.exception("pb request failed")
+                    resp = pb.ApbErrorResp(message=str(e))
+                with tracer.span("pb_encode_send", "wire"):
+                    out = codec.encode_msg(resp)
+                    self.request.sendall(out)
+                return kind, len(out)
 
         class _Server(socketserver.ThreadingTCPServer):
             allow_reuse_address = True
@@ -192,17 +224,12 @@ class _Connection:
 
     def _static_read(self, req: pb.ApbStaticReadObjects):
         try:
-            from antidote_tpu.obs.spans import tracer
-
             clock = codec.clock_from_pb(req.clock)
             props = codec.props_from_pb(req.properties)
             objects = [codec.bound_from_pb(b) for b in req.objects]
             # routed through the read serve plane (ISSUE 8): the one-
             # shot read allocates no interactive transaction and
-            # coalesces with concurrent readers (mat/serve.py); the
-            # instant marks the PB arrival on the serve-stage timeline
-            tracer.instant("pb_static_read", "coordinator",
-                           keys=len(objects))
+            # coalesces with concurrent readers (mat/serve.py)
             values, commit_vc = self.db.read_objects_static(
                 clock, objects, props)
         except Exception as e:  # noqa: BLE001

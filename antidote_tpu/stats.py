@@ -303,19 +303,15 @@ class Registry:
         # ---- kernel-span layer (ISSUE 2, antidote_tpu/obs/prof.py):
         # per-kernel device-plane timing, compile-cache misses, and the
         # buffer census.  Dispatch buckets reach down to 10 µs (a warm
-        # dispatch is host-side only); the completion histogram shares
-        # the stage-latency bucket ladder.
+        # dispatch is host-side only).  Nothing here times the device:
+        # the host never waits for a kernel in order to time it, a
+        # profiler capture's device plane does (obs/prof.py).
         self.kernel_dispatch_latency = Histogram(
             "antidote_kernel_dispatch_latency_seconds",
             "Host wall time to dispatch one profiled device kernel "
             "(async: excludes device execution)",
             buckets=(0.00001, 0.0001, 0.0005, 0.001, 0.005, 0.01,
                      0.05, 0.1, 0.5, 1.0, 5.0))
-        self.kernel_complete_latency = Histogram(
-            "antidote_kernel_complete_latency_seconds",
-            "Dispatch-to-completion wall time of profiled kernels, "
-            "measured by a scalar device->host fetch (sampled txns, "
-            "detail mode, and open captures only)", buckets=lat_buckets)
         self.kernel_calls = Counter(
             "antidote_kernel_calls_total",
             "Profiled device-kernel dispatches",
@@ -942,7 +938,7 @@ class Registry:
                 self.commit_latency, self.log_append_latency,
                 self.device_flush_latency, self.device_read_latency,
                 self.depgate_wait, self.replication_lag,
-                self.kernel_dispatch_latency, self.kernel_complete_latency,
+                self.kernel_dispatch_latency,
                 self.kernel_calls, self.kernel_compile_misses,
                 self.device_buffer_hwm,
                 self.gate_dispatches, self.gate_h2d_bytes,

@@ -280,12 +280,15 @@ class Coordinator:
         (api.read_objects_static): a one-shot read snapshots exactly
         like a transaction, it just skips the transaction."""
         node = self.node
-        if client_clock and props.update_clock:
-            snap = self._wait_for_clock(client_clock).join(client_clock)
-        else:
-            snap = VC(node.stable_vc())
-        return snap.set_dc(node.dc_id, max(snap.get_dc(node.dc_id),
-                                           node.clock.now_us()))
+        with tracer.span("txn_snapshot", "coordinator"):
+            if client_clock and props.update_clock:
+                snap = self._wait_for_clock(client_clock).join(
+                    client_clock)
+            else:
+                snap = VC(node.stable_vc())
+            return snap.set_dc(node.dc_id,
+                               max(snap.get_dc(node.dc_id),
+                                   node.clock.now_us()))
 
     def start_transaction(self, client_clock: Optional[VC] = None,
                           properties: Optional[TxnProperties] = None
@@ -312,18 +315,28 @@ class Coordinator:
         import time as _time
 
         node = self.node
-        deadline = _time.monotonic() + node.config.clock_wait_timeout_s
-        while True:
+
+        def covering():
             snap = VC(node.stable_vc())
             snap = snap.set_dc(node.dc_id, max(snap.get_dc(node.dc_id),
                                                node.clock.now_us()))
-            if snap.ge(client_clock):
-                return snap
-            if _time.monotonic() > deadline:
-                raise TimeoutError(
-                    f"snapshot never caught up with client clock "
-                    f"{dict(client_clock)}; stable={dict(snap)}")
-            node.wait_hook()
+            return snap if snap.ge(client_clock) else None
+
+        snap = covering()
+        if snap is not None:
+            return snap
+        deadline = _time.monotonic() + node.config.clock_wait_timeout_s
+        with tracer.wait_span("txn_clock_wait", "coordinator"):
+            while True:
+                node.wait_hook()
+                snap = covering()
+                if snap is not None:
+                    return snap
+                if _time.monotonic() > deadline:
+                    raise TimeoutError(
+                        f"snapshot never caught up with client clock "
+                        f"{dict(client_clock)}; "
+                        f"stable={dict(node.stable_vc())}")
 
     def gr_snapshot_wait(self, client_clock: Optional[VC]) -> VC:
         """GentleRain snapshot choice (reference gr_snapshot_obtain,
@@ -338,18 +351,19 @@ class Coordinator:
         node = self.node
         want = client_clock.get_dc(node.dc_id) if client_clock else 0
         deadline = _time.monotonic() + node.config.clock_wait_timeout_s
-        while True:
-            st = VC(node.stable_vc())
-            entries = dict(st)
-            gst = min(entries.values()) if entries else 0
-            if want <= gst:
-                snap = VC({dc: gst for dc in entries})
-                return snap.set_dc(node.dc_id, gst)
-            if _time.monotonic() > deadline:
-                raise TimeoutError(
-                    f"GST {gst} never caught up with client clock entry "
-                    f"{want} for {node.dc_id}")
-            node.wait_hook()
+        with tracer.wait_span("gr_snapshot_wait", "coordinator"):
+            while True:
+                st = VC(node.stable_vc())
+                entries = dict(st)
+                gst = min(entries.values()) if entries else 0
+                if want <= gst:
+                    snap = VC({dc: gst for dc in entries})
+                    return snap.set_dc(node.dc_id, gst)
+                if _time.monotonic() > deadline:
+                    raise TimeoutError(
+                        f"GST {gst} never caught up with client clock "
+                        f"entry {want} for {node.dc_id}")
+                node.wait_hook()
 
     def start_transaction_gr(self, client_clock: Optional[VC] = None,
                              properties: Optional[TxnProperties] = None

@@ -94,7 +94,7 @@ class DeviceFlusher:
             with self._lock:
                 self._queued.discard(key)
             try:
-                with pm._lock:
+                with pm._locked:
                     pm._wait_device_quiesce()
                     plane.flush_gc_now()
             except Exception:  # noqa: BLE001 — the drain must not die
@@ -114,6 +114,30 @@ class DeviceFlusher:
             # under it and the interpreter unwind this daemon thread
             # mid-XLA-call (an abort at exit, not an exception)
             t.join()
+
+
+class _TimedLock:
+    """``with pm._locked:`` is ``with pm._lock:`` whose wait, when the
+    lock is contended, is recorded as a ``pm_lock_wait`` span: the
+    batch-level sites of the request path use it, so a request's tree
+    says how long it stood behind another holder.  Uncontended it is
+    one non-blocking acquire and touches no tracer state."""
+
+    __slots__ = ("_lock", "_partition")
+
+    def __init__(self, lock, partition: int):
+        self._lock = lock
+        self._partition = partition
+
+    def __enter__(self):
+        if not self._lock.acquire(False):
+            with tracer.wait_span("pm_lock_wait", "manager",
+                                  partition=self._partition):
+                self._lock.acquire()
+
+    def __exit__(self, *exc):
+        self._lock.release()
+        return False
 
 
 #: tag marking a deferred-op entry that carries a RAW OPERATION whose
@@ -202,6 +226,7 @@ class PartitionManager:
         self._stable_cache = VC()
         self._stable_cached_at = 0.0
         self._lock = threading.Condition()
+        self._locked = _TimedLock(self._lock, partition)
         #: set (under self._lock) by the handoff cutover at the moment
         #: the final log tail is snapshot: appends require self._lock,
         #: so checking this flag in the same critical section as the
@@ -410,7 +435,7 @@ class PartitionManager:
 
     def prepare(self, txid, snapshot_vc: VC, certify: bool = True) -> int:
         """Certify + log a prepare record; returns the prepare time."""
-        with self._lock:
+        with self._locked:
             self._mutate_check()
             keys = [k for k, _t, _e in self._staged.get(txid, [])]
             if certify:
@@ -600,8 +625,12 @@ class PartitionManager:
         """Block (under self._lock) until no lock-free device reader is
         in flight: device mutations donate buffers a reader may still
         hold.  Must run under self._lock."""
-        while self._dev_readers:
-            self._lock.wait()
+        if not self._dev_readers:
+            return
+        with tracer.wait_span("device_quiesce_wait", "manager",
+                              partition=self.partition):
+            while self._dev_readers:
+                self._lock.wait()
 
     def _migrate_key_to_host(self, key, type_name: str,
                              state=None) -> None:
@@ -685,7 +714,7 @@ class PartitionManager:
         legacy path (``Config.log_group=False``) keeps the inline
         fsync under the lock exactly as before."""
         stable = self._stable_for_gc()  # before the lock (see __init__)
-        with self._lock:
+        with self._locked:
             self._mutate_check()
             self.log.append_commit(self.dc_id, txid, commit_time,
                                    snapshot_vc, certified)
@@ -750,7 +779,7 @@ class PartitionManager:
                       certify: bool = True) -> int:
         """One-partition fast path: prepare + commit in one step
         (reference single_commit, src/clocksi_vnode.erl:180-190)."""
-        with self._lock:
+        with self._locked:
             self._mutate_check()
             keys = [k for k, _t, _e in self._staged.get(txid, [])]
             if certify:
@@ -840,6 +869,28 @@ class PartitionManager:
                 return True
         return False
 
+    def _await_unprepared(self, keys, snapshot_vc: VC, txid,
+                          what: str) -> None:
+        """Under self._lock: wait (releasing it) until no prepared
+        transaction may still commit one of ``keys`` below
+        ``snapshot_vc`` (reference check_prepared,
+        src/clocksi_readitem_server.erl:236-264); TimeoutError naming
+        ``what`` after ``read_wait_timeout``."""
+        def blocked():
+            return any(self._blocking_prepared(k, snapshot_vc, txid)
+                       for k in keys)
+
+        if not blocked():
+            return
+        deadline = time.monotonic() + self.read_wait_timeout
+        with tracer.wait_span("pm_prepared_wait", "manager", txid=txid,
+                              partition=self.partition):
+            while blocked():
+                remaining = deadline - time.monotonic()
+                if remaining <= 0 or not self._lock.wait(
+                        timeout=remaining):
+                    raise TimeoutError(f"{what} blocked on prepared txn")
+
     def read(self, key, type_name: str, snapshot_vc: Optional[VC],
              txid=None, exact_state: bool = False) -> Any:
         """Clock-SI safe read: wait until the local clock passed the
@@ -858,15 +909,11 @@ class PartitionManager:
             # must not stall commits on this partition)
             self.clock.wait_until(snapshot_vc.get_dc(self.dc_id))
         reader = None
-        with self._lock:
+        with self._locked:
             self._read_check()
             if snapshot_vc is not None:
-                deadline = time.monotonic() + self.read_wait_timeout
-                while self._blocking_prepared(key, snapshot_vc, txid):
-                    remaining = deadline - time.monotonic()
-                    if remaining <= 0 or not self._lock.wait(timeout=remaining):
-                        raise TimeoutError(
-                            f"read of {key!r} blocked on prepared txn")
+                self._await_unprepared((key,), snapshot_vc, txid,
+                                       f"read of {key!r}")
             if self.device is not None and self.device.owns(type_name, key):
                 fold_exact = self.device.state_exact(type_name, key)
                 need_exact = exact_state and not fold_exact
@@ -918,7 +965,11 @@ class PartitionManager:
                     self._cache_put(key, fr, value, True)
                 return value
         try:
-            value = reader()
+            # one key's fold: dispatch and fetch in one, as the planes'
+            # single-key readers make them
+            with tracer.span("device_read", "device", txid=txid,
+                             plane=type_name):
+                value = reader()
         finally:
             with self._lock:
                 self._dev_readers -= 1
@@ -1072,21 +1123,16 @@ class PartitionManager:
             self.clock.wait_until(snapshot_vc.get_dc(self.dc_id))
         out: Dict[Tuple[Any, str], Any] = {}
         dev_batches = []  # (type, [(key, cacheable_frontier)], closure)
-        with self._lock:
+        with self._locked:
             self._read_check()
             if snapshot_vc is not None:
+                keys = [k for k, _t in items]
                 if nowait and any(
                         self._blocking_prepared(k, snapshot_vc, txid)
-                        for k, _t in items):
+                        for k in keys):
                     return None
-                deadline = time.monotonic() + self.read_wait_timeout
-                while any(self._blocking_prepared(k, snapshot_vc, txid)
-                          for k, _t in items):
-                    remaining = deadline - time.monotonic()
-                    if remaining <= 0 or not self._lock.wait(
-                            timeout=remaining):
-                        raise TimeoutError(
-                            "batched read blocked on prepared txn")
+                self._await_unprepared(keys, snapshot_vc, txid,
+                                       "batched read")
             by_type: Dict[str, list] = {}
             cache_hits = dev_misses = 0
             for key, type_name in items:
@@ -1167,7 +1213,7 @@ class PartitionManager:
                         pending_readers -= 1
                         self._lock.notify_all()
                 cacheable = []
-                with self._lock:
+                with self._locked:
                     for key, fr, exact in pairs:
                         if key in got:
                             value = got[key]
@@ -1266,8 +1312,12 @@ class PartitionManager:
                 # neither seed nor suffix.  Wait both quiescent; the
                 # condition wait releases the lock, so the deferred
                 # committers' publishes (and device readers) drain.
-                while self._dev_readers or self._defer_unpublished:
-                    self._lock.wait()
+                if self._dev_readers or self._defer_unpublished:
+                    with tracer.wait_span("ckpt_quiesce_wait", "oplog",
+                                          partition=self.partition):
+                        while self._dev_readers \
+                                or self._defer_unpublished:
+                            self._lock.wait()
                 doc = self.log.capture_cut()
                 dirty, self._ckpt_dirty = self._ckpt_dirty, {}
                 self._ckpt_fold(doc, dirty)
@@ -1287,7 +1337,9 @@ class PartitionManager:
             # atomic rename runs under the lock inside adopt (ISSUE 11
             # — the ROADMAP "stage the rewrite out of the lock" item)
             trunc = self.log.stage_truncation(doc)
-            with self._lock:
+            with self._lock, \
+                    tracer.span("ckpt_adopt", "oplog",
+                                partition=self.partition):
                 # lock-ok: adopt redeems the staged truncation — the
                 # BOUNDED half (catch-up of bytes appended during the
                 # copy, atomic rename, directory fsync) runs under the
@@ -1504,7 +1556,9 @@ class PartitionManager:
         """Min prepare time of in-flight txns (caps the stable time so a
         snapshot never passes a pending commit; reference get_min_prep,
         src/clocksi_vnode.erl:671-678)."""
-        with self._lock:
+        # every snapshot asks every partition (node.stable_vc): a
+        # request's wait for this lock is named in its tree
+        with self._locked:
             if self.prepared:
                 return min(pt for pt, _ in self.prepared.values())
             return self.clock.now_us()

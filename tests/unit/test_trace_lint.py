@@ -28,6 +28,9 @@ def test_lint_detects_a_dark_entry_point(tmp_path):
                        .replace("@traced", "@_not_traced")
                        .replace("tracer.span", "tracer_span")
                        .replace("tracer.instant", "tracer_instant")
+                       .replace("tracer.wait_span", "tracer_wait_span")
+                       .replace("tracer.root", "tracer_root")
+                       .replace("tracer.close_stamp", "tracer_close")
                        .replace("tracing.annotate", "tracing_annotate")
                        .replace("prof.annotate", "prof_annotate"))
     problems = trace_lint.lint(str(tmp_path))

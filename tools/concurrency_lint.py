@@ -211,6 +211,14 @@ _BLOCKING_OWNED = {
 #: Condition/Event wait verbs (exempt when waiting on the held lock)
 _WAIT_NAMES = {"wait", "wait_for"}
 
+#: context managers built AROUND an existing lock, which they acquire
+#: and release and nothing else: ``self._locked = _TimedLock(
+#: self._lock, ...)`` (txn/manager.py: the same lock, its contended
+#: wait recorded as a ``pm_lock_wait`` span).  Classified like a
+#: Condition around the lock, so ``with pm._locked:`` holds
+#: ``pm._lock`` for every rule below
+_LOCK_WRAPPERS = {"_TimedLock"}
+
 #: collective-program builders: a name assigned from a call reaching
 #: one of these is a multi-chip launcher and must only be CALLED under
 #: a collective region ([collective-lock], runtime.py's invariant)
@@ -381,6 +389,8 @@ class _Analyzer:
                             and isinstance(sub.value, ast.Call)):
                         continue
                     kind = _terminal(sub.value.func)
+                    if kind in _LOCK_WRAPPERS:
+                        kind = "Condition"
                     if kind not in ("Lock", "RLock", "Condition",
                                     "Event"):
                         continue

@@ -81,15 +81,37 @@ from typing import Dict, List
 #: entry points of each plane that MUST be instrumented.  Grow this
 #: list when a PR adds a plane; never shrink it to silence the lint.
 ENTRY_POINTS: Dict[str, Dict[str, List[str]]] = {
+    # PR 25: the request path, wire to device.  PbServer.__init__
+    # defines the handler class whose loop opens the pb_request root;
+    # the static API calls replace the pb_static_read / static_read
+    # instants; the manager's three waits and the serve plane's queue
+    # have names; a device dispatch is prepare / dispatch / fetch
+    "antidote_tpu/pb/server.py": {
+        "PbServer": ["__init__"],
+    },
+    "antidote_tpu/api.py": {
+        "AntidoteTPU": ["read_objects_static", "update_objects_static"],
+    },
     "antidote_tpu/txn/coordinator.py": {
         "Coordinator": ["read_objects", "update_objects",
-                        "commit_transaction", "abort_transaction"],
+                        "commit_transaction", "abort_transaction",
+                        "snapshot_for", "gr_snapshot_wait"],
+    },
+    "antidote_tpu/txn/manager.py": {
+        "_TimedLock": ["__enter__"],
+        "PartitionManager": ["_await_unprepared", "_wait_device_quiesce",
+                             "checkpoint_now"],
+    },
+    "antidote_tpu/mat/serve.py": {
+        "ReadServer": ["finish", "_lead_once", "_drain"],
     },
     "antidote_tpu/oplog/partition.py": {
         "PartitionLog": ["append_commit"],
     },
     "antidote_tpu/mat/device_plane.py": {
         "DevicePlane": ["stage", "read", "read_many", "gc", "flush"],
+        "_PlaneBase": ["_append_rows", "read_many_begin", "_many_reader",
+                       "flush", "gc"],
     },
     "antidote_tpu/mat/sharded.py": {
         "_ShardedBase": ["append", "read", "read_keys"],
@@ -111,6 +133,10 @@ ENTRY_POINTS: Dict[str, Dict[str, List[str]]] = {
 #: ISSUE 7; prof.annotate is the home)
 _INSTRUMENTED_CALLS = {
     ("tracer", "span"), ("tracer", "instant"),
+    # PR 25: a span in which the thread sleeps, a request's root, and
+    # a span that ends on another thread than it began on
+    ("tracer", "wait_span"), ("tracer", "root"),
+    ("tracer", "close_stamp"),
     ("prof", "annotate"),
 }
 
