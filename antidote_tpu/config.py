@@ -155,9 +155,11 @@ class Config:
     #: data directory for durable logs / metadata
     data_dir: str = "antidote_data"
     #: stable-snapshot read cache TTL, seconds.  Every transaction start
-    #: reads the stable snapshot; computing it sweeps all partitions'
-    #: min-prepared (a lock per partition — a convoy under concurrent
-    #: clients).  A stale-by-milliseconds stable snapshot is always
+    #: reads the stable snapshot.  The single-DC provider's sweep of the
+    #: partitions' min-prepared takes no lock (PartitionManager
+    #: .min_prepared), so there the cache saves a clock draw a partition;
+    #: the multi-DC provider (meta/gossip.py) folds arrays under its own
+    #: lock behind it.  A stale-by-milliseconds stable snapshot is always
     #: safe: stability is monotone, and the snapshot's own-DC entry is
     #: bumped to `now` regardless (the reference reads a 1 s-cadence
     #: gossiped value, far staler than this)

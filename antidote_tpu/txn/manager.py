@@ -15,6 +15,7 @@ transaction block on the condition until commit/abort notifies
 from __future__ import annotations
 
 import logging
+import queue
 import threading
 import time
 from dataclasses import replace as dc_replace
@@ -65,8 +66,7 @@ class DeviceFlusher:
     src/materializer_vnode.erl:620-647.)"""
 
     def __init__(self):
-        import queue
-
+        #: the running thread's own queue; stop() leaves a new one
         self._q: "queue.Queue" = queue.Queue()
         self._queued: set = set()
         self._lock = threading.Lock()
@@ -80,14 +80,14 @@ class DeviceFlusher:
             self._queued.add(key)
             if self._thread is None:
                 self._thread = threading.Thread(
-                    target=self._run, daemon=True,
+                    target=self._run, args=(self._q,), daemon=True,
                     name="device-flusher")
                 self._thread.start()
-        self._q.put((key, pm, plane))
+            self._q.put((key, pm, plane))
 
-    def _run(self) -> None:
+    def _run(self, q: "queue.Queue") -> None:
         while True:
-            item = self._q.get()
+            item = q.get()
             if item is None:
                 return
             key, pm, plane = item
@@ -105,10 +105,14 @@ class DeviceFlusher:
 
     def stop(self) -> None:
         with self._lock:
-            t = self._thread
+            t, q = self._thread, self._q
             self._thread = None
+            # a schedule() that races a closing node starts a thread of
+            # its own on a queue of its own: on a shared queue it took
+            # this thread's sentinel, and the join below never returned
+            self._q = queue.Queue()
         if t is not None:
-            self._q.put(None)
+            q.put(None)
             # no timeout: the queued flushes are finite, and returning
             # while one still runs would let the caller close the logs
             # under it and the interpreter unwind this daemon thread
@@ -216,8 +220,10 @@ class PartitionManager:
         #: fall below — the GST.  A txn's own snapshot is NOT safe here: a
         #: concurrent txn prepared earlier can still commit with a lower
         #: time, and pruning at an unstable horizon loses its op from the
-        #: cached bases.  Must be called OUTSIDE self._lock (it reads
-        #: min-prepared across partitions).
+        #: cached bases.  Must be called OUTSIDE self._lock: min-prepared
+        #: itself takes no lock, but a richer provider (the gossip fold,
+        #: the device tracker) takes its own, and a remote partition's
+        #: proxy is an RPC.
         self.stable_vc_source: Callable[[], VC] = VC
         #: sampled horizon cache: the source sweeps every partition, so
         #: it is refreshed at most every ``_STABLE_REFRESH_S`` (the
@@ -244,6 +250,10 @@ class PartitionManager:
         self.parked = False
         #: txid -> (prepare_time, [keys])
         self.prepared: Dict[Any, Tuple[int, List[Any]]] = {}
+        #: the smallest prepare time in ``prepared`` (None = empty),
+        #: stored under self._lock wherever the table changes and read
+        #: with no lock by min_prepared() / has_prepared()
+        self._min_prep: Optional[int] = None
         #: key -> last committed time at this DC
         self.committed: Dict[Any, int] = {}
         #: ops staged per txid before commit (the txn's effects on this
@@ -440,10 +450,26 @@ class PartitionManager:
             keys = [k for k, _t, _e in self._staged.get(txid, [])]
             if certify:
                 self.certify(txid, keys, snapshot_vc)
-            pt = self.clock.now_us()
-            self.prepared[txid] = (pt, keys)
+            pt = self._record_prepared_locked(txid, keys)
             self.log.append_prepare(self.dc_id, txid, pt)
             return pt
+
+    def _record_prepared_locked(self, txid, keys: List[Any]) -> int:
+        """Draw ``txid``'s prepare time and enter it in the table;
+        must run under self._lock.  The floor goes out BEFORE the draw:
+        min_prepared() explains why."""
+        if self._min_prep is None:
+            self._min_prep = self.clock.now_us()
+        pt = self.clock.now_us()
+        self.prepared[txid] = (pt, keys)
+        self._publish_min_prep_locked()
+        return pt
+
+    def _publish_min_prep_locked(self) -> None:
+        """Store the table's minimum for the lock-free readers; must
+        run under self._lock, after the table changed."""
+        self._min_prep = min(pt for pt, _ in self.prepared.values()) \
+            if self.prepared else None
 
     def _stable_for_gc(self) -> VC:
         """Throttled GC horizon; call OUTSIDE self._lock.
@@ -773,6 +799,7 @@ class PartitionManager:
             if commit_time > self.committed.get(key, 0):
                 self.committed[key] = commit_time
         self.prepared.pop(txid, None)
+        self._publish_min_prep_locked()
         self._lock.notify_all()
 
     def single_commit(self, txid, snapshot_vc: VC,
@@ -784,8 +811,7 @@ class PartitionManager:
             keys = [k for k, _t, _e in self._staged.get(txid, [])]
             if certify:
                 self.certify(txid, keys, snapshot_vc)
-            ct = self.clock.now_us()
-            self.prepared[txid] = (ct, keys)
+            ct = self._record_prepared_locked(txid, keys)
         self.commit(txid, ct, snapshot_vc, certified=certify)
         return ct
 
@@ -796,6 +822,7 @@ class PartitionManager:
                 self.log.append_abort(self.dc_id, txid)
             self._staged.pop(txid, None)
             self.prepared.pop(txid, None)
+            self._publish_min_prep_locked()
             self._lock.notify_all()
 
     # ------------------------------------------------------ remote apply
@@ -1548,20 +1575,50 @@ class PartitionManager:
 
     def has_prepared(self) -> bool:
         """True while any transaction holds a prepare on this partition
-        (the cross-node handoff drain waits for this to clear)."""
-        with self._lock:
-            return bool(self.prepared)
+        (the cross-node handoff drain waits for this to clear).  Takes
+        no lock: it reads what min_prepared() reads."""
+        return self._min_prep is not None
 
     def min_prepared(self) -> int:
         """Min prepare time of in-flight txns (caps the stable time so a
         snapshot never passes a pending commit; reference get_min_prep,
-        src/clocksi_vnode.erl:671-678)."""
-        # every snapshot asks every partition (node.stable_vc): a
-        # request's wait for this lock is named in its tree
-        with self._locked:
-            if self.prepared:
-                return min(pt for pt, _ in self.prepared.values())
-            return self.clock.now_us()
+        src/clocksi_vnode.erl:671-678): the smallest prepare time while
+        something is prepared, else a clock reading.
+
+        Takes NO lock — every snapshot asks every partition, and the
+        lock's holders (drains, commits) are slow.  The promise of a
+        returned ``v``: no transaction of this partition will ever
+        commit at a time below ``v`` without being visible already.
+        It is kept by ordering.  The writers of ``self.prepared``
+        store ``self._min_prep`` under the lock they hold anyway, and
+        a writer that finds the table empty stores a floor (a clock
+        reading) BEFORE it draws its prepare time ``pt``
+        (_record_prepared_locked).  The reader draws its clock reading
+        BEFORE it loads ``_min_prep`` (one attribute load, atomic under
+        the interpreter lock).  HybridClock.now_us() is strictly
+        monotone across threads, so:
+
+        - loaded None: every transaction entered earlier has left the
+          table, published (commit publishes, pops, then stores the new
+          minimum) or aborted; one entered later stores its floor after
+          this load, hence draws ``pt`` after this reader's draw, hence
+          ``pt`` > the reading returned.
+        - loaded a floor: it was drawn with the table empty and before
+          the writer's ``pt``, so it lies below every pending and every
+          later prepare time.
+        - loaded a minimum of the table: no entry of that table lies
+          below it, and every later ``pt`` is drawn later, so above it.
+          The entry may be gone by the time the caller looks: a value
+          that was safe stays safe, stability is monotone.
+
+        A reader that loaded first and drew second would return, from
+        an empty table, a reading above a ``pt`` drawn in between: a
+        stable time over a pending prepare (a causal violation at a
+        second DC, a GC fold at an unsafe horizon here,
+        _stable_for_gc)."""
+        now = self.clock.now_us()
+        published = self._min_prep
+        return now if published is None else published
 
     def value_snapshot(self, key, type_name: str,
                        clock: Optional[VC] = None) -> Any:
