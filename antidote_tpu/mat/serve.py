@@ -359,7 +359,7 @@ class ReadServer:
         fresh snapshot would stall the whole group behind prepares
         none of them can observe.  Frontier objects are snapshotted
         here and re-checked by IDENTITY after the fold
-        (:meth:`_serve_group`): a mid-window publish demotes the
+        (:meth:`_distribute`): a mid-window publish demotes the
         waiter instead of leaking a too-new op.  ``latest``: VC-less
         readers share one un-gated fold.  ``exact``: everyone else
         groups by exact VC equality — identical inclusion masks,
@@ -419,88 +419,36 @@ class ReadServer:
         return groups, solos
 
     def _serve_groups(self, groups, span_txid=None) -> None:
-        """Fold every drain group and distribute values — with ONE
-        fused dispatch per device across ALL the groups.
-
-        Stage 1 begins every group (``read_many_begin`` captures the
-        device folds, reader counts taken).  Stage 2 buckets every
-        captured fold by its ``.device`` handle — a chip for a pinned
-        plane, the Mesh for a pod-sharded one (jax.sharding.Mesh
-        compares by content, so every sharded plane lands in one
-        bucket) — and runs each bucket as one ``fused_read`` under
-        ``collective_guard`` (multi-chip programs serialize on
-        runtime.COLLECTIVE_LOCK).  Stage 3 finishes each group:
-        ``read_many_finish`` distributes values, runs any non-fused
-        lone folds, and RELEASES the reader counts — it runs exactly
-        once per begun group, whatever stage 2 did.
+        """Fold every drain group and distribute the values: one
+        request a group to ``read_requests`` (txn/manager.py), which
+        states when a reader may hold the partition's reader count,
+        runs the captures that share a device as ONE ``fused_read``
+        program and finishes every capture.  A covered waiter whose
+        frontier moved under the fold is served again at its own exact
+        VC, once the groups' counts are given back.
 
         The read-dispatch delta over the whole drain feeds
         ``shard_read_dispatches_per_drain`` — the gauge the config18
         bench gates at O(1) on a sharded node (vs O(groups x types)
-        unfused).
-
-        Deadlock discipline: a begin that would FLUSH must never run
-        while this thread still holds earlier begins' reader counts
-        (the flush's quiesce wait can only be released by our own
-        not-yet-run finishes).  The wave therefore begins groups with
-        ``nowait=True`` — a group whose begin would flush or block on
-        a prepared txn is DEFERRED to a sequential pass after the wave
-        finishes (zero own readers outstanding), where the blocking
-        begin is safe again."""
+        unfused)."""
         pm = self._pm
-        from antidote_tpu.mat.device_plane import (
-            collective_guard, fused_read, read_dispatch_count)
+        from antidote_tpu.mat.device_plane import read_dispatch_count
+        from antidote_tpu.txn.manager import read_requests
 
         d0 = read_dispatch_count()
-        began: List[tuple] = []
-        deferred: List[tuple] = []
-        by_dev: Dict[Any, list] = {}
-        for kind, waiters, fold_vc, fr_map in groups:
-            items = self._group_items(waiters)
-            with tracer.span("read_serve_fold", "device",
-                             txid=span_txid, keys=len(items)):
-                try:
-                    r = pm.read_many_begin(items, fold_vc, span_txid,
-                                           nowait=True)
-                except Exception as e:  # noqa: BLE001 — to waiters
-                    for w in waiters:
-                        w.error = e
-                    continue
-            if r is None:
-                deferred.append((kind, waiters, fold_vc, fr_map))
-                continue
-            out, batches = r
-            gi = len(began)
-            began.append((kind, waiters, fold_vc, fr_map, out,
-                          batches))
-            self._collect_splits(by_dev, gi, batches)
-        got_by = self._fuse(by_dev, collective_guard, fused_read)
-        finished = set()
-        try:
-            for gi, rec in enumerate(began):
-                finished.add(gi)
-                self._finish_group(rec, got_by.get(gi), span_txid)
-        finally:
-            # whatever happened above, every begun group's finish must
-            # run: it releases the reader counts read_many_begin took
-            # (a leak wedges every publish)
-            for gi, rec in enumerate(began):
-                if gi not in finished:
-                    _kind, waiters, fold_vc, _fr, out, batches = rec
-                    try:
-                        pm.read_many_finish(out, batches, fold_vc,
-                                            span_txid)
-                    except Exception as e:  # noqa: BLE001
-                        for w in waiters:
-                            if w.error is None:
-                                w.error = e
-        # sequential pass: the wave's readers are released, so these
-        # groups' begins may flush / wait on prepares safely (the
-        # pre-ISSUE-20 per-group shape, fused within each group)
-        for kind, waiters, fold_vc, fr_map in deferred:
-            self._serve_group_seq(kind, waiters, fold_vc, fr_map,
-                                  span_txid, collective_guard,
-                                  fused_read)
+        results = read_requests(
+            [(pm, self._group_items(waiters), fold_vc, span_txid)
+             for _kind, waiters, fold_vc, _fr in groups])
+        broken: List[_Waiter] = []
+        for (kind, waiters, _vc, fr_map), got in zip(groups, results):
+            broken += self._distribute(kind, waiters, fr_map, got)
+        # rare: the legacy inclusion mask at the waiter's own VC cannot
+        # over-include, whatever published; the waiter's txid rides
+        # along like the solo path's — the legacy own-prepared
+        # exclusion and trace joins survive
+        for w, got in zip(broken, read_requests(
+                [(pm, w.items, w.vc, w.txid) for w in broken])):
+            self._distribute("exact", [w], None, got)
         delta = read_dispatch_count() - d0
         reg = stats.registry
         reg.shard_serve_drains.inc()
@@ -517,98 +465,29 @@ class ReadServer:
                     items.append(pair)
         return items
 
-    @staticmethod
-    def _collect_splits(by_dev, gi, batches) -> None:
-        """Bucket a begun group's fused-capable fold captures by their
-        ``.device`` handle (a chip, or the Mesh of a sharded plane)."""
-        for bi, (_t, _pairs, closure) in enumerate(batches):
-            split = getattr(closure, "split", None) \
-                if closure is not None else None
-            if split is not None:
-                by_dev.setdefault(
-                    getattr(closure, "device", None), []).append(
-                        (gi, bi, split))
-
-    @staticmethod
-    def _fuse(by_dev, collective_guard, fused_read):
-        """One ``fused_read`` per device bucket (>=2 captures — a lone
-        fold dispatches itself in finish); returns {gi: {bi: got}}."""
-        got_by: Dict[int, Dict[int, dict]] = {}
-        for dev, entries in by_dev.items():
-            if dev is None or len(entries) < 2:
-                continue
-            try:
-                with tracer.span("read_serve_fused", "device",
-                                 folds=len(entries)), \
-                        collective_guard(dev):
-                    outs = fused_read([s for _gi, _bi, s in entries])
-            except Exception:  # noqa: BLE001 — per-fold fallback
-                log.exception("fused serve read failed; falling "
-                              "back to per-type folds")
-                continue
-            for (gi, bi, _s), got in zip(entries, outs):
-                got_by.setdefault(gi, {})[bi] = got
-        return got_by
-
-    def _serve_group_seq(self, kind, waiters, fold_vc, fr_map,
-                         span_txid, collective_guard,
-                         fused_read) -> None:
-        """Sequential (blocking-begin) serve of one deferred group:
-        begin may flush and wait, the group's own captures still fuse
-        per device, finish runs in a finally."""
-        pm = self._pm
-        items = self._group_items(waiters)
-        with tracer.span("read_serve_fold", "device", txid=span_txid,
-                         keys=len(items)):
-            try:
-                out, batches = pm.read_many_begin(items, fold_vc,
-                                                  span_txid)
-            except Exception as e:  # noqa: BLE001 — fanned to waiters
-                for w in waiters:
-                    w.error = e
-                return
-        by_dev: Dict[Any, list] = {}
-        self._collect_splits(by_dev, 0, batches)
-        got_by = self._fuse(by_dev, collective_guard, fused_read)
-        self._finish_group((kind, waiters, fold_vc, fr_map, out,
-                            batches), got_by.get(0), span_txid)
-
-    def _finish_group(self, rec, got_map, span_txid=None) -> None:
-        """Stage-3 of one group: distribute the (possibly pre-fused)
-        fold results to the group's waiters, with the covered groups'
-        frontier-identity revalidation."""
-        pm = self._pm
-        kind, waiters, fold_vc, fr_map, out, batches = rec
-        try:
-            got = pm.read_many_finish(out, batches, fold_vc,
-                                      span_txid, got_map)
-        except Exception as e:  # noqa: BLE001 — fanned to waiters
+    def _distribute(self, kind, waiters, fr_map, got) -> List[_Waiter]:
+        """Hand one group's answer (its values, or the exception that
+        failed it) to its waiters; returns the covered waiters that
+        failed the frontier-identity revalidation and hold nothing
+        yet."""
+        if isinstance(got, BaseException):
             for w in waiters:
-                w.error = e
-            return
+                w.error = got
+            return []
         broken: List[_Waiter] = []
         if kind == "covered":
-            # frontier-identity revalidation: a publish between the
-            # classify snapshot and the fold capture may have put an
-            # op beyond a waiter's snapshot into the group fold
-            with pm._locked:
+            # a publish between the classify snapshot and the fold
+            # capture may have put an op beyond a waiter's snapshot
+            # into the group fold
+            with self._pm._locked:
                 for w in waiters:
-                    if any(pm.key_frontier.get(k) is not fr_map[k]
+                    if any(self._pm.key_frontier.get(k) is not fr_map[k]
                            for k, _t in w.items):
                         broken.append(w)
         for w in waiters:
-            if w in broken:
-                continue
-            w.values = {pair: got[pair] for pair in w.items}
-        for w in broken:
-            # rare: re-serve at the waiter's own exact VC (the legacy
-            # inclusion mask cannot over-include, whatever published);
-            # the waiter's txid rides along like the solo path's — the
-            # legacy own-prepared exclusion and trace joins survive
-            try:
-                w.values = pm.read_many(w.items, w.vc, txid=w.txid)
-            except Exception as e:  # noqa: BLE001 — per-waiter
-                w.error = e
+            if w not in broken:
+                w.values = {pair: got[pair] for pair in w.items}
+        return broken
 
 
 def read_groups(groups, snapshot_vc, txid=None) -> Dict:

@@ -33,6 +33,16 @@ class HybridClock:
         with self._lock:
             self._last = max(self._last, int(ts_us))
 
+    def _reading(self) -> int:
+        """The clock's reading: it issues nothing and bumps nothing."""
+        with self._lock:
+            return max(time.time_ns() // 1000, self._last)
+
+    def reached(self, ts_us: int) -> bool:
+        """True once wait_until(``ts_us``) would return at once; it
+        never turns False again."""
+        return self._reading() >= ts_us
+
     def wait_until(self, ts_us: int) -> None:
         """Block until the local clock passes ``ts_us`` (the reference's
         wait_for_clock spin, src/clocksi_interactive_coord.erl:915-926) —
@@ -44,8 +54,7 @@ class HybridClock:
         waiting for the wall to catch up would stall every read for the
         regression span."""
         while True:
-            with self._lock:
-                now = max(time.time_ns() // 1000, self._last)
+            now = self._reading()
             if now >= ts_us:
                 return
             time.sleep(min((ts_us - now) / 1e6, 0.01))

@@ -23,7 +23,8 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from antidote_tpu import stats
 from antidote_tpu.clocks import VC
-from antidote_tpu.mat.device_plane import DevicePlane, ReadBelowBase
+from antidote_tpu.mat.device_plane import (DevicePlane, ReadBelowBase,
+                                           collective_guard, fused_read)
 from antidote_tpu.mat.host_store import HostStore
 from antidote_tpu.mat.materializer import (
     MaterializedSnapshot,
@@ -896,20 +897,23 @@ class PartitionManager:
                 return True
         return False
 
-    def _await_unprepared(self, keys, snapshot_vc: VC, txid,
-                          what: str) -> None:
+    def _await_unprepared(self, keys, snapshot_vc: VC, txid, what: str,
+                          deadline: Optional[float] = None) -> None:
         """Under self._lock: wait (releasing it) until no prepared
         transaction may still commit one of ``keys`` below
         ``snapshot_vc`` (reference check_prepared,
         src/clocksi_readitem_server.erl:236-264); TimeoutError naming
-        ``what`` after ``read_wait_timeout``."""
+        ``what`` at ``deadline`` (``read_wait_timeout`` from now when
+        the caller brings none).  The caller holds no partition's
+        reader count: read_requests says why."""
         def blocked():
             return any(self._blocking_prepared(k, snapshot_vc, txid)
                        for k in keys)
 
         if not blocked():
             return
-        deadline = time.monotonic() + self.read_wait_timeout
+        if deadline is None:
+            deadline = time.monotonic() + self.read_wait_timeout
         with tracer.wait_span("pm_prepared_wait", "manager", txid=txid,
                               partition=self.partition):
             while blocked():
@@ -1121,47 +1125,76 @@ class PartitionManager:
     def read_many(self, items: List[Tuple[Any, str]], snapshot_vc,
                   txid=None) -> Dict[Tuple[Any, str], Any]:
         """Batched Clock-SI reads for THIS partition: one lock pass
-        gates and splits the keys (cache / device / host), then one
-        device fold PER TYPE runs outside the lock for all its keys —
-        the async-batched-reads pipelining of the reference coordinator
+        splits the keys (cache / device / host), then one device fold
+        PER TYPE runs outside the lock for all its keys — the
+        async-batched-reads pipelining of the reference coordinator
         (src/clocksi_interactive_coord.erl:731-747) fused with the
-        read-server concurrency split of :meth:`read`."""
-        out, dev_batches = self.read_many_begin(items, snapshot_vc,
-                                                txid)
-        return self.read_many_finish(out, dev_batches, snapshot_vc,
-                                     txid)
+        read-server concurrency split of :meth:`read`.  It is
+        :func:`read_requests` with one request."""
+        return read_many_fused([(self, items)], snapshot_vc, txid)
 
-    def read_many_begin(self, items, snapshot_vc, txid=None,
-                        nowait=False):
-        """First half of :meth:`read_many`: gate, split, flush, and
-        capture the device folds (reader counts INCREMENTED — the
-        caller MUST run read_many_finish exactly once, whatever
-        happens).  Split out so a multi-partition caller can fuse the
-        captured folds across partitions per chip (read_many_fused).
-
-        ``nowait=True`` returns None instead of blocking or flushing:
-        no prepared-txn wait, no device flush.  The cross-GROUP fused
-        drain (mat/serve.py) begins several groups before finishing
-        any, so its later begins hold earlier begins' reader counts —
-        a flush's quiesce wait here would deadlock on the caller's OWN
-        readers.  A None defers the group to a sequential pass after
-        the fused wave releases its readers."""
+    def read_gate(self, items, snapshot_vc, txid, deadline: float) -> None:
+        """Everything a batched read may have to WAIT for, and it holds
+        nothing when it returns: the clock wait, the prepared
+        transactions that may still commit one of the keys below the
+        snapshot (TimeoutError at ``deadline``, a time.monotonic()
+        reading), and the flush — with its quiesce wait — of every
+        plane that holds pending operations for a key.  The caller
+        must hold no partition's reader count (read_requests).  What
+        it found is not a promise: read_many_begin checks again."""
+        if time.monotonic() >= deadline:
+            raise TimeoutError("batched read blocked on prepared txn")
         if snapshot_vc is not None:
+            # outside the lock: it can be long and must not stall
+            # commits on this partition
             self.clock.wait_until(snapshot_vc.get_dc(self.dc_id))
+        with self._locked:
+            self._read_check()
+            if snapshot_vc is not None:
+                self._await_unprepared([k for k, _t in items],
+                                       snapshot_vc, txid, "batched read",
+                                       deadline)
+            by_type: Dict[str, list] = {}
+            for key, type_name in items:
+                if self.device is not None and self.device.owns(
+                        type_name, key):
+                    by_type.setdefault(type_name, []).append(key)
+            for type_name, keys_t in by_type.items():
+                plane = self.device.planes[type_name]
+                if not plane.pending_keys.isdisjoint(keys_t):
+                    # a flush donates buffers: readers of older
+                    # captures drain first
+                    self._wait_device_quiesce()
+                    plane.flush()
+
+    def read_many_begin(self, items, snapshot_vc, txid=None):
+        """CAPTURE, the first half of a batched read: under the lock,
+        serve the cache hits and the host keys, and capture the device
+        folds with the reader count INCREMENTED — the caller MUST run
+        read_many_finish exactly once, whatever happens.  It never
+        waits and never flushes.  Where the read is not ready — the
+        clock has not passed the snapshot, a prepared transaction may
+        still commit one of the keys below it (Clock-SI: a read at
+        ``s`` sees every commit at or below ``s``), or a plane holds
+        pending operations for a key it would fold — it returns None,
+        having taken and counted nothing: the caller releases what it
+        holds, waits in read_gate and captures again
+        (:func:`read_requests`).  Both checks are made in the lock
+        hold that makes the closures, whatever a gate found before."""
+        if snapshot_vc is not None and not self.clock.reached(
+                snapshot_vc.get_dc(self.dc_id)):
+            return None
         out: Dict[Tuple[Any, str], Any] = {}
         dev_batches = []  # (type, [(key, cacheable_frontier)], closure)
         with self._locked:
             self._read_check()
-            if snapshot_vc is not None:
-                keys = [k for k, _t in items]
-                if nowait and any(
-                        self._blocking_prepared(k, snapshot_vc, txid)
-                        for k in keys):
-                    return None
-                self._await_unprepared(keys, snapshot_vc, txid,
-                                       "batched read")
+            if snapshot_vc is not None and any(
+                    self._blocking_prepared(k, snapshot_vc, txid)
+                    for k, _t in items):
+                return None
             by_type: Dict[str, list] = {}
-            cache_hits = dev_misses = 0
+            host_items = []
+            cache_hits = 0
             for key, type_name in items:
                 fr = self.key_frontier.get(key)
                 covers = fr is not None and (
@@ -1175,30 +1208,24 @@ class PartitionManager:
                         continue
                 if self.device is not None and self.device.owns(
                         type_name, key):
-                    dev_misses += 1
                     by_type.setdefault(type_name, []).append(
                         (key, fr if covers else None,
                          self.device.state_exact(type_name, key)))
                 else:
-                    # _read_store counts its own cache hit/miss
-                    out[(key, type_name)] = self._read_store(
-                        key, type_name, snapshot_vc, txid)
+                    host_items.append((key, type_name))
+            for type_name, pairs in by_type.items():
+                if not self.device.planes[type_name].pending_keys \
+                        .isdisjoint([k for k, _fr, _ex in pairs]):
+                    return None
             if cache_hits:
                 stats.registry.read_cache_hits.inc(cache_hits)
-            if dev_misses:
-                stats.registry.read_cache_misses.inc(dev_misses)
-            # flush EVERY type first, then create closures: a flush is
-            # a buffer-donating device mutation, and quiescing for a
-            # later type would deadlock on our own earlier closure's
-            # reader count
-            for type_name, pairs in by_type.items():
-                plane = self.device.planes[type_name]
-                if not plane.pending_keys.isdisjoint(
-                        [k for k, _fr, _ex in pairs]):
-                    if nowait:
-                        return None  # no closures yet — nothing leaks
-                    self._wait_device_quiesce()
-                    plane.flush()
+            if by_type:
+                stats.registry.read_cache_misses.inc(
+                    sum(len(pairs) for pairs in by_type.values()))
+            for key, type_name in host_items:
+                # _read_store counts its own cache hit/miss
+                out[(key, type_name)] = self._read_store(
+                    key, type_name, snapshot_vc, txid)
             for type_name, pairs in by_type.items():
                 plane = self.device.planes[type_name]
                 keys_t = [k for k, _fr, _ex in pairs]
@@ -1629,87 +1656,120 @@ class PartitionManager:
             return self._read_store(key, type_name, clock)
 
 
+def read_requests(requests) -> list:
+    """Every batched read reaches the device through here.
+    ``requests`` is [(pm, items, snapshot_vc, txid)] over LOCAL
+    partitions (one partition may appear more than once: a serve
+    drain's groups); the answer holds, in the same order, each
+    request's {(key, type): value} or the exception that failed it.
+
+    THE RULE: a thread that holds a partition's ``_dev_readers`` count
+    waits for nothing a commit could be holding up, because every
+    device mutation (_wait_device_quiesce) waits under the partition
+    lock until that count is zero — a reader that waits on B for a
+    prepared transaction while it holds A's count stops that very
+    transaction's commit on A.  So a read captures nowhere while it
+    waits anywhere.  A wave tries read_many_begin, which never waits,
+    on every open request; the captures that share a chip — or the
+    Mesh of pod-sharded planes — run as ONE fused_read program (at
+    most n_devices * n_types programs for a read over P partitions, a
+    lone capture dispatches itself in finish); read_many_finish runs
+    for EVERY capture, fused or not, whatever failed (it is the one
+    place a count is given back, and a leak wedges every publish).
+    Only then, holding nothing, the thread stands in read_gate for
+    each request that was not ready, and the next wave tries those
+    again; a partition's ``read_wait_timeout`` runs from the first
+    wave."""
+    results: list = [None] * len(requests)
+    t_first = time.monotonic()
+    todo = list(range(len(requests)))
+    while todo:
+        captured = []  # (request index, out, dev_batches)
+        waiting = []
+        got_by: Dict[int, Dict[int, dict]] = {}
+        try:
+            for ri in todo:
+                pm, items, vc, txid = requests[ri]
+                try:
+                    with tracer.span("read_serve_fold", "device",
+                                     txid=txid, keys=len(items)):
+                        cap = pm.read_many_begin(items, vc, txid)
+                except Exception as e:  # noqa: BLE001 — this request's
+                    results[ri] = e
+                    continue
+                if cap is None:
+                    waiting.append(ri)
+                else:
+                    captured.append((ri, *cap))
+            got_by = _fuse_captures(captured)
+        finally:
+            interrupt = None
+            for ci, (ri, out, batches) in enumerate(captured):
+                pm, _items, vc, txid = requests[ri]
+                try:
+                    results[ri] = pm.read_many_finish(
+                        out, batches, vc, txid, got_by.get(ci))
+                except Exception as e:  # noqa: BLE001 — this request's
+                    results[ri] = e
+                except BaseException as e:  # noqa: BLE001 — re-raised
+                    interrupt = interrupt or e
+            if interrupt is not None:
+                raise interrupt
+        todo = []
+        for ri in waiting:
+            pm, items, vc, txid = requests[ri]
+            try:
+                pm.read_gate(items, vc, txid,
+                             t_first + pm.read_wait_timeout)
+            except Exception as e:  # noqa: BLE001 — this request's
+                results[ri] = e
+            else:
+                todo.append(ri)
+    return results
+
+
+def _fuse_captures(captured) -> Dict[int, Dict[int, dict]]:
+    """One ``fused_read`` per ``.device`` handle that two or more of
+    the captured folds share (a chip for a pinned plane; the Mesh for
+    a pod-sharded one — jax.sharding.Mesh compares by content, so
+    every sharded plane lands in one bucket, and the multi-chip
+    program serializes on COLLECTIVE_LOCK); returns {capture index:
+    {batch index: got}} for read_many_finish.  A bucket whose program
+    fails is left to its own closures."""
+    by_dev: Dict[Any, list] = {}
+    for ci, (_ri, _out, batches) in enumerate(captured):
+        for bi, (_t, _pairs, closure) in enumerate(batches):
+            split = getattr(closure, "split", None)
+            if split is not None:
+                by_dev.setdefault(getattr(closure, "device", None),
+                                  []).append((ci, bi, split))
+    got_by: Dict[int, Dict[int, dict]] = {}
+    for dev, entries in by_dev.items():
+        if dev is None or len(entries) < 2:
+            continue
+        try:
+            with tracer.span("read_serve_fused", "device",
+                             folds=len(entries)), \
+                    collective_guard(dev):
+                outs = fused_read([s for _ci, _bi, s in entries])
+        except Exception:  # noqa: BLE001 — per-fold fallback
+            log.exception("fused read failed; falling back to "
+                          "per-type folds")
+            continue
+        for (ci, bi, _s), got in zip(entries, outs):
+            got_by.setdefault(ci, {})[bi] = got
+    return got_by
+
+
 def read_many_fused(groups, snapshot_vc, txid=None
                     ) -> Dict[Tuple[Any, str], Any]:
-    """Multi-partition batched read with per-CHIP device dispatch:
-    ``groups`` is [(pm, items)] over LOCAL partitions; every captured
-    device fold landing on the same chip runs in ONE XLA program
-    (mat/device_plane.fused_read), so a read spanning P ring-placed
-    partitions issues at most n_devices * n_types programs instead of
-    P * n_types (round-4 verdict item 4: per-partition dispatch won't
-    scale to the 256-partition configs).  On a single-device node this
-    degenerates to one program for the whole read — strictly fewer
-    dispatches than the per-partition loop it replaces.
-
-    Begin/run/finish are split so reader counts stay balanced on every
-    path: each partition's read_many_begin increments its counts, and
-    read_many_finish (which always runs, fused result or not) releases
-    them."""
-    from antidote_tpu.mat.device_plane import (collective_guard,
-                                               fused_read)
-
-    begun = []  # (pm, out, dev_batches)
-    try:
-        for pm, items in groups:
-            out, dev_batches = pm.read_many_begin(items, snapshot_vc,
-                                                  txid)
-            begun.append((pm, out, dev_batches))
-    except BaseException:
-        # release the already-begun partitions' reader counts (their
-        # closures run un-fused; results discarded)
-        for pm, out, dev_batches in begun:
-            try:
-                pm.read_many_finish(out, dev_batches, snapshot_vc, txid)
-            except Exception:  # noqa: BLE001 — original error wins
-                pass
-        raise
-    # group fusible captures by chip.  BaseException here (interrupt
-    # mid-fuse) must still fall through to the finish loop below —
-    # every begun partition's reader counts are released there.
-    results: Dict[Tuple[int, int], dict] = {}
-    err = None
-    try:
-        by_dev: Dict[Any, list] = {}
-        for gi, (_pm, _out, batches) in enumerate(begun):
-            for bi, (_t, _pairs, closure) in enumerate(batches):
-                split = getattr(closure, "split", None) \
-                    if closure is not None else None
-                if split is not None:
-                    by_dev.setdefault(
-                        getattr(closure, "device", None), []).append(
-                            (gi, bi, split))
-        for dev, entries in by_dev.items():
-            if len(entries) < 2 or dev is None:
-                continue  # a lone fold dispatches itself in finish
-            try:
-                # ``dev`` is the Mesh handle when the partitions are
-                # pod-sharded (every sharded plane reports the same
-                # mesh, so the whole read is ONE multi-chip program)
-                # — which must serialize on COLLECTIVE_LOCK
-                with collective_guard(dev):
-                    outs = fused_read([s for _gi, _bi, s in entries])
-            except Exception:  # noqa: BLE001 — per-fold fallback
-                log.exception("fused cross-partition read failed; "
-                              "falling back to per-partition folds")
-                continue
-            for (gi, bi, _s), got in zip(entries, outs):
-                results[(gi, bi)] = got
-    except BaseException as e:  # noqa: BLE001 — re-raised below
-        err = e
+    """One snapshot read over ``groups`` = [(pm, items)], LOCAL
+    partitions: read_requests with one request a partition, merged;
+    the first failure is raised."""
     merged: Dict[Tuple[Any, str], Any] = {}
-    for gi, (pm, out, batches) in enumerate(begun):
-        got_map = {bi: results[(gi, bi)]
-                   for bi in range(len(batches))
-                   if (gi, bi) in results}
-        # EVERY begun partition's finish must run (it releases the
-        # reader counts begin took) — a failing partition must not
-        # leak its successors' counts; first error re-raises after
-        try:
-            merged.update(pm.read_many_finish(
-                out, batches, snapshot_vc, txid, got_map))
-        except BaseException as e:  # noqa: BLE001 — re-raised below
-            if err is None:
-                err = e
-    if err is not None:
-        raise err
+    for got in read_requests([(pm, items, snapshot_vc, txid)
+                              for pm, items in groups]):
+        if isinstance(got, BaseException):
+            raise got
+        merged.update(got)
     return merged
