@@ -500,6 +500,13 @@ class Registry:
             "antidote_read_cache_misses_total",
             "Snapshot reads that missed the value cache and paid a "
             "materialization (device fold / host store / log replay)")
+        self.update_state_reads = Counter(
+            "antidote_update_state_reads_total",
+            "Keys whose state an update's downstream generation read, "
+            "by path: batched (one snapshot read a call, through the "
+            "read serve plane) or single (the partition's exact "
+            "single-key read: lossy folds, maps, counter_b, remote)",
+            labels=("path",))
         self.read_waiters_per_dispatch = Gauge(
             "antidote_read_waiters_per_dispatch",
             "Amortization ratio of the read serve plane: waiters "
@@ -957,6 +964,7 @@ class Registry:
                 self.read_dispatches, self.read_serve_groups,
                 self.read_serve_waiters, self.read_coalesced_keys,
                 self.read_cache_hits, self.read_cache_misses,
+                self.update_state_reads,
                 self.read_waiters_per_dispatch,
                 self.log_fsyncs, self.log_group_records,
                 self.log_group_drains, self.log_group_size,

@@ -28,6 +28,7 @@ from antidote_tpu.clocks import VC
 from antidote_tpu.obs.events import recorder
 from antidote_tpu.obs.spans import traced, tracer
 from antidote_tpu.crdt import DownstreamCtx, DownstreamError, get_type, is_type
+from antidote_tpu.mat.device_plane import DevicePlane
 from antidote_tpu.mat.materializer import materialize_eager
 from antidote_tpu.txn.manager import (
     _RAW_OP,
@@ -35,6 +36,17 @@ from antidote_tpu.txn.manager import (
     PartitionManager,
     _is_raw,
 )
+
+
+#: types whose state an update's downstream takes from the partition's
+#: single-key exact read, never from the batched snapshot read: the
+#: device fold of a STATE_LOSSY type collapses dots its effect has to
+#: cancel; whether a map's fold is exact depends on its resident
+#: fields, which only a hold of the partition lock can say and a commit
+#: can change before the fold runs; counter_b's rights go to the
+#: bcounter manager as that read gives them
+_EXACT_READ_ONLY = DevicePlane.STATE_LOSSY | {"map_go", "map_rr",
+                                              "counter_b"}
 
 
 def _batch_never_ran(exc) -> bool:
@@ -562,28 +574,39 @@ class Coordinator:
             raise
 
     def _apply_updates(self, tx: Transaction, updates: List) -> None:
+        """One call's updates as a batch: every operation checked, the
+        state the state-requiring ones need read ONCE, the downstreams
+        generated in the caller's order, then one stage a partition."""
+        node = self.node
+        # pass 1, no side effects: the first bad operation aborts the
+        # transaction before anything is read, staged or logged
+        plan = []  # (bucket, key, cls, op, pm, needs_state)
         for upd in updates:
-            bo, op_name, op_param = self.node.normalize_update(upd)
-            key, type_name, bucket = self.node.normalize_bound(bo)
+            bo, op_name, op_param = node.normalize_update(upd)
+            key, type_name, bucket = node.normalize_bound(bo)
             cls = get_type(type_name) if is_type(type_name) else None
             op = (op_name, op_param)
             if cls is None or not cls.is_operation(op):
                 # abort like the hook/downstream failure paths below —
-                # leaving the txn ACTIVE would leak staged effects and
-                # the open-transactions gauge
+                # leaving the txn ACTIVE would leak the open-
+                # transactions gauge
                 self.abort_transaction(tx)
                 raise TypeError(f"type_check failed: {type_name} {op!r}")
             try:
-                key2, type_name2, op = self.node.hooks.run_pre(
+                key, type_name, op = node.hooks.run_pre(
                     bucket, key, type_name, op)
             except Exception as e:
                 self.abort_transaction(tx)
                 raise TransactionAborted(f"pre-commit hook failed: {e}") from e
-            cls = get_type(type_name2)
-            pm = self.node.partition_of(key2)
+            cls = get_type(type_name)
+            plan.append((bucket, key, cls, op, node.partition_of(key),
+                         cls.require_state_downstream(op)))
+        base = self._read_update_states(tx, plan)
+        # pass 2, in the caller's order
+        staged: Dict[Any, List[Tuple]] = {}  # local partition -> effects
+        for bucket, key, cls, op, pm, needs_state in plan:
             remote = getattr(pm, "deferred_stage", False)
-            if (remote and cls.require_state_downstream(op)
-                    and cls.name != "counter_b"):
+            if remote and needs_state and cls.name != "counter_b":
                 # REMOTE + state-requiring: ship the raw op and let the
                 # OWNER generate downstream against its local
                 # materialized state (the reference generates at the
@@ -593,36 +616,77 @@ class Coordinator:
                 # downstream consults the bcounter permission manager,
                 # which lives with the coordinator's node.
                 tx.deferred_ops.setdefault(pm.partition, []).append(
-                    (key2, cls.name, (_RAW_OP, op)))
-                tx.raw_keys.add(key2)
-                if pm.partition not in tx.partitions:
-                    tx.partitions.append(pm.partition)
-                tx.client_ops.append((bucket, key2, cls.name, op))
-                continue
-            try:
-                state = None
-                if cls.require_state_downstream(op):
-                    # exact_state: an effect built from the device fold's
-                    # per-DC dot collapse would under-cancel at exact
-                    # replicas (set_rw/flag_dw) — see DevicePlane.state_exact
-                    state = pm.read_with_writeset(
-                        key2, cls.name, tx.snapshot_vc, tx.txid,
-                        tx.own_effects(key2), exact_state=True)
-                effect = self.node.gen_downstream(
-                    cls, op, state, tx.ctx, key=key2, bucket=bucket)
-            except DownstreamError as e:
-                self.abort_transaction(tx)
-                raise TransactionAborted(f"downstream failed: {e}") from e
-            if remote:
-                tx.deferred_ops.setdefault(pm.partition, []).append(
-                    (key2, cls.name, effect))
+                    (key, cls.name, (_RAW_OP, op)))
+                tx.raw_keys.add(key)
             else:
-                pm.stage_update(tx.txid, key2, cls.name, effect)
-            entry = tx.writeset.setdefault(key2, (cls.name, []))
-            entry[1].append(effect)
+                try:
+                    state = None
+                    if needs_state:
+                        state = self._update_state(tx, base, pm, key, cls)
+                    effect = node.gen_downstream(
+                        cls, op, state, tx.ctx, key=key, bucket=bucket)
+                except DownstreamError as e:
+                    self.abort_transaction(tx)
+                    raise TransactionAborted(
+                        f"downstream failed: {e}") from e
+                if remote:
+                    tx.deferred_ops.setdefault(pm.partition, []).append(
+                        (key, cls.name, effect))
+                else:
+                    staged.setdefault(pm, []).append(
+                        (key, cls.name, effect))
+                tx.writeset.setdefault(key, (cls.name, []))[1].append(
+                    effect)
             if pm.partition not in tx.partitions:
                 tx.partitions.append(pm.partition)
-            tx.client_ops.append((bucket, key2, cls.name, op))
+            tx.client_ops.append((bucket, key, cls.name, op))
+        for pm, ops in staged.items():
+            # one lock hold a partition, log records in operation order
+            pm.stage_group(tx.txid, ops)
+
+    def _read_update_states(self, tx: Transaction, plan) -> Dict:
+        """{(key, type): state at the snapshot} for the distinct keys of
+        ``plan`` whose downstream needs the state and whose state ONE
+        batched snapshot read may give it: local keys of a type whose
+        fold is exact (_EXACT_READ_ONLY).  It is read_objects' call for
+        its local groups, so the read coalesces with the drain that is
+        forming, goes direct and fused when every window is idle, is
+        answered by the value cache where that holds the key, and waits
+        nowhere while it holds a reader count (manager.read_requests)."""
+        by_pm: Dict[Any, dict] = {}  # partition -> {(key, type): None}
+        for _bucket, key, cls, _op, pm, needs_state in plan:
+            if (needs_state and isinstance(pm, PartitionManager)
+                    and cls.name not in _EXACT_READ_ONLY):
+                by_pm.setdefault(pm, {})[(key, cls.name)] = None
+        if not by_pm:
+            return {}
+        from antidote_tpu.mat.serve import read_groups
+
+        n_keys = sum(len(items) for items in by_pm.values())
+        with tracer.span("txn_state_read", "coordinator", txid=tx.txid,
+                         keys=n_keys, partitions=len(by_pm)):
+            base = read_groups(
+                [(pm, list(items)) for pm, items in by_pm.items()],
+                tx.snapshot_vc, txid=tx.txid)
+        stats.registry.update_state_reads.inc(n_keys, path="batched")
+        return base
+
+    def _update_state(self, tx: Transaction, base: Dict, pm, key, cls):
+        """The state one update's downstream is generated from: the
+        snapshot's, with the transaction's own earlier effects on the
+        key replayed over it (a key updated twice in one call, or
+        already in the writeset, sees its predecessors)."""
+        own = tx.own_effects(key)
+        if (key, cls.name) in base:
+            state = base[(key, cls.name)]
+            return materialize_eager(cls.name, state, own) if own \
+                else state
+        # exact_state: an effect built from the device fold's per-DC
+        # dot collapse would under-cancel at exact replicas
+        # (set_rw/flag_dw) — see DevicePlane.state_exact
+        stats.registry.update_state_reads.inc(path="single")
+        return pm.read_with_writeset(key, cls.name, tx.snapshot_vc,
+                                     tx.txid, own, exact_state=True)
 
     def _materialize_raw_ops(self, tx: Transaction, key) -> None:
         """Convert a key's pending raw ops into effects at the

@@ -9,7 +9,12 @@ timeout.  Alone on this machine the longest wait is under 1 s; with the
 suite's other workers on the same cores a committer that waits for
 readers to leave the device has been seen to wait 1.0-1.5 s (readers
 share the count, so a writer can be passed), hence a bound of 2.5 s:
-half of what the deadlock's waits last."""
+half of what the deadlock's waits last.
+
+PR 29's case: the committers' ten keys hold six sets, added to and
+removed from, so every update makes the coordinator's one batched read
+of the state its downstreams need (``txn_state_read``) beside the
+readers' drains and the other committers' multi-partition commits."""
 
 import random
 import sys
@@ -18,6 +23,7 @@ import time
 
 import pytest
 
+from antidote_tpu import stats
 from antidote_tpu.api import AntidoteTPU
 from antidote_tpu.config import Config
 from antidote_tpu.crdt import get_type
@@ -30,15 +36,25 @@ READERS = WRITERS = 8
 READS_EACH, UPDATES_EACH = 120, 130      # 2,000 transactions
 
 
-def update_of(rng, obj):
+def update_of(rng, obj, removes=False):
     if obj[1] == CK:
         return (obj, "increment", rng.randint(1, 99))
-    return (obj, "add", rng.randint(0, 5))
+    op = rng.choice(["add", "remove"]) if removes else "add"
+    return (obj, op, rng.randint(0, 5))
 
 
-@pytest.mark.parametrize("seed", [28])
+def keys_of(rng, set_keys):
+    """A committer's ten keys: as they fall, or ``set_keys`` of them
+    sets."""
+    if set_keys is None:
+        return rng.sample(KEYS, 10)
+    return rng.sample(KEYS[300:], set_keys) \
+        + rng.sample(KEYS[:300], 10 - set_keys)
+
+
+@pytest.mark.parametrize("seed,set_keys", [(28, None), (29, 6)])
 def test_no_read_fails_or_is_wrong_and_no_wait_nears_the_timeout(
-        tmp_path, seed):
+        tmp_path, seed, set_keys):
     db = AntidoteTPU(dc_id="dc1", data_dir=str(tmp_path / "d"),
                      config=Config(n_partitions=4, metrics_port=None,
                                    device_lanes=64))
@@ -62,6 +78,9 @@ def test_no_read_fails_or_is_wrong_and_no_wait_nears_the_timeout(
 
             setattr(pm, name, timed)
     reads, errors, aborts = [], [], []
+    state_reads = stats.registry.update_state_reads
+    batched0 = state_reads.value(path="batched")
+    single0 = state_reads.value(path="single")
     # a reader reads at the newest commit clock any committer was
     # answered with (a session handed on): such a snapshot lies above
     # the prepare time of a transaction still committing, which the
@@ -90,7 +109,8 @@ def test_no_read_fails_or_is_wrong_and_no_wait_nears_the_timeout(
         start.wait()
         clock = None
         for _ in range(UPDATES_EACH):
-            updates = [update_of(r, o) for o in r.sample(KEYS, 10)]
+            updates = [update_of(r, o, removes=set_keys is not None)
+                       for o in keys_of(r, set_keys)]
             for _attempt in range(200):
                 try:
                     clock = db.update_objects_static(clock, updates)
@@ -123,6 +143,11 @@ def test_no_read_fails_or_is_wrong_and_no_wait_nears_the_timeout(
     assert errors == []
     assert len(reads) == READERS * READS_EACH
     assert [pm._dev_readers for pm in pms] == [0, 0, 0, 0]
+    # every set key of every attempt read its state in the one batch
+    assert state_reads.value(path="single") == single0
+    if set_keys is not None:
+        assert state_reads.value(path="batched") - batched0 == set_keys * (
+            WRITERS * UPDATES_EACH + len(aborts))
     assert max(waits, default=0.0) < 2.5, sorted(waits)[-5:]
     # every value of every read against the host materializer's log
     # replay at the snapshot the read returned

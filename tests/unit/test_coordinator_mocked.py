@@ -40,6 +40,11 @@ class FakePartition:
         self.calls.append(("stage", txid, key))
         self.staged.setdefault(txid, []).append((key, type_name, effect))
 
+    def stage_group(self, txid, ops, snapshot_vc=None):
+        # the coordinator stages a call's effects once per partition
+        for key, type_name, effect in ops:
+            self.stage_update(txid, key, type_name, effect)
+
     def read_with_writeset(self, key, type_name, snapshot_vc, txid,
                            own_effects, exact_state=False):
         self.calls.append(("read", key))
