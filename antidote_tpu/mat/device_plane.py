@@ -371,29 +371,25 @@ class _PlaneBase:
 
         _start_warm(run, f"warm:{self.type_name}")
 
-    def warm_reads(self, buckets: tuple = (1, 64)) -> None:
+    def warm_reads(self) -> None:
         """Background-compile this plane's READ fold at the CURRENT
         state shapes.  The first read after a capacity growth
         recompiles the fold on whatever client thread issued it —
         measured 0.35-1 s inline (the dominant config6 p99 spike
         together with the growth itself); warming runs it on a copy in
-        a compile thread instead.  Buckets cover the single-key reader
-        (shape 1) and the first batched-dispatch bucket."""
+        a compile thread instead.  One program: the first dispatch
+        bucket, which is also what one key pads to (:meth:`read`)."""
         if self._mesh is not None:
             return  # see warm_appends: no background mesh dispatches
         shapes = tuple(
             (tuple(x.shape), str(getattr(x, "dtype", "")))
             for x in jax.tree_util.tree_leaves(self.st))
-        base_key = ("read", id(type(self)), shapes)
-        todo = []
+        b = _bucket(1)
+        k = ("read", id(type(self)), shapes, b)
         with _WARM_LOCK:
-            for b in buckets:
-                k = base_key + (b,)
-                if k not in _WARMED:
-                    _WARMED.add(k)
-                    todo.append(b)
-        if not todo:
-            return
+            if k in _WARMED:
+                return
+            _WARMED.add(k)
         try:
             rv = self._read_vc_dense(None)
         except ReadBelowBase:  # pragma: no cover — latest never raises
@@ -401,24 +397,19 @@ class _PlaneBase:
         # reads are pure but appends DONATE the state buffers — warm on
         # a copy taken here, under the caller's partition lock
         st_copy = jax.tree_util.tree_map(jnp.copy, self.st)
-        specs = []
-        for b in todo:
-            pad = np.zeros(b, dtype=np.int32)
-            try:
-                spec, _post = self._many_split(
-                    st_copy, [], np.zeros(0, dtype=np.int32), pad, rv)
-            except NotImplementedError:
-                return  # per-document planes (RGA) have no batch fold
-            specs.append(spec)
+        try:
+            (fn, args), _post = self._many_split(
+                st_copy, [], np.zeros(0, dtype=np.int32),
+                np.zeros(b, dtype=np.int32), rv)
+        except NotImplementedError:
+            return  # per-document planes (RGA) have no batch fold
 
         def run():
-            for fn, args in specs:
-                try:
-                    jax.block_until_ready(fn(*args))
-                except Exception:  # noqa: BLE001 — warm is best-effort
-                    log.warning("read warm failed (%s)", self.type_name,
-                                exc_info=True)
-                    return
+            try:
+                jax.block_until_ready(fn(*args))
+            except Exception:  # noqa: BLE001 — warm is best-effort
+                log.warning("read warm failed (%s)", self.type_name,
+                            exc_info=True)
 
         _start_warm(run, f"warm-read:{self.type_name}")
 
@@ -592,46 +583,19 @@ class _PlaneBase:
 
     # -- lock-free read split ------------------------------------------------
 
-    def read_begin(self, key, read_vc: Optional[VC]):
-        """MUST run under the partition lock: flush the key's staged
-        rows, resolve directories, and capture the (immutable) device
-        state.  Returns a zero-arg closure that materializes the value
-        and may run OUTSIDE the lock — the shard state is a functional
-        pytree, so a concurrent flush/GC only swaps ``self.st`` with a
-        new value and never mutates what the closure captured.  This is
+    def read_many_begin(self, keys: list, read_vc: Optional[VC]):
+        """The capture of every read, one key or many.  MUST run under
+        the partition lock: flush the keys' staged rows, resolve
+        directories, and capture the (immutable) device state — one
+        captured state + one device fold for every device-owned key in
+        ``keys``.  Returns a closure yielding {key: value} (non-owned
+        keys absent — callers serve them from the host path) that may
+        run OUTSIDE the lock: the shard state is a functional pytree,
+        so a concurrent flush/GC only swaps ``self.st`` with a new
+        value and never mutates what the closure captured.  This is
         the read-concurrency analogue of the reference's shared-ETS
         readers next to the vnode process (reference
         src/clocksi_readitem_server.erl:95-110)."""
-        if key in self.pending_keys:
-            self.flush("read")
-        idx = self.key_index.get(key)
-        if idx is None:
-            raise ReadBelowBase()  # evicted during the flush — host path
-        rv = self._read_vc_dense(read_vc)
-        st = self.st
-        r = self._reader(st, idx, rv)
-        if self._mesh is None:
-            return r
-
-        def locked_read():
-            # mesh-sharded: the fold is a multi-chip launch — same
-            # serialization rule as the appends (runtime.py invariant)
-            with COLLECTIVE_LOCK:
-                return r()
-
-        return locked_read
-
-    def _reader(self, st, idx: int, rv):
-        """Subclass hook: closure materializing key ``idx`` of the
-        captured state at dense snapshot ``rv``."""
-        raise NotImplementedError
-
-    def read_many_begin(self, keys: list, read_vc: Optional[VC]):
-        """Batched :meth:`read_begin`: one captured state + one device
-        fold for every device-owned key in ``keys``.  Returns a closure
-        yielding {key: value} (non-owned keys absent — callers serve
-        them from the host path); safe to run outside the lock like
-        read_begin's closure."""
         if self.pending_keys and not self.pending_keys.isdisjoint(keys):
             self.flush("read")
         owned = [k for k in keys if k in self.key_index]
@@ -701,10 +665,15 @@ class _PlaneBase:
         return self.read_many_begin(keys, read_vc)()
 
     def read(self, key, read_vc: Optional[VC]):
-        """The key's host-CRDT state at ``read_vc``, materialized by
-        this plane's device fold (state shape documented on each
-        subclass's ``_reader`` hook)."""
-        return self.read_begin(key, read_vc)()
+        """The key's host-CRDT state at ``read_vc``: the batched read
+        with one key in it (state shape as each subclass's
+        ``_many_split`` decodes it).  ReadBelowBase where the key is
+        not this plane's once its staged rows are flushed — evicted,
+        or never here: the caller takes the host/log path."""
+        got = self.read_many([key], read_vc)
+        if key not in got:
+            raise ReadBelowBase()
+        return got[key]
 
     def seed_effects(self, state) -> Optional[list]:
         """Effects that rebuild ``state`` exactly from bottom when
@@ -715,12 +684,11 @@ class _PlaneBase:
         this plane cannot represent a bare state as effects (RGA's
         per-document trees, the STATE_LOSSY dot collapses) — the key
         stays on the host path, exactly the pre-seed behavior.  The
-        round trip is the inverse of ``_reader``/the evict export:
+        round trip is the inverse of the read/the evict export:
         seed_effects(read()) staged onto an empty plane reads back
         identical (pinned per type by tests/unit/test_ckpt_segments
         .py)."""
         return None
-
 
     # -- lifecycle ----------------------------------------------------------
 
@@ -1068,7 +1036,7 @@ class OrsetPlane(_PlaneBase):
     def seed_effects(self, state):
         # state: {elem: frozenset((actor, seq))} — one add per live
         # dot, empty observed set (removes nothing): the union of dots
-        # IS the state, exactly what _reader reconstructs.  One ROW
+        # IS the state, exactly what the read reconstructs.  One ROW
         # per effect, so the seeder can chunk-fold dot-heavy keys
         # against the per-key lane budget.
         return [("add", [(elem, dot, ())])
@@ -1083,33 +1051,10 @@ class OrsetPlane(_PlaneBase):
     def _device_gc(self, gst_dense):
         self.st = store.orset_gc(self.st, jnp.asarray(gst_dense))
 
-
-    def _reader(self, st, idx, rv):
-        # captured under the lock; safe after release (see read_begin):
-        # rev_elems[idx] / dc_ids are append-only, st is immutable
-        elems = self.rev_elems[idx]
-        domain = self.domain
-
-        def run():
-            dots = np.asarray(store.orset_read_keys(
-                st, jnp.asarray([idx], dtype=np.int32),
-                jnp.asarray(rv))[0])
-            actors = domain.dc_ids
-            state = {}
-            for slot, elem in enumerate(list(elems)):
-                if slot >= dots.shape[0]:
-                    break  # slot grown after the capture: no dots yet
-                live = frozenset(
-                    (actors[j], int(s))
-                    for j, s in enumerate(dots[slot][:len(actors)])
-                    if s > 0)
-                if live:
-                    state[elem] = live
-            return state
-
-        return run
-
     def _many_split(self, st, owned, idxs, pad, rv):
+        # captured under the lock; safe after release (see
+        # read_many_begin): rev_elems[i] / dc_ids are append-only, st
+        # is immutable
         elem_lists = [self.rev_elems[i] for i in idxs]
         domain = self.domain
 
@@ -1120,7 +1065,7 @@ class OrsetPlane(_PlaneBase):
                 state = {}
                 for slot, elem in enumerate(list(elem_lists[i])):
                     if slot >= dots.shape[1]:
-                        break  # slot grown after the capture
+                        break  # slot grown after the capture: no dots yet
                     live = frozenset(
                         (actors[j], int(s))
                         for j, s in enumerate(dots[i, slot][:len(actors)])
@@ -1175,11 +1120,6 @@ class CounterPlane(_PlaneBase):
     def _device_gc(self, gst_dense):
         self.st = store.counter_gc(self.st, jnp.asarray(gst_dense))
 
-
-    def _reader(self, st, idx, rv):
-        return lambda: int(store.counter_read_keys(
-            st, jnp.asarray([idx], dtype=np.int32), jnp.asarray(rv))[0])
-
     def _many_split(self, st, owned, idxs, pad, rv):
         def post(vals):
             return {k: int(vals[i]) for i, k in enumerate(owned)}
@@ -1232,27 +1172,6 @@ class MvregPlane(OrsetPlane):
 
     def _device_gc(self, gst_dense):
         self.st = store.mvreg_gc(self.st, jnp.asarray(gst_dense))
-
-
-    def _reader(self, st, idx, rv):
-        vals = self.rev_elems[idx]
-        domain = self.domain
-
-        def run():
-            dots = np.asarray(store.mvreg_read_keys(
-                st, jnp.asarray([idx], dtype=np.int32),
-                jnp.asarray(rv))[0])
-            actors = domain.dc_ids
-            pairs = set()
-            for slot, v in enumerate(list(vals)):
-                if slot >= dots.shape[0]:
-                    break
-                for j, s in enumerate(dots[slot][:len(actors)]):
-                    if s > 0:
-                        pairs.add(((actors[j], int(s)), v))
-            return frozenset(pairs)
-
-        return run
 
     def _many_split(self, st, owned, idxs, pad, rv):
         val_lists = [self.rev_elems[i] for i in idxs]
@@ -1317,20 +1236,6 @@ class FlagEwPlane(OrsetPlane):
         # un-observed enable per dot
         return [("en", dot, ()) for dot in state]
 
-    def _reader(self, st, idx, rv):
-        domain = self.domain
-
-        def run():
-            dots = np.asarray(store.orset_read_keys(
-                st, jnp.asarray([idx], dtype=np.int32),
-                jnp.asarray(rv))[0])
-            actors = domain.dc_ids
-            return frozenset(
-                (actors[j], int(s))
-                for j, s in enumerate(dots[0][:len(actors)]) if s > 0)
-
-        return run
-
     def _many_split(self, st, owned, idxs, pad, rv):
         domain = self.domain
 
@@ -1364,7 +1269,8 @@ class RwsetPlane(OrsetPlane):
     value level for this type.  Because of the collapse the type is in
     DevicePlane.STATE_LOSSY: downstream generation never reads this
     fold — require_state_downstream reads take an exact log replay
-    (PartitionManager.read(exact_state=True))."""
+    (``exact_state``, an argument of the read's capture:
+    PartitionManager.read_many_begin)."""
 
     type_name = "set_rw"
     # (slot, kind, dot_dc, dot_seq, obs_add, obs_rmv, op_dc, op_ct, op_ss)
@@ -1441,28 +1347,6 @@ class RwsetPlane(OrsetPlane):
             (actors[j], int(s))
             for j, s in enumerate(row[:len(actors)]) if s > 0)
 
-
-    def _reader(self, st, idx, rv):
-        elems = self.rev_elems[idx]
-        domain = self.domain
-
-        def run():
-            adds, rmvs = store.rwset_read_keys(
-                st, jnp.asarray([idx], dtype=np.int32), jnp.asarray(rv))
-            adds, rmvs = np.asarray(adds)[0], np.asarray(rmvs)[0]
-            actors = domain.dc_ids
-            state = {}
-            for slot, elem in enumerate(list(elems)):
-                if slot >= adds.shape[0]:
-                    break  # slot grown after the capture
-                a = self._dots_of(adds[slot], actors)
-                r = self._dots_of(rmvs[slot], actors)
-                if a or r:
-                    state[elem] = (a, r)
-            return state
-
-        return run
-
     def _many_split(self, st, owned, idxs, pad, rv):
         elem_lists = [self.rev_elems[i] for i in idxs]
         domain = self.domain
@@ -1527,19 +1411,6 @@ class FlagDwPlane(RwsetPlane):
         self._commit_rows(key, idx, [
             (idx, 0, kind, dot_col, int(seq), oa, orm, op_dc_col,
              int(payload.commit_time), ss_pairs)])
-
-
-    def _reader(self, st, idx, rv):
-        domain = self.domain
-
-        def run():
-            adds, rmvs = store.rwset_read_keys(
-                st, jnp.asarray([idx], dtype=np.int32), jnp.asarray(rv))
-            actors = domain.dc_ids
-            return (self._dots_of(np.asarray(adds)[0, 0], actors),
-                    self._dots_of(np.asarray(rmvs)[0, 0], actors))
-
-        return run
 
     def _many_split(self, st, owned, idxs, pad, rv):
         domain = self.domain
@@ -1615,20 +1486,6 @@ class SetGoPlane(OrsetPlane):
 
     def _device_gc(self, gst_dense):
         self.st = store.setgo_gc(self.st, jnp.asarray(gst_dense))
-
-
-    def _reader(self, st, idx, rv):
-        elems = self.rev_elems[idx]
-
-        def run():
-            present = np.asarray(store.setgo_read_keys(
-                st, jnp.asarray([idx], dtype=np.int32),
-                jnp.asarray(rv))[0])
-            return frozenset(
-                e for slot, e in enumerate(list(elems))
-                if slot < present.shape[0] and present[slot])
-
-        return run
 
     def _many_split(self, st, owned, idxs, pad, rv):
         elem_lists = [self.rev_elems[i] for i in idxs]
@@ -1774,27 +1631,10 @@ class LwwPlane(_PlaneBase):
     def _device_gc(self, gst_dense):
         self.st = store.lww_gc(self.st, jnp.asarray(gst_dense))
 
-
-    def _reader(self, st, idx, rv):
+    def _many_split(self, st, owned, idxs, pad, rv):
         # actors_sorted is REPLACED wholesale on a rank repack (which
         # also repacks st under the same lock) — capturing the list here
         # keeps ranks and state consistent after the lock is released
-        acts = self.actors_sorted
-        vals = self.rev_vals
-
-        def run():
-            ts, tie, val = (np.asarray(a) for a in store.lww_read_keys(
-                st, jnp.asarray([idx], dtype=np.int32), jnp.asarray(rv)))
-            if val[0] < 0:
-                return (0, (), None)  # unwritten at this snapshot
-            rank = int(tie[0]) >> _TIE_SHIFT
-            seq = int(tie[0]) & _TIE_SEQ_MAX
-            return (int(ts[0]), (acts[rank], seq), vals[int(val[0])])
-
-        return run
-
-    def _many_split(self, st, owned, idxs, pad, rv):
-        # consistent with the captured state (see LwwPlane._reader)
         acts = self.actors_sorted
         vals = self.rev_vals
 
@@ -2081,6 +1921,10 @@ class RgaPlane(_PlaneBase):
     # -- read path ----------------------------------------------------------
 
     def _reader(self, st, idx, rv):
+        """Closure folding document ``idx`` of the captured state at
+        dense snapshot ``rv`` — this plane's ONLY reader: documents are
+        independent trees with no cross-key fold, so ``_many_reader``
+        is one of these a key and :meth:`read` a batch of one."""
         from antidote_tpu.mat import rga_store
 
         sti = st[idx]
@@ -2104,6 +1948,10 @@ class RgaPlane(_PlaneBase):
         return run
 
     def _many_reader(self, st, owned, idxs, pad, rv):
+        """Documents fold one device call each (independent trees — no
+        cross-key batching), so the base capture's padded indices go
+        unused and the batch is a reader per owned key, with no
+        ``.split`` to fuse."""
         readers = [(k, self._reader(st, int(i), rv))
                    for k, i in zip(owned, idxs)]
 
@@ -2111,20 +1959,6 @@ class RgaPlane(_PlaneBase):
             return {k: r() for k, r in readers}
 
         return run
-
-    def read_many_begin(self, keys: list, read_vc: Optional[VC]):
-        """Documents fold one device call each (independent trees — no
-        cross-key batching), so the base's padded-idx plumbing reduces
-        to a reader per owned key."""
-        if self.pending_keys and not self.pending_keys.isdisjoint(keys):
-            self.flush("read")
-        owned = [k for k in keys if k in self.key_index]
-        if not owned:
-            return dict
-        rv = self._read_vc_dense(read_vc)
-        idxs = np.asarray([self.key_index[k] for k in owned],
-                          dtype=np.int32)
-        return self._many_reader(self.st, owned, idxs, idxs, rv)
 
 
 class MapPlane:
@@ -2431,13 +2265,12 @@ class MapPlane:
     # -- read path ----------------------------------------------------------
 
     def read_many_begin(self, keys: list, read_vc: Optional[VC]):
-        """Lock-held capture (see _PlaneBase.read_begin): synthetic keys
-        of ALL requested maps are grouped so each nested type costs ONE
-        batched sub-fold (plus one presence fold for map_go) regardless
-        of how many maps the transaction reads — the same
-        one-fold-per-type batching the flat planes get from
-        read_many_begin.  The closure reassembles per-map states outside
-        the lock."""
+        """Lock-held capture (see _PlaneBase.read_many_begin): synthetic
+        keys of ALL requested maps are grouped so each nested type
+        costs ONE batched sub-fold (plus one presence fold for map_go)
+        regardless of how many maps the transaction reads — the same
+        one-fold-per-type batching the flat planes get.  The closure
+        reassembles per-map states outside the lock."""
         owned = [k for k in keys if k in self.fields]
         if not owned:
             return dict
@@ -2491,21 +2324,15 @@ class MapPlane:
 
         return run
 
-    def read_begin(self, key, read_vc: Optional[VC]):
-        cl = self.read_many_begin([key], read_vc)
-        if key not in self.fields:
-            # evicted during the begin-flush — host/log path, exactly
-            # the flat planes' contract (_PlaneBase.read_begin)
-            raise ReadBelowBase()
-        return lambda: cl()[key]
+    def read_many(self, keys: list, read_vc: Optional[VC]) -> dict:
+        return self.read_many_begin(keys, read_vc)()
 
     def read(self, key, read_vc: Optional[VC]):
         """Map host state ({(field, nested_type): nested_state}) at
-        ``read_vc``."""
-        return self.read_begin(key, read_vc)()
-
-    def read_many(self, keys: list, read_vc: Optional[VC]) -> dict:
-        return self.read_many_begin(keys, read_vc)()
+        ``read_vc``: the batched read with one map in it, and
+        ReadBelowBase where the map is not on the device — the flat
+        planes' contract, in their words."""
+        return _PlaneBase.read(self, key, read_vc)
 
 
 class DevicePlane:
@@ -2884,21 +2711,20 @@ class DevicePlane:
 
     def read(self, key, type_name: str, read_vc: Optional[VC],
              txid=None):
-        # txid-tagged so the per-read span joins its txn's tree and
-        # obeys per-txid sampling; untagged reads fall back to
-        # sampled()'s 1-in-N thinning instead of flooding the ring
-        with tracer.span("device_read", "device", txid=txid, key=key,
-                         type=type_name):
-            t0 = time.perf_counter()
-            value = self.planes[type_name].read(key, read_vc)
-        stats.registry.device_read_latency.observe(
-            time.perf_counter() - t0)
-        return value
+        """:meth:`read_many` with one key; ReadBelowBase where the
+        device does not hold it (_PlaneBase.read)."""
+        got = self.read_many([key], type_name, read_vc, txid=txid)
+        if key not in got:
+            raise ReadBelowBase()
+        return got[key]
 
     def read_many(self, keys: list, type_name: str,
                   read_vc: Optional[VC], txid=None) -> dict:
         """{key: state} for device-owned keys; callers take the host
         path for the rest."""
+        # txid-tagged so the span joins its txn's tree and obeys
+        # per-txid sampling; untagged reads fall back to sampled()'s
+        # 1-in-N thinning instead of flooding the ring
         with tracer.span("device_read_many", "device", txid=txid,
                          n=len(keys), type=type_name):
             t0 = time.perf_counter()

@@ -32,15 +32,15 @@ plane's economy (antidote_tpu/mat/ingest.py):
   whole group at the freshest member's snapshot).  Waiters a
   frontier does NOT cover (an op exists between their snapshot and
   the key's frontier) group by exact VC equality instead — the
-  fold's inclusion mask at that exact VC is the legacy per-txn
-  semantics, so groups that must not merge never do.  Coverage is
-  re-validated by frontier IDENTITY after the fold (the _cache_put
-  discipline): a mid-window publish demotes the affected waiters to
+  fold's inclusion mask at that exact VC is what a transaction
+  reading alone gets, so groups that must not merge never do.
+  Coverage is re-validated by frontier IDENTITY after the fold (the
+  _cache_put discipline): a mid-window publish demotes the affected waiters to
   their own exact-VC folds instead of leaking an op from beyond
   their snapshot.  And a waiter whose snapshot is already blocked
   behind a PREPARED transaction is demoted to self-service — it pays
-  the Clock-SI wait on its own thread, the legacy blocking scope,
-  never convoying the window.
+  the Clock-SI wait on its own thread, in read_requests' gate, never
+  convoying the window.
 - **One gathered dispatch per group.**  A group's keys fold through
   ``read_many_begin``'s captured closures, and every capture sharing
   a chip runs as ONE ``fused_read`` program — so N concurrent readers
@@ -54,11 +54,13 @@ plane's economy (antidote_tpu/mat/ingest.py):
   repeat reads of a stable key skip the device entirely; the READ_*
   cache counters make the hit ratio a first-class metric.
 
-``Config.read_serve=False`` keeps the per-txn path byte-for-byte (the
-benches' comparison baseline, like mat_ingest / gate_device_ring /
-interdc_ship); ``serve_from_config`` is the one construction path so
-an assembly cannot honor the knobs for some partitions and not others
-(the gate_from_config lesson).
+``Config.read_serve=False`` sends every transaction's read straight
+to ``txn.manager.read_requests`` — the one way a key is read, which
+the drains call too — with no window (the benches' comparison
+baseline, like mat_ingest / gate_device_ring / interdc_ship);
+``serve_from_config`` is the one construction path so an assembly
+cannot honor the knobs for some partitions and not others (the
+gate_from_config lesson).
 """
 
 from __future__ import annotations

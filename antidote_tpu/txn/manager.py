@@ -19,7 +19,8 @@ import queue
 import threading
 import time
 from dataclasses import replace as dc_replace
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import (Any, Callable, Dict, List, NamedTuple, Optional,
+                    Tuple)
 
 from antidote_tpu import stats
 from antidote_tpu.clocks import VC
@@ -897,122 +898,48 @@ class PartitionManager:
                 return True
         return False
 
-    def _await_unprepared(self, keys, snapshot_vc: VC, txid, what: str,
-                          deadline: Optional[float] = None) -> None:
+    def _await_unprepared(self, keys, snapshot_vc: VC, txid,
+                          deadline: float) -> None:
         """Under self._lock: wait (releasing it) until no prepared
         transaction may still commit one of ``keys`` below
         ``snapshot_vc`` (reference check_prepared,
-        src/clocksi_readitem_server.erl:236-264); TimeoutError naming
-        ``what`` at ``deadline`` (``read_wait_timeout`` from now when
-        the caller brings none).  The caller holds no partition's
-        reader count: read_requests says why."""
+        src/clocksi_readitem_server.erl:236-264); TimeoutError at
+        ``deadline``.  The caller holds no partition's reader count:
+        read_requests says why."""
         def blocked():
             return any(self._blocking_prepared(k, snapshot_vc, txid)
                        for k in keys)
 
         if not blocked():
             return
-        if deadline is None:
-            deadline = time.monotonic() + self.read_wait_timeout
         with tracer.wait_span("pm_prepared_wait", "manager", txid=txid,
                               partition=self.partition):
             while blocked():
                 remaining = deadline - time.monotonic()
                 if remaining <= 0 or not self._lock.wait(
                         timeout=remaining):
-                    raise TimeoutError(f"{what} blocked on prepared txn")
+                    raise TimeoutError(
+                        "batched read blocked on prepared txn")
 
     def read(self, key, type_name: str, snapshot_vc: Optional[VC],
              txid=None, exact_state: bool = False) -> Any:
-        """Clock-SI safe read: wait until the local clock passed the
-        snapshot and no conflicting prepared txn may commit below it
-        (reference check_clock/check_prepared,
-        src/clocksi_readitem_server.erl:236-264), then materialize.
+        """Clock-SI safe read of one key: :func:`read_requests` with
+        one request of one item, as :meth:`read_many` is with one
+        request of many — the gate waits until the local clock passed
+        the snapshot and no conflicting prepared txn may commit below
+        it (reference check_clock/check_prepared,
+        src/clocksi_readitem_server.erl:236-264), the capture
+        materializes.  The caller holds no partition's lock.
 
         ``exact_state``: the caller will feed the state to downstream
         generation (require_state_downstream) — device folds of
         STATE_LOSSY types (whose reconstruction collapses per-DC dot
-        sets) are refused and replaced by an exact log replay; an effect
-        built from a collapsed state would under-cancel at exact
-        replicas, diverging the federation permanently."""
-        if snapshot_vc is not None:
-            # clock wait happens outside the lock (it can be long and
-            # must not stall commits on this partition)
-            self.clock.wait_until(snapshot_vc.get_dc(self.dc_id))
-        reader = None
-        with self._locked:
-            self._read_check()
-            if snapshot_vc is not None:
-                self._await_unprepared((key,), snapshot_vc, txid,
-                                       f"read of {key!r}")
-            if self.device is not None and self.device.owns(type_name, key):
-                fold_exact = self.device.state_exact(type_name, key)
-                need_exact = exact_state and not fold_exact
-                # the device fold runs OUTSIDE the lock on the captured
-                # immutable shard state (plane.read_begin) — the
-                # read-concurrency analogue of the reference's read
-                # servers next to the vnode (src/clocksi_readitem_server
-                # .erl:95-110).  Host-store reads stay under the lock:
-                # they are dict lookups, and commit() mutates the same
-                # entries.
-                fr = self.key_frontier.get(key)
-                covers_all = fr is not None and (
-                    snapshot_vc is None or fr.le(snapshot_vc))
-                if covers_all:
-                    ent = self._val_cache.get(key)
-                    if ent is not None and ent[0] is fr \
-                            and (ent[3] or not need_exact):
-                        ent[2] = 0
-                        stats.registry.read_cache_hits.inc()
-                        return ent[1]
-                stats.registry.read_cache_misses.inc()
-                if need_exact:
-                    value = self._read_from_log(key, type_name,
-                                                snapshot_vc, txid)
-                    if covers_all:
-                        self._cache_put(key, fr, value, True)
-                    return value
-                plane = self.device.planes[type_name]
-                if key in plane.pending_keys:
-                    # read_begin will flush (donating buffers): drain
-                    # in-flight readers of older captures first
-                    self._wait_device_quiesce()
-                try:
-                    reader = plane.read_begin(key, snapshot_vc)
-                except ReadBelowBase:
-                    reader = False  # sentinel: log replay below
-                else:
-                    stats.registry.read_dispatches.inc()
-                    self._dev_readers += 1
-            else:
-                value = self._read_store(key, type_name, snapshot_vc, txid,
-                                         exact_state=exact_state)
-                return value
-        if reader is False:
-            with self._lock:  # log scans serialize with appenders
-                value = self._read_from_log(key, type_name, snapshot_vc,
-                                            txid)
-                if covers_all and self.key_frontier.get(key) is fr:
-                    self._cache_put(key, fr, value, True)
-                return value
-        try:
-            # one key's fold: dispatch and fetch in one, as the planes'
-            # single-key readers make them
-            with tracer.span("device_read", "device", txid=txid,
-                             plane=type_name):
-                value = reader()
-        finally:
-            with self._lock:
-                self._dev_readers -= 1
-                self._lock.notify_all()
-        if covers_all:
-            with self._lock:
-                # re-check: a publish while we folded moved the frontier
-                if self.key_frontier.get(key) is fr:
-                    self._cache_put(key, fr, value, fold_exact)
-        self._maybe_probe_set_aw(key, type_name, snapshot_vc, txid,
-                                 value)
-        return value
+        sets) are refused and replaced by an exact log replay
+        (read_many_begin); an effect built from a collapsed state would
+        under-cancel at exact replicas, diverging the federation
+        permanently."""
+        return read_many_fused([(self, [(key, type_name)])], snapshot_vc,
+                               txid, exact_state)[(key, type_name)]
 
     def _maybe_probe_set_aw(self, key, type_name: str, snapshot_vc,
                             txid, value) -> None:
@@ -1129,8 +1056,9 @@ class PartitionManager:
         PER TYPE runs outside the lock for all its keys — the
         async-batched-reads pipelining of the reference coordinator
         (src/clocksi_interactive_coord.erl:731-747) fused with the
-        read-server concurrency split of :meth:`read`.  It is
-        :func:`read_requests` with one request."""
+        read servers' concurrency next to the vnode (the planes'
+        read_many_begin closures).  It is :func:`read_requests` with
+        one request."""
         return read_many_fused([(self, items)], snapshot_vc, txid)
 
     def read_gate(self, items, snapshot_vc, txid, deadline: float) -> None:
@@ -1152,8 +1080,7 @@ class PartitionManager:
             self._read_check()
             if snapshot_vc is not None:
                 self._await_unprepared([k for k, _t in items],
-                                       snapshot_vc, txid, "batched read",
-                                       deadline)
+                                       snapshot_vc, txid, deadline)
             by_type: Dict[str, list] = {}
             for key, type_name in items:
                 if self.device is not None and self.device.owns(
@@ -1167,20 +1094,27 @@ class PartitionManager:
                     self._wait_device_quiesce()
                     plane.flush()
 
-    def read_many_begin(self, items, snapshot_vc, txid=None):
-        """CAPTURE, the first half of a batched read: under the lock,
+    def read_many_begin(self, items, snapshot_vc, txid=None,
+                        exact_state: bool = False):
+        """CAPTURE, the first half of every read: under the lock,
         serve the cache hits and the host keys, and capture the device
         folds with the reader count INCREMENTED — the caller MUST run
         read_many_finish exactly once, whatever happens.  It never
-        waits and never flushes.  Where the read is not ready — the
-        clock has not passed the snapshot, a prepared transaction may
-        still commit one of the keys below it (Clock-SI: a read at
-        ``s`` sees every commit at or below ``s``), or a plane holds
-        pending operations for a key it would fold — it returns None,
-        having taken and counted nothing: the caller releases what it
-        holds, waits in read_gate and captures again
-        (:func:`read_requests`).  Both checks are made in the lock
-        hold that makes the closures, whatever a gate found before."""
+        waits and never flushes.  The folds run OUTSIDE the lock on
+        the captured immutable shard state; host-store reads stay
+        under it: they are dict lookups, and commit() mutates the same
+        entries.  ``exact_state`` (:meth:`read`): a key whose device
+        fold is not its exact host state is answered from the log
+        here, and only a cache entry stored as exact answers it.
+        Where the read is not ready — the clock has not passed the
+        snapshot, a prepared transaction may still commit one of the
+        keys below it (Clock-SI: a read at ``s`` sees every commit at
+        or below ``s``), or a plane holds pending operations for a key
+        it would fold — it returns None, having taken and counted
+        nothing: the caller releases what it holds, waits in read_gate
+        and captures again (:func:`read_requests`).  Both checks are
+        made in the lock hold that makes the closures, whatever a gate
+        found before."""
         if snapshot_vc is not None and not self.clock.reached(
                 snapshot_vc.get_dc(self.dc_id)):
             return None
@@ -1194,6 +1128,7 @@ class PartitionManager:
                 return None
             by_type: Dict[str, list] = {}
             host_items = []
+            log_items = []  # device keys whose lossy fold will not do
             cache_hits = 0
             for key, type_name in items:
                 fr = self.key_frontier.get(key)
@@ -1201,16 +1136,21 @@ class PartitionManager:
                     snapshot_vc is None or fr.le(snapshot_vc))
                 if covers:
                     ent = self._val_cache.get(key)
-                    if ent is not None and ent[0] is fr:
+                    if ent is not None and ent[0] is fr \
+                            and (ent[3] or not exact_state):
                         ent[2] = 0
                         out[(key, type_name)] = ent[1]
                         cache_hits += 1
                         continue
                 if self.device is not None and self.device.owns(
                         type_name, key):
-                    by_type.setdefault(type_name, []).append(
-                        (key, fr if covers else None,
-                         self.device.state_exact(type_name, key)))
+                    exact = self.device.state_exact(type_name, key)
+                    if exact_state and not exact:
+                        log_items.append(
+                            (key, type_name, fr if covers else None))
+                    else:
+                        by_type.setdefault(type_name, []).append(
+                            (key, fr if covers else None, exact))
                 else:
                     host_items.append((key, type_name))
             for type_name, pairs in by_type.items():
@@ -1219,13 +1159,20 @@ class PartitionManager:
                     return None
             if cache_hits:
                 stats.registry.read_cache_hits.inc(cache_hits)
-            if by_type:
-                stats.registry.read_cache_misses.inc(
-                    sum(len(pairs) for pairs in by_type.values()))
+            misses = len(log_items) + sum(
+                len(pairs) for pairs in by_type.values())
+            if misses:
+                stats.registry.read_cache_misses.inc(misses)
             for key, type_name in host_items:
                 # _read_store counts its own cache hit/miss
                 out[(key, type_name)] = self._read_store(
+                    key, type_name, snapshot_vc, txid,
+                    exact_state=exact_state)
+            for key, type_name, fr in log_items:
+                value = out[(key, type_name)] = self._read_from_log(
                     key, type_name, snapshot_vc, txid)
+                if fr is not None:
+                    self._cache_put(key, fr, value, True)
             for type_name, pairs in by_type.items():
                 plane = self.device.planes[type_name]
                 keys_t = [k for k, _fr, _ex in pairs]
@@ -1240,13 +1187,15 @@ class PartitionManager:
         return out, dev_batches
 
     def read_many_finish(self, out, dev_batches, snapshot_vc,
-                         txid=None, got_map=None):
-        """Second half of :meth:`read_many`: run (or accept) the device
-        folds, post-process, warm the cache, and RELEASE the reader
-        counts taken by read_many_begin.  ``got_map`` maps a batch's
-        index to its already-computed {key: value} dict (the fused
-        cross-partition path ran the fold); missing entries run their
-        own closure here."""
+                         txid=None, got_map=None,
+                         exact_state: bool = False):
+        """FINISH, the second half of every read: run (or accept) the
+        device folds, post-process, warm the cache, and RELEASE the
+        reader counts taken by read_many_begin (``exact_state`` as it
+        was given there).  ``got_map`` maps a batch's index to its
+        already-computed {key: value} dict (the fused cross-partition
+        path ran the fold); missing entries run their own closure
+        here."""
         got_map = got_map or {}
         pending_readers = sum(1 for _t, _p, c in dev_batches
                               if c is not None)
@@ -1275,9 +1224,10 @@ class PartitionManager:
                                     self.key_frontier.get(key) is fr:
                                 cacheable.append((key, fr, value, exact))
                         else:
-                            # evicted during the begin-flush — host path
+                            # not the plane's after all — host path
                             value = self._read_store(
-                                key, type_name, snapshot_vc, txid)
+                                key, type_name, snapshot_vc, txid,
+                                exact_state=exact_state)
                         out[(key, type_name)] = value
                     for key, fr, value, exact in cacheable:
                         self._cache_put(key, fr, value, exact)
@@ -1656,12 +1606,23 @@ class PartitionManager:
             return self._read_store(key, type_name, clock)
 
 
+class ReadRequest(NamedTuple):
+    """One partition's share of a read, as :func:`read_requests` takes
+    it; a plain tuple of the first four does as well."""
+    pm: "PartitionManager"
+    items: List[Tuple[Any, str]]
+    snapshot_vc: Optional[VC]
+    txid: Any = None
+    #: the states feed downstream generation (PartitionManager.read)
+    exact_state: bool = False
+
+
 def read_requests(requests) -> list:
-    """Every batched read reaches the device through here.
-    ``requests`` is [(pm, items, snapshot_vc, txid)] over LOCAL
-    partitions (one partition may appear more than once: a serve
-    drain's groups); the answer holds, in the same order, each
-    request's {(key, type): value} or the exception that failed it.
+    """Every read reaches the device through here, one key or many.
+    ``requests`` is [ReadRequest] over LOCAL partitions (one partition
+    may appear more than once: a serve drain's groups); the answer
+    holds, in the same order, each request's {(key, type): value} or
+    the exception that failed it.
 
     THE RULE: a thread that holds a partition's ``_dev_readers`` count
     waits for nothing a commit could be holding up, because every
@@ -1680,6 +1641,7 @@ def read_requests(requests) -> list:
     each request that was not ready, and the next wave tries those
     again; a partition's ``read_wait_timeout`` runs from the first
     wave."""
+    requests = [ReadRequest(*r) for r in requests]
     results: list = [None] * len(requests)
     t_first = time.monotonic()
     todo = list(range(len(requests)))
@@ -1689,11 +1651,12 @@ def read_requests(requests) -> list:
         got_by: Dict[int, Dict[int, dict]] = {}
         try:
             for ri in todo:
-                pm, items, vc, txid = requests[ri]
+                pm, items, vc, txid, exact = requests[ri]
                 try:
                     with tracer.span("read_serve_fold", "device",
                                      txid=txid, keys=len(items)):
-                        cap = pm.read_many_begin(items, vc, txid)
+                        cap = pm.read_many_begin(items, vc, txid,
+                                                 exact_state=exact)
                 except Exception as e:  # noqa: BLE001 — this request's
                     results[ri] = e
                     continue
@@ -1705,10 +1668,11 @@ def read_requests(requests) -> list:
         finally:
             interrupt = None
             for ci, (ri, out, batches) in enumerate(captured):
-                pm, _items, vc, txid = requests[ri]
+                pm, _items, vc, txid, exact = requests[ri]
                 try:
                     results[ri] = pm.read_many_finish(
-                        out, batches, vc, txid, got_by.get(ci))
+                        out, batches, vc, txid, got_by.get(ci),
+                        exact_state=exact)
                 except Exception as e:  # noqa: BLE001 — this request's
                     results[ri] = e
                 except BaseException as e:  # noqa: BLE001 — re-raised
@@ -1717,7 +1681,7 @@ def read_requests(requests) -> list:
                 raise interrupt
         todo = []
         for ri in waiting:
-            pm, items, vc, txid = requests[ri]
+            pm, items, vc, txid, _exact = requests[ri]
             try:
                 pm.read_gate(items, vc, txid,
                              t_first + pm.read_wait_timeout)
@@ -1761,13 +1725,14 @@ def _fuse_captures(captured) -> Dict[int, Dict[int, dict]]:
     return got_by
 
 
-def read_many_fused(groups, snapshot_vc, txid=None
+def read_many_fused(groups, snapshot_vc, txid=None,
+                    exact_state: bool = False
                     ) -> Dict[Tuple[Any, str], Any]:
     """One snapshot read over ``groups`` = [(pm, items)], LOCAL
     partitions: read_requests with one request a partition, merged;
     the first failure is raised."""
     merged: Dict[Tuple[Any, str], Any] = {}
-    for got in read_requests([(pm, items, snapshot_vc, txid)
+    for got in read_requests([(pm, items, snapshot_vc, txid, exact_state)
                               for pm, items in groups]):
         if isinstance(got, BaseException):
             raise got

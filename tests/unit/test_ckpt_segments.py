@@ -411,7 +411,7 @@ def test_device_seed_round_trips_each_type(tn, effects):
     # log path (the base VC is the seed frontier)
     assert dst.planes[tn].read(key, vc) == state
     with pytest.raises(ReadBelowBase):
-        dst.planes[tn].read_begin(key, VC({"dc1": 1}))
+        dst.planes[tn].read(key, VC({"dc1": 1}))
 
 
 def test_device_seed_refuses_lossy_and_unrepresentable():

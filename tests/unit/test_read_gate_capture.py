@@ -119,7 +119,18 @@ def assert_publish_does_not_block(pms):
 # ----------------------------------------------- (a) the deadlock's schedule
 
 
-def blocked_reader(ab):
+def one_key_at_a_time(groups, vc):
+    """``read_many_fused``'s answer through PartitionManager.read."""
+    return {(k, t): pm.read(k, t, vc)
+            for pm, items in groups for k, t in items}
+
+
+#: the entries that take a snapshot over partitions: both are
+#: read_requests, the second with one request of one item at a time
+ENTRIES = {"fused": read_many_fused, "read": one_key_at_a_time}
+
+
+def blocked_reader(ab, entry=read_many_fused):
     """T prepared on A (key a2) and on B (key b1); a reader of a1 on A
     and b1 on B at a snapshot above T's prepare times, started, and
     stopped where it waits for T on B.  Returns what the test needs to
@@ -132,18 +143,20 @@ def blocked_reader(ab):
     snap_t, ct = prepare_t([(A, "a2", 10), (B, "b1", 20)])
     s = now_vc(A)
     at_b = Entered(B, "_await_unprepared", lambda: A._dev_readers)
-    reader, box = in_thread(lambda: read_many_fused(
+    reader, box = in_thread(lambda: entry(
         [(A, [("a1", CK)]), (B, [("b1", CK)])], s))
     assert at_b.event.wait(5), "the reader never reached B's wait"
     return A, B, snap_t, ct, s, at_b, reader, box
 
 
-def test_a_reader_waiting_on_b_holds_no_count_on_a(ab):
-    """The parent's read_many_fused began on A, kept A's count and
-    stood in B's prepared wait, while T's commit on A stood in
+@pytest.mark.parametrize("entry", sorted(ENTRIES))
+def test_a_reader_waiting_on_b_holds_no_count_on_a(ab, entry):
+    """PR 27's read_many_fused began on A, kept A's count and stood in
+    B's prepared wait, while T's commit on A stood in
     _wait_device_quiesce: 0.5 s here, 5 s served, then the read
     failed."""
-    A, B, snap_t, ct, _s, at_b, reader, box = blocked_reader(ab)
+    A, B, snap_t, ct, _s, at_b, reader, box = blocked_reader(
+        ab, ENTRIES[entry])
     assert at_b.seen == [0], "the reader waits on B holding A's count"
     t0 = time.monotonic()
     commit_a, cbox = in_thread(lambda: A.commit(T, ct, snap_t))
@@ -414,7 +427,8 @@ def test_the_gate_waits_for_readers_before_it_flushes(tmp_path):
 # ------------------------------------------------------- (e) the timeout
 
 
-@pytest.mark.parametrize("shape", ["two_partitions", "one_partition"])
+@pytest.mark.parametrize("shape", ["two_partitions", "one_partition",
+                                   "one_key"])
 def test_the_timeout_fires_with_its_message_and_holds_nothing(ab, shape):
     A, B = ab
     write(A, "a1", 1)
@@ -427,8 +441,10 @@ def test_the_timeout_fires_with_its_message_and_holds_nothing(ab, shape):
     with pytest.raises(TimeoutError) as err:
         if shape == "two_partitions":
             read_many_fused([(A, [("a1", CK)]), (B, [("b1", CK)])], s)
-        else:
+        elif shape == "one_partition":
             B.read_many([("b1", CK), ("b0", CK)], s)
+        else:
+            B.read("b1", CK, s)
     assert str(err.value) == "batched read blocked on prepared txn"
     assert 0.45 < time.monotonic() - t0 < 3.0
     assert [pm._dev_readers for pm in ab] == [0, 0]
@@ -439,7 +455,8 @@ def test_the_timeout_fires_with_its_message_and_holds_nothing(ab, shape):
     assert_publish_does_not_block(ab)
 
 
-def test_the_timeout_runs_from_the_first_gate_across_waves(ab):
+@pytest.mark.parametrize("entry", ["read_many", "read"])
+def test_the_timeout_runs_from_the_first_gate_across_waves(ab, entry):
     """A request that is gated, captured "not ready" and gated again
     does not start its 0.5 s anew."""
     A, _B = ab
@@ -459,6 +476,9 @@ def test_the_timeout_runs_from_the_first_gate_across_waves(ab):
     t0 = time.monotonic()
     with pytest.raises(TimeoutError,
                        match="batched read blocked on prepared txn"):
-        A.read_many([("a1", CK)], None)
+        if entry == "read_many":
+            A.read_many([("a1", CK)], None)
+        else:
+            A.read("a1", CK, None)
     assert 0.45 < time.monotonic() - t0 < 3.0
     assert len(calls) >= 4 and len(set(calls)) == 1

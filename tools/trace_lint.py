@@ -109,7 +109,7 @@ ENTRY_POINTS: Dict[str, Dict[str, List[str]]] = {
         "PartitionLog": ["append_commit"],
     },
     "antidote_tpu/mat/device_plane.py": {
-        "DevicePlane": ["stage", "read", "read_many", "gc", "flush"],
+        "DevicePlane": ["stage", "read_many", "gc", "flush"],
         "_PlaneBase": ["_append_rows", "read_many_begin", "_many_reader",
                        "flush", "gc"],
     },
