@@ -627,13 +627,18 @@ class _PlaneBase:
         """Closure materializing the owned keys in one batched fold of
         the captured state (``pad`` = idxs padded to the dispatch
         bucket).  Carries ``.split``/``.device`` so a cross-partition
-        caller can fuse this fold with other planes' (see fused_read);
-        planes with no batched-fold form (RGA's per-document trees)
-        override this without a split."""
+        caller can fuse this fold with other planes' (see fused_read),
+        and ``.halves`` = (fetch, post) for a caller that gives its
+        reader count back as soon as the captured buffers are done
+        with: ``fetch()`` is the device half, ending with the values
+        on the host; ``post`` decodes them and touches no device state
+        (PartitionManager._ckpt_fold).  Planes with no batched-fold
+        form (RGA's per-document trees) override this without
+        either."""
         spec, post = self._many_split(st, owned, idxs, pad, rv)
         fn, args = spec
 
-        def run():
+        def fetch():
             count_read_dispatch()
             with self._collective_cm():
                 with tracer.span("device_dispatch", "device",
@@ -641,10 +646,13 @@ class _PlaneBase:
                     out = fn(*args)
                 with tracer.wait_span("device_fetch", "device",
                                       plane=self.type_name):
-                    out = jax.tree_util.tree_map(np.asarray, out)
-            return post(out)
+                    return jax.tree_util.tree_map(np.asarray, out)
+
+        def run():
+            return post(fetch())
 
         run.split = (spec, post)
+        run.halves = (fetch, post)
         if self._mesh is not None:
             # the mesh IS the fusing discriminator: every sharded
             # plane's fold is the same multi-chip program family, so

@@ -472,7 +472,9 @@ class CheckpointStore:
         a crash at any earlier byte leaves the previous manifest
         authoritative over files that still all exist."""
         t0 = time.perf_counter()
-        delta = doc.pop("delta", None)
+        # read, not popped: the adopt installs exactly these seeds
+        # (PartitionLog.adopt_checkpoint) and drops the key
+        delta = doc.get("delta")
         if delta is None:
             # no incremental fold (first cut, or a caller handing a
             # fully-materialized doc): the whole seed set is the delta
@@ -499,12 +501,13 @@ class CheckpointStore:
         tracer.instant("ckpt_manifest", "oplog",
                        path=os.path.basename(self.path),
                        segments=len(segments), compacted=compacted)
-        keys = doc.pop("keys")  # the manifest carries the list, not
-        try:                    # the seed states themselves
+        # the manifest carries the list, not the seed states themselves
+        held = {k: doc.pop(k) for k in ("keys", "delta") if k in doc}
+        try:
             doc["segments"] = segments
             self.write_doc(doc)
         finally:
-            doc["keys"] = keys
+            doc.update(held)
         # post-commit sweep: everything the live manifest does not
         # reference (compacted-away segments, strays from a crashed
         # persist) is garbage now

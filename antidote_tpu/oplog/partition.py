@@ -640,7 +640,9 @@ class PartitionLog:
         their bytes, so recovery never needs the below-cut file).
         Must run under the owning partition's lock: the cut is only a
         cut because nothing appends or publishes while it is taken
-        (PartitionManager.checkpoint_now is the one caller)."""
+        (PartitionManager.checkpoint_now is the one caller, in the
+        one hold that also captures the dirty keys' states; the fold
+        of those states runs after it, with the lock released)."""
         doc = empty_doc(self.partition)
         doc["cut_offset"] = self.log.end_offset()
         doc["op_counters"] = dict(self.op_counters)
@@ -783,20 +785,31 @@ class PartitionLog:
         self.log.abort_truncate(trunc_stage["token"])
 
     def adopt_checkpoint(self, doc: dict,
-                         trunc_stage: Optional[dict] = None) -> None:
+                         trunc_stage: Optional[dict] = None) -> int:
         """Make a persisted document's seeds live for the replay paths
         (eviction migration, read-below-base, host-store cache misses)
         and commit the staged truncation of log bytes below its cut
         (``trunc_stage``, from :meth:`stage_truncation` — run BEFORE
         taking the partition lock; only the bounded catch-up + rename
         half runs here).  Must run under the owning partition's lock,
-        like :meth:`capture_cut` — the seed swap and the index prune
-        race the readers otherwise."""
-        doc.pop("delta", None)  # persisted (or folded into keys)
+        like :meth:`capture_cut` — the seed install and the index prune
+        race the readers otherwise — so what it costs there is what
+        changed: where ``doc["delta"]`` is the dirty keys stacked on
+        the document adopted before (``keys`` = that document's keys +
+        ``delta``, manager._ckpt_fold's segmented form), only those
+        seeds are written and every other key keeps the entry it had.
+        A document with no such base — no ``delta`` (monolithic), a
+        ``delta`` that IS ``keys`` (the first segmented cut after a
+        monolithic one), or nothing adopted yet — builds every seed.
+        Returns the number of seed entries written."""
+        fresh = doc.pop("delta", None)  # persisted; ``keys`` has them
+        if fresh is None or fresh is doc["keys"] or self.ckpt_doc is None:
+            fresh = doc["keys"]
+            self.ckpt_seeds = {}
         self.ckpt_doc = doc
-        self.ckpt_seeds = {
-            key: (tn, state, VC(vc))
-            for key, (tn, state, vc) in doc["keys"].items()}
+        seeds = self.ckpt_seeds
+        for key, (tn, state, vc) in fresh.items():
+            seeds[key] = (tn, state, VC(vc))
         stats.registry.ckpt_keys.set(len(doc["keys"]),
                                      partition=str(self.partition))
         recorder.record("oplog", "ckpt_write", partition=self.partition,
@@ -811,6 +824,7 @@ class PartitionLog:
                 self.abort_truncation(trunc_stage)
             else:
                 self._commit_truncation(doc, trunc_stage)
+        return len(fresh)
 
     def _commit_truncation(self, doc: dict, trunc_stage: dict) -> None:
         """Phase 2: redeem the staged rewrite — re-validate + bounded

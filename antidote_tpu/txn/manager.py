@@ -743,6 +743,18 @@ class PartitionManager:
         fsync under the lock exactly as before."""
         stable = self._stable_for_gc()  # before the lock (see __init__)
         with self._locked:
+            # BEFORE the record is in the log: a publish waits for
+            # device readers, and that wait releases the lock.  Taken
+            # between the append and the publish it showed the next
+            # holder a commit the store did not have — a checkpoint
+            # cut above the record with a seed without the effect
+            # (the txn in neither seed nor suffix), a host-store miss
+            # rebuilt from the log while the device then staged the
+            # op too (applied twice at the key's eviction).  Waited
+            # out here, record and effects appear in one hold:
+            # _publish's own waits find no reader (a capture needs
+            # this lock).
+            self._wait_device_quiesce()
             self._mutate_check()
             self.log.append_commit(self.dc_id, txid, commit_time,
                                    snapshot_vc, certified)
@@ -862,6 +874,7 @@ class PartitionManager:
             self._lock.notify_all()
 
         with self._lock:
+            self._wait_device_quiesce()  # before the append: commit()
             self._mutate_check()
             ticket = self.log.append_remote_group(records)
             defer = self.publish_after_durable and ticket is not None
@@ -1283,28 +1296,40 @@ class PartitionManager:
 
     def checkpoint_now(self) -> Optional[dict]:
         """Cut + fold + persist one checkpoint for this partition
-        (ISSUE 10): under the partition lock (readers quiesced — the
-        device folds below swap donated buffers), capture the log cut,
-        fold every key published since the previous cut — device-
-        resident keys via ONE batched fold per type plane (the PR-8
-        export machinery's read_many path), host keys via the
-        materializer, state-lossy device folds via the exact log
-        replay — and hand the document to the log for the atomic write
-        (+ retention-gated truncation).  Returns the document, or None
-        when checkpointing is disabled."""
+        (ISSUE 10), by the rule every read follows (read_requests):
+        capture under the partition lock, fold outside it.  What stops
+        the partition is two holds, both O(dirty keys): the CUT — with
+        device readers and deferred publishes quiesced, take the log
+        cut, swap the dirty set and capture every key published since
+        the previous cut at its state AT the cut (:meth:`_ckpt_capture`)
+        — and, once the document is on disk, the ADOPT, which installs
+        the seeds that changed and redeems the staged truncation.
+        Between them nothing is held but the captures' reader counts,
+        and those only until the device values are on the host:
+        :meth:`_ckpt_fold` runs the device folds (ONE batched fold per
+        type plane, the PR-8 export machinery's read_many path),
+        decodes and builds the document; the log is synced up to the
+        cut, the document persisted (atomic write), the truncation
+        staged (retention-gated).  Returns the document, or None when
+        checkpointing is disabled."""
         if self.log.ckpt is None or not self.log.enabled:
             return None
         t0 = time.perf_counter()
         with self._lock:
             if self._ckpt_inflight:
-                # another thread is mid-checkpoint (its persist runs
-                # outside this lock): reuse its document rather than
-                # stacking writers — the inflight guard is also what
-                # keeps documents landing on disk in cut order
+                # another thread is mid-checkpoint (its fold and its
+                # persist run outside this lock): reuse its document
+                # rather than stacking writers — the inflight guard is
+                # also what keeps documents landing on disk in cut
+                # order
                 return self.log.ckpt_doc
             self._ckpt_inflight = True
         dirty: Dict[Any, str] = {}
         trunc: Optional[dict] = None
+        #: (type, [(key, frontier)], closure), one reader count each —
+        #: ours from the capture until the fold gives it back, or the
+        #: finally below if the fold never got there
+        dev_batches: list = []
         try:
             with self._lock, \
                     tracer.span("ckpt_cut", "oplog",
@@ -1316,6 +1341,10 @@ class PartitionManager:
                 # neither seed nor suffix.  Wait both quiescent; the
                 # condition wait releases the lock, so the deferred
                 # committers' publishes (and device readers) drain.
+                # (A publish that is not deferred has no such window:
+                # commit() waits for the readers before it appends.)
+                # Readers drain for the capture too: its flushes
+                # donate buffers.
                 if self._dev_readers or self._defer_unpublished:
                     with tracer.wait_span("ckpt_quiesce_wait", "oplog",
                                           partition=self.partition):
@@ -1324,7 +1353,10 @@ class PartitionManager:
                             self._lock.wait()
                 doc = self.log.capture_cut()
                 dirty, self._ckpt_dirty = self._ckpt_dirty, {}
-                self._ckpt_fold(doc, dirty)
+                seeds = self._ckpt_capture(dirty, dev_batches)
+            with tracer.span("ckpt_fold", "oplog",
+                             partition=self.partition, dirty=len(dirty)):
+                self._ckpt_fold(doc, seeds, dev_batches)
             # make the log durable UP TO the cut before the document
             # claims it: open-time recovery resumes validation at the
             # cut precisely because bytes below it are trusted durable
@@ -1343,13 +1375,16 @@ class PartitionManager:
             trunc = self.log.stage_truncation(doc)
             with self._lock, \
                     tracer.span("ckpt_adopt", "oplog",
-                                partition=self.partition):
+                                partition=self.partition) as span:
                 # lock-ok: adopt redeems the staged truncation — the
                 # BOUNDED half (catch-up of bytes appended during the
                 # copy, atomic rename, directory fsync) runs under the
                 # partition lock by design; the unbounded tail copy
                 # already staged out above
-                self.log.adopt_checkpoint(doc, trunc)
+                written = self.log.adopt_checkpoint(doc, trunc)
+                if span is not None:
+                    # O(dirty) or the full build, for /debug/spans
+                    span.args["seeds"] = written
                 self._ckpt_ops = 0
                 self._ckpt_last_end = doc["cut_offset"]
             recorder.record("oplog", "ckpt_cut_done",
@@ -1362,8 +1397,8 @@ class PartitionManager:
             # next (successful) checkpoint would carry these keys'
             # PREVIOUS-cut seeds while its cut moved past their ops —
             # re-folding them is what keeps seed+suffix exact.
-            # Publishes during the failure window merged their own
-            # entries; theirs win (newer).
+            # Publishes during the fold and the failure window merged
+            # their own entries; theirs win (newer).
             with self._lock:
                 merged = dict(dirty)
                 merged.update(self._ckpt_dirty)
@@ -1376,16 +1411,90 @@ class PartitionManager:
             raise
         finally:
             with self._lock:
+                # counts the fold did not give back (it, or the
+                # capture, raised first): a leak would wedge
+                # _wait_device_quiesce (and every publish) forever
+                self._dev_readers -= len(dev_batches)
                 self._ckpt_inflight = False
                 self._lock.notify_all()
 
-    def _ckpt_fold(self, doc: dict, dirty: Dict[Any, str]) -> None:
-        """Fold the dirty keys into ``doc`` (the capture half of
-        :meth:`checkpoint_now`); runs under self._lock with device
-        readers quiesced.  Under ``ckpt_segmented`` the freshly folded
-        dirty entries ALSO land in ``doc["delta"]`` — the only part
-        the segmented persist serializes (O(churn)); the carried seeds
-        ride forward as shared references, never re-copied."""
+    def _ckpt_capture(self, dirty: Dict[Any, str],
+                      dev_batches: list) -> Dict[Any, tuple]:
+        """The capture half of :meth:`checkpoint_now`; runs under
+        self._lock with device readers and deferred publishes
+        quiesced, in the hold that took the cut, and fixes everything
+        a seed is made of.  For every dirty key the REFERENCE of its
+        frontier (a frontier is replaced, never mutated: the value
+        cache's ``ent[0] is fr`` relies on the same).  Its state: read
+        here for host-store keys (the materializer) and for
+        state-lossy device folds (the exact log replay); captured for
+        device-resident keys as ONE closure a type plane over the
+        immutable shard state (``read_many_begin``, which flushes the
+        keys' staged rows first), appended to ``dev_batches`` with a
+        reader count the caller owns from the append on.  Returns the
+        seeds read here, {key: (type, state, frontier)}."""
+        frontier = self.key_frontier.get
+        by_type: Dict[str, list] = {}
+        host_items = []
+        for key, tn in dirty.items():
+            if self.device is not None \
+                    and self.device.owns(tn, key) \
+                    and self.device.state_exact(tn, key):
+                by_type.setdefault(tn, []).append(key)
+            else:
+                host_items.append((key, tn))
+        for tn, ks in by_type.items():
+            plane = self.device.planes[tn]
+            pairs: list = []
+            dev_batches.append((tn, pairs,
+                                plane.read_many_begin(ks, None)))
+            self._dev_readers += 1
+            for k in ks:
+                if plane.owns(k):
+                    pairs.append((k, frontier(k)))
+                else:  # the capture's flush evicted it: host path
+                    host_items.append((k, tn))
+        seeds: Dict[Any, tuple] = {}
+        for key, tn in host_items:
+            if self.device is not None and self.device.owns(tn, key):
+                # STATE_LOSSY fold (set_rw/flag_dw/lossy maps): a
+                # collapsed state seeded into the host store would
+                # feed downstream generation and under-cancel at
+                # exact replicas — replay the (still complete) log
+                # instead; exact by construction
+                state = self._read_from_log(key, tn, None)
+            else:
+                state = self.store.read(key, tn, None)[0]
+            seeds[key] = (tn, state, frontier(key))
+        return seeds
+
+    def _ckpt_fold(self, doc: dict, seeds: Dict[Any, tuple],
+                   dev_batches: list) -> None:
+        """The fold half of :meth:`checkpoint_now`: fold what
+        :meth:`_ckpt_capture` fixed into ``doc``.  Runs OUTSIDE
+        self._lock, commits and reads going on: it holds the reader
+        counts of ``dev_batches`` through the closures' device halves
+        and gives them back, all at once, when the values are on the
+        host — a publish waits for a checkpoint in
+        _wait_device_quiesce as long as it would for a read — then
+        decodes and builds the document from the captured states and
+        the previous document, which ``_ckpt_inflight`` keeps ours.
+        Under ``ckpt_segmented`` the freshly folded dirty entries ALSO
+        land in ``doc["delta"]`` — the only part the segmented persist
+        serializes and the adopt installs (O(churn)); the carried
+        seeds ride forward as shared references, never re-copied."""
+        fetched = []
+        try:
+            for tn, pairs, closure in dev_batches:
+                # no halves (maps, RGA documents): the whole closure,
+                # its decode included, under the count
+                fetch, post = getattr(closure, "halves", (closure, None))
+                fetched.append((tn, pairs, fetch(), post))
+        finally:
+            with self._lock:
+                self._dev_readers -= len(dev_batches)
+                del dev_batches[:]
+                self._lock.notify_all()
         prev_doc = self.log.ckpt_doc
         segmented = (self.log.ckpt is not None
                      and self.log.ckpt.settings.segmented)
@@ -1399,41 +1508,23 @@ class PartitionManager:
             # dirty keys (the incremental economy)
             keys = {k: (tn, state, dict(vc))
                     for k, (tn, state, vc) in self.log.ckpt_seeds.items()}
-        clock = VC(prev_doc["clock"]) if prev_doc else VC()
-        by_type: Dict[str, list] = {}
-        host_items = []
-        for key, tn in dirty.items():
-            if self.device is not None \
-                    and self.device.owns(tn, key) \
-                    and self.device.state_exact(tn, key):
-                by_type.setdefault(tn, []).append(key)
-            else:
-                host_items.append((key, tn))
-        folded: Dict[Any, Tuple[str, Any]] = {}
-        for tn, ks in by_type.items():
-            got = self.device.read_many(ks, tn, None)
-            for k in ks:
-                if k in got:
-                    folded[k] = (tn, got[k])
-                else:  # evicted mid-flush: host path below
-                    host_items.append((k, tn))
-        for key, tn in host_items:
-            if self.device is not None and self.device.owns(tn, key):
-                # STATE_LOSSY fold (set_rw/flag_dw/lossy maps): a
-                # collapsed state seeded into the host store would
-                # feed downstream generation and under-cancel at
-                # exact replicas — replay the (still complete) log
-                # instead; exact by construction
-                folded[key] = (tn, self._read_from_log(key, tn, None))
-            else:
-                folded[key] = (tn, self.store.read(key, tn, None)[0])
+        clock = dict(prev_doc["clock"]) if prev_doc else {}
         delta: Dict[Any, tuple] = {}
-        for key, (tn, state) in folded.items():
-            fr = self.key_frontier.get(key) or VC()
-            ent = (tn, state, dict(fr))
-            keys[key] = ent
-            delta[key] = ent
-            clock = clock.join(fr)
+
+        def fold(key, tn, state, fr):
+            vc = dict(fr) if fr else {}
+            keys[key] = delta[key] = (tn, state, vc)
+            for dc, t in vc.items():  # VC.join, without a VC a key
+                if t > clock.get(dc, 0):
+                    clock[dc] = t
+
+        for tn, pairs, got, post in fetched:
+            if post is not None:
+                got = post(got)
+            for key, fr in pairs:
+                fold(key, tn, got[key], fr)
+        for key, (tn, state, fr) in seeds.items():
+            fold(key, tn, state, fr)
         doc["keys"] = keys
         if segmented:
             # a previous MONOLITHIC document's carried seeds live in
@@ -1443,7 +1534,7 @@ class PartitionManager:
             doc["delta"] = keys if (prev_doc is not None
                                     and "segments" not in prev_doc) \
                 else delta
-        doc["clock"] = dict(clock)
+        doc["clock"] = clock
 
     def install_ckpt_seeds(self) -> set:
         """Boot-time half of checkpoint recovery: install every seed

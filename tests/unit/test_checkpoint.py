@@ -835,3 +835,124 @@ def test_commit_lands_during_truncation_tail_copy(tmp_path, backend,
     got = _all_values(re)
     re.close()
     assert got == want
+
+
+# ------------------------------------- the fold outside the lock (ISSUE 32)
+
+
+def test_key_evicted_by_the_captures_flush_takes_the_host_path(tmp_path):
+    """The capture flushes a plane's staged rows for the dirty keys,
+    and a flush can evict (here: six unstable ops on a two-lane key).
+    Ownership is checked again after it: the evicted key's seed is
+    read from the host store under the same hold, the key that stayed
+    is folded on the device, and both are exact after a restart."""
+    cfg = _mk_cfg(tmp_path, device_store=True, ckpt=True, n_partitions=1,
+                  ckpt_ops=1 << 30, ckpt_bytes=1 << 40, device_lanes=2)
+    node = Node(dc_id="dc1", config=cfg)
+    pm = node.partitions[0]
+    for i in range(6):
+        _commit(node, 200 + i, [("ctr_0", "counter_pn", 1)], certify=True)
+    for i in range(2):
+        _commit(node, 300 + i, [("ctr_1", "counter_pn", 1)], certify=True)
+    assert pm.device.owns("counter_pn", "ctr_0") \
+        and pm.device.owns("counter_pn", "ctr_1")
+    frontiers = {k: dict(pm.key_frontier[k]) for k in ("ctr_0", "ctr_1")}
+    doc = pm.checkpoint_now()
+    assert not pm.device.owns("counter_pn", "ctr_0"), \
+        "the capture's flush evicted nothing: the case is not set up"
+    assert pm.device.owns("counter_pn", "ctr_1")
+    assert doc["keys"]["ctr_0"] == ("counter_pn", 6, frontiers["ctr_0"])
+    assert doc["keys"]["ctr_1"] == ("counter_pn", 2, frontiers["ctr_1"])
+    assert pm._dev_readers == 0
+    _commit(node, 400, [("ctr_0", "counter_pn", 10)], certify=True)
+    want = _all_values(node)
+    assert want["ctr_0"] == 16
+    node.close()
+    re = Node(dc_id="dc1", config=cfg)
+    assert _all_values(re) == want
+    re.close()
+
+
+@pytest.mark.parametrize("readers", [0, 3])
+def test_checkpoints_beside_commits_lose_and_double_nothing(tmp_path,
+                                                            readers):
+    """Stress (time-bounded): eight committers, a counter each, beside
+    one thread that cuts checkpoint after checkpoint — and, in the
+    second case, three readers whose reads reach the planes (a value
+    cache of one entry) — the interpreter switching threads every
+    10 us.  Every step is in the live value once; no reader count is
+    left behind; and a restart from the last document plus its suffix
+    reads the same values — a step folded into a seed AND left above
+    its cut would read twice, one dropped from both never.  What it
+    guards: a publish waits for device readers with the partition lock
+    released, the fold's own count among them since ISSUE 32, so a
+    commit record must not be in the log across that wait (commit()
+    waits first): a cut taken then lost the step at the key's next
+    eviction, and a host-store miss rebuilt from the log then applied
+    it twice (the parent: every run with readers).  (One writer a key:
+    two uncertified writers of one key can publish against their
+    commit times' order, which a seed's clock cannot tell from
+    "already folded", fold or no fold.)"""
+    import sys
+
+    cfg = _mk_cfg(tmp_path, device_store=True, ckpt=True, n_partitions=1,
+                  ckpt_ops=1 << 30, ckpt_bytes=1 << 40)
+    node = Node(dc_id="dc1", config=cfg)
+    pm = node.partitions[0]
+    pm._val_cache_cap = 1
+    keys = [f"ctr_{i}" for i in range(8)]
+    stop_at = time.monotonic() + 2.0
+    cuts, steps, errors = [], [0] * 8, []
+
+    def reader():
+        try:
+            while time.monotonic() < stop_at:
+                pm.read_many([(k, "counter_pn") for k in keys], None)
+        except BaseException as e:  # noqa: BLE001 — surfaced below
+            errors.append(e)
+
+    def committer(i):
+        try:
+            while time.monotonic() < stop_at:
+                # the commit time is drawn at the prepare, as a served
+                # transaction's is (_commit draws it before: one at a
+                # time)
+                txid = ("dc1", 40_000_000 + i * 100_000 + steps[i])
+                pm.stage_update(txid, keys[i], "counter_pn", 1)
+                pm.single_commit(txid, VC({"dc1": node.clock.now_us()}))
+                steps[i] += 1
+        except BaseException as e:  # noqa: BLE001 — surfaced below
+            errors.append(e)
+
+    def cutter():
+        try:
+            while time.monotonic() < stop_at:
+                cuts.append(pm.checkpoint_now()["cut_offset"])
+        except BaseException as e:  # noqa: BLE001 — surfaced below
+            errors.append(e)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=committer, args=(i,))
+                   for i in range(8)] + [threading.Thread(target=cutter)] \
+            + [threading.Thread(target=reader) for _ in range(readers)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(60)
+            assert not t.is_alive(), "a thread of the stress wedged"
+    finally:
+        sys.setswitchinterval(interval)
+    assert not errors, errors
+    assert len(cuts) > 2 and cuts == sorted(cuts)
+    assert pm._dev_readers == 0 and not pm._ckpt_inflight
+    assert sum(steps) > 50
+    want = dict(zip(keys, steps))
+    assert {k: pm.value_snapshot(k, "counter_pn") for k in keys} == want
+    node.close()
+    re = Node(dc_id="dc1", config=cfg)
+    pm2 = re.partitions[0]
+    assert pm2.log.suffix_start == cuts[-1]
+    assert {k: pm2.value_snapshot(k, "counter_pn") for k in keys} == want
+    re.close()

@@ -186,6 +186,71 @@ def test_compaction_folds_segments_and_counts(tmp_path):
     re.close()
 
 
+def test_delta_rides_the_document_to_the_adopt_and_no_further(
+        tmp_path, monkeypatch):
+    """The segmented persist reads ``doc["delta"]`` and leaves it for
+    the adopt, which installs exactly those seeds (ISSUE 32) and drops
+    the key: neither the manifest on disk nor the adopted document
+    carries it."""
+    cfg = _mk(tmp_path)
+    node = Node(dc_id="dc1", config=cfg)
+    for i in range(10):
+        _commit(node, i, [(f"ctr_{i}", "counter_pn", 1)])
+    pm = node.partitions[0]
+    assert pm.checkpoint_now() is not None
+    _commit(node, 1000, [("ctr_3", "counter_pn", 5)])
+    seen = {}
+    real = pm.log.adopt_checkpoint
+
+    def adopt(doc, trunc=None):
+        seen["delta"] = dict(doc["delta"])
+        return real(doc, trunc)
+
+    monkeypatch.setattr(pm.log, "adopt_checkpoint", adopt)
+    doc = pm.checkpoint_now()
+    assert set(seen["delta"]) == {"ctr_3"}
+    assert "delta" not in doc and len(doc["keys"]) == 10
+    manifest = CheckpointStore._parse(
+        open(pm.log.path + ".ckpt", "rb").read())
+    assert "delta" not in manifest and "keys" not in manifest
+    assert pm.log.seed_for("ctr_3")[1] == 6
+    node.close()
+
+
+def test_compacting_cut_still_adopts_only_its_delta(tmp_path):
+    """A cut that elects compaction writes every live seed into one
+    segment, yet what changed since the adopted document is still its
+    delta: the live seeds of the untouched keys stay the same objects,
+    and a reopen from the compacted segment reads them all."""
+    cfg = _mk(tmp_path, ckpt_seg_waste_frac=0.2)
+    node = Node(dc_id="dc1", config=cfg)
+    keys = [f"ctr_{i}" for i in range(6)]
+    for i, k in enumerate(keys):
+        _commit(node, i, [(k, "counter_pn", 1)])
+    pm = node.partitions[0]
+    assert pm.checkpoint_now() is not None
+    from antidote_tpu import stats
+
+    compactions = stats.registry.ckpt_seg_compactions.value()
+    n = 100
+    while stats.registry.ckpt_seg_compactions.value() == compactions:
+        before = dict(pm.log.ckpt_seeds)
+        for k in keys[:3]:
+            _commit(node, n, [(k, "counter_pn", 1)])
+            n += 1
+        assert pm.checkpoint_now() is not None
+        assert n < 200, "compaction never elected"
+    assert len(_segfiles(node)) == 1
+    seeds = pm.log.ckpt_seeds
+    assert [k for k in keys if seeds[k] is not before[k]] == keys[:3]
+    want = {k: seeds[k][:2] for k in keys}
+    node.close()
+    re = Node(dc_id="dc1", config=cfg)
+    got = re.partitions[0].log.ckpt_seeds
+    assert {k: got[k][:2] for k in keys} == want
+    re.close()
+
+
 # ------------------------------------------------------- torn / loud
 
 
