@@ -617,13 +617,15 @@ class DurableLog:
     def _backend_sync(self, io) -> None:
         """flush + fsync on a pinned backend, OUTSIDE self._lock (the
         stdio stream serializes concurrent writers internally, and
-        fsync covers at least every byte written before it started)."""
-        tracer.instant("log_fsync", "oplog",
-                       path=os.path.basename(self.path))
-        if isinstance(io, tuple):
-            io[0].oplog_sync(io[1])
-        else:
-            io.sync()
+        fsync covers at least every byte written before it started).
+        The wait span ``log_fsync`` holds the backend's call alone: the
+        thread sleeps on the disk there."""
+        with tracer.wait_span("log_fsync", "oplog",
+                              path=os.path.basename(self.path)):
+            if isinstance(io, tuple):
+                io[0].oplog_sync(io[1])
+            else:
+                io.sync()
 
     # ----------------------------------------------------------- flush/sync
 

@@ -311,16 +311,19 @@ class PartitionLog:
         ``ticket`` (the commit ack gate).  Must run WITHOUT the
         partition lock — committers coalesce here, one leader drains
         the window, and the per-committer wait feeds the
-        ``log_sync_wait`` histogram + sampled txn trees."""
+        ``log_sync_wait`` histogram and, as a wait span of the same
+        name, the request's tree: the committer sleeps here, so the
+        commit spans above it do not count the disk as their own."""
         if ticket is None:
             return
         t0 = time.perf_counter()
-        info = self.log.wait_durable(ticket)
-        wait_s = time.perf_counter() - t0
-        stats.registry.log_sync_wait.observe(wait_s)
-        tracer.instant("log_sync_wait", "oplog", txid=txid,
-                       partition=self.partition,
-                       wait_us=round(wait_s * 1e6, 1), led=info["led"])
+        with tracer.wait_span("log_sync_wait", "oplog", txid=txid,
+                              partition=self.partition) as span:
+            info = self.log.wait_durable(ticket)
+            if span is not None:
+                span.args.update(led=info["led"],
+                                 records=info["records"])
+        stats.registry.log_sync_wait.observe(time.perf_counter() - t0)
 
     def append_abort(self, dc, txid) -> LogRecord:
         rec = abort_record(self._next_op_id(dc), txid)

@@ -873,9 +873,11 @@ def test_key_evicted_by_the_captures_flush_takes_the_host_path(tmp_path):
     re.close()
 
 
-@pytest.mark.parametrize("readers", [0, 3])
-def test_checkpoints_beside_commits_lose_and_double_nothing(tmp_path,
-                                                            readers):
+@pytest.mark.parametrize("readers,sync_log", [
+    pytest.param(0, False, id="0"), pytest.param(3, False, id="3"),
+    pytest.param(3, True, id="3-sync_log")])
+def test_checkpoints_beside_commits_lose_and_double_nothing(
+        tmp_path, readers, sync_log):
     """Stress (time-bounded): eight committers, a counter each, beside
     one thread that cuts checkpoint after checkpoint — and, in the
     second case, three readers whose reads reach the planes (a value
@@ -892,11 +894,15 @@ def test_checkpoints_beside_commits_lose_and_double_nothing(tmp_path,
     it twice (the parent: every run with readers).  (One writer a key:
     two uncertified writers of one key can publish against their
     commit times' order, which a seed's clock cannot tell from
-    "already folded", fold or no fold.)"""
+    "already folded", fold or no fold.)  Under ``sync_log`` (the
+    default order: publish, then wait for the fsync) a cut may fall
+    between a commit's publish and its acknowledgement: the record is
+    then below the cut and its effect in the seed, whether or not its
+    committer has been told."""
     import sys
 
     cfg = _mk_cfg(tmp_path, device_store=True, ckpt=True, n_partitions=1,
-                  ckpt_ops=1 << 30, ckpt_bytes=1 << 40)
+                  ckpt_ops=1 << 30, ckpt_bytes=1 << 40, sync_log=sync_log)
     node = Node(dc_id="dc1", config=cfg)
     pm = node.partitions[0]
     pm._val_cache_cap = 1

@@ -453,10 +453,12 @@ def test_wait_durable_times_out_on_uncoverable_ticket(tmp_path):
     log.close()
 
 
-def test_sync_wait_instant_joins_txn_tree(tmp_path):
-    """The per-committer log_sync_wait instant carries the txid, so a
-    sampled transaction's tree shows what its commit ack paid for
-    durability; the drain itself records a log_group_drain span."""
+def test_sync_wait_span_joins_txn_tree(tmp_path):
+    """The per-committer log_sync_wait is a WAIT span that carries the
+    txid, so a sampled transaction's tree shows what its commit ack
+    paid for durability and no work span above it counts the sleep as
+    its own; the leader's drain and its fsync nest under it, the fsync
+    a wait span around the backend's call alone."""
     from antidote_tpu.obs.spans import tracer
 
     old_rate = tracer.sample_rate
@@ -468,13 +470,101 @@ def test_sync_wait_instant_joins_txn_tree(tmp_path):
         plog.append_update("dc1", txid, "k", "counter_pn", 1)
         plog.append_commit("dc1", txid, 5, VC())
         plog.wait_durable(plog.commit_ticket(), txid=txid)
-        waits = tracer.spans(txid=txid, name="log_sync_wait")
-        assert waits and waits[0].cat == "oplog"
-        assert waits[0].args["led"] is True
-        assert tracer.spans(name="log_group_drain")
+        (wait,) = tracer.spans(txid=txid, name="log_sync_wait")
+        assert (wait.cat, wait.kind) == ("oplog", "wait")
+        assert wait.args["led"] is True and wait.args["records"] == 2
+        assert wait.args["partition"] == 0
+        (drain,) = tracer.spans(txid=txid, name="log_group_drain")
+        (fsync,) = tracer.spans(txid=txid, name="log_fsync")
+        assert drain.kind == "work" and drain.parent_id == wait.span_id
+        assert fsync.kind == "wait" and fsync.parent_id == drain.span_id
         plog.close()
     finally:
         tracer.sample_rate = old_rate
+
+
+class _SlowSync:
+    """A pinned backend whose sync sleeps first: the disk of a slow
+    machine, inside ``_backend_sync``'s own call."""
+
+    def __init__(self, io, seconds):
+        self.io, self.seconds = io, seconds
+
+    def sync(self):
+        time.sleep(self.seconds)
+        if isinstance(self.io, tuple):
+            self.io[0].oplog_sync(self.io[1])
+        else:
+            self.io.sync()
+
+
+def _committed_capture(tmp_path, monkeypatch, sync_log, slow_s=0.0):
+    """Six static updates through the API — three of one key, three of
+    eight keys over both partitions — inside a capture; the capture's
+    summary."""
+    import contextlib
+
+    from antidote_tpu.api import AntidoteTPU
+    from antidote_tpu.obs.spans import summarize, tracer
+
+    if slow_s:
+        real = DurableLog._backend_sync
+        monkeypatch.setattr(
+            DurableLog, "_backend_sync",
+            lambda self, io: real(self, _SlowSync(io, slow_s)))
+    db = AntidoteTPU(config=Config(n_partitions=2, sync_log=sync_log,
+                                   device_store=False),
+                     data_dir=str(tmp_path / "data"))
+    keys = [(i, "counter_pn", "b") for i in range(8)]  # i % 2
+    tracer.capture_begin(lambda name: contextlib.nullcontext())
+    try:
+        clock = None
+        for _ in range(3):
+            clock = db.update_objects_static(
+                clock, [(keys[0], "increment", 1)])
+            clock = db.update_objects_static(
+                clock, [(k, "increment", 1) for k in keys])
+    finally:
+        raw = tracer.capture_end()
+    assert db.read_objects_static(clock, keys)[0] == [6] + [3] * 7
+    db.close()
+    return summarize(**raw)
+
+
+def test_a_slow_fsync_is_nobodys_work(tmp_path, monkeypatch):
+    """Every fsync slowed to 20 ms inside a capture: the sleeps are
+    wait spans, so the capture's ``host_busy_s`` leaves them out, and
+    so does the self time of the coordinator's commit spans above
+    them (``frontend_self_ms_per_txn`` and ``host_busy_pct`` read
+    those)."""
+    cap = _committed_capture(tmp_path, monkeypatch, True, slow_s=0.02)
+    rows = cap["spans"]
+    fsync, wait = rows["log_fsync"], rows["log_sync_wait"]
+    assert fsync["kind"] == wait["kind"] == "wait"
+    # three single-partition commits and three over two partitions
+    assert wait["count"] == 3 + 3 * 2 and fsync["count"] >= wait["count"]
+    slept = fsync["total_s"]
+    assert slept >= 0.02 * fsync["count"]
+    assert wait["total_s"] >= slept - 1e-4
+    assert rows["log_group_drain"]["self_s"] < 0.02
+    assert (rows["single_commit"]["count"],
+            rows["2pc_commit"]["count"]) == (3, 3)
+    for name, waits in (("single_commit", 3), ("2pc_commit", 6)):
+        row = rows[name]
+        assert row["total_s"] >= 0.02 * waits
+        assert row["self_s"] <= row["total_s"] - 0.02 * waits, name
+    # one thread committed: what it slept on the disk it was not busy
+    assert cap["host_busy_s"] <= cap["length_s"] - slept + 1e-4
+
+
+def test_without_sync_log_a_commit_records_neither_wait(tmp_path,
+                                                        monkeypatch):
+    cap = _committed_capture(tmp_path, monkeypatch, False)
+    assert cap["spans"]["single_commit"]["count"] == 3
+    assert cap["spans"]["2pc_commit"]["count"] == 3
+    assert "log_sync_wait" not in cap["spans"]
+    assert "log_fsync" not in cap["spans"]
+    assert "log_group_drain" not in cap["spans"]
 
 
 def test_recovery_identical_across_group_modes(tmp_path, backend):
