@@ -352,16 +352,13 @@ class _PlaneBase:
                             (b, 2 + ingest.packed_width(cols, d)),
                             dtype=np.int64)
                         pk[:, 0] = cap  # all padding: a no-op program
-                        st, _over = ingest.packed_append(
-                            st, jnp.asarray(pk))
+                        st, _over = ingest.packed_append(st, pk)
                         continue
                     ki = np.full(b, cap, dtype=np.int32)
                     lo = np.zeros(b, dtype=np.int32)
                     arrays = [np.zeros((b, d) if tag == "vv" else b,
                                        dtype=np.int64) for tag in cols]
-                    st, _over = fn(st, jnp.asarray(ki),
-                                   jnp.asarray(lo),
-                                   *(jnp.asarray(a) for a in arrays))
+                    st, _over = fn(st, ki, lo, *arrays)
                 except Exception:  # noqa: BLE001 — warm is best-effort
                     # the serving path will meet the same program: a
                     # compiler refusal must be seen here first
@@ -460,7 +457,8 @@ class _PlaneBase:
         """Device-append decoded rows; returns bool[n] overflow.
 
         Coalesced path (mat/ingest.py, default): ONE packed host
-        tensor, ONE upload, one donated-scatter dispatch.  Legacy path
+        tensor, ONE upload (the jitted call's own, of the NumPy
+        argument), one donated-scatter dispatch.  Legacy path
         (``mat_ingest=False``): the historical per-column packing —
         ~10 separate uploads per flush — kept as the benches'
         comparison baseline."""
@@ -475,8 +473,7 @@ class _PlaneBase:
             with self._collective_cm(), \
                     tracer.span("device_dispatch", "device",
                                 plane=self.type_name, rows=n):
-                self.st, overflow = ingest.packed_append(
-                    self.st, jnp.asarray(packed))
+                self.st, overflow = ingest.packed_append(self.st, packed)
             ingest.note_dispatch(
                 n, packed.nbytes,
                 replicas=(self._mesh.shape["part"]
@@ -488,8 +485,7 @@ class _PlaneBase:
                     tracer.span("device_dispatch", "device",
                                 plane=self.type_name, rows=n):
                 self.st, overflow = type(self)._append_fn(
-                    self.st, jnp.asarray(ki), jnp.asarray(lo),
-                    *(jnp.asarray(a) for a in arrays))
+                    self.st, ki, lo, *arrays)
         with tracer.wait_span("device_fetch", "device",
                               plane=self.type_name):
             return np.asarray(overflow)[:n]
@@ -592,16 +588,22 @@ class _PlaneBase:
         keys absent — callers serve them from the host path) that may
         run OUTSIDE the lock: the shard state is a functional pytree,
         so a concurrent flush/GC only swaps ``self.st`` with a new
-        value and never mutates what the closure captured.  This is
-        the read-concurrency analogue of the reference's shared-ETS
-        readers next to the vnode process (reference
-        src/clocksi_readitem_server.erl:95-110)."""
+        value and never mutates what the closure captured.  The host
+        arrays it captured beside the state (the padded index vector,
+        an explicit snapshot's dense row) stay NumPy until the jitted
+        call takes them as its arguments — uploading them here would
+        be Python-path work under the lock — and are a snapshot all
+        the same: each is built fresh per capture and nothing writes
+        it afterwards.  This is the read-concurrency analogue of the
+        reference's shared-ETS readers next to the vnode process
+        (reference src/clocksi_readitem_server.erl:95-110)."""
         if self.pending_keys and not self.pending_keys.isdisjoint(keys):
             self.flush("read")
         owned = [k for k in keys if k in self.key_index]
         if not owned:
             return dict
-        # argument preparation: indices and snapshot to device arrays
+        # argument preparation: the indices and the snapshot as host
+        # arrays; the dispatch's jitted call uploads them
         with tracer.span("device_prepare", "device",
                          plane=self.type_name, keys=len(owned)):
             rv = self._read_vc_dense(read_vc)
@@ -1052,12 +1054,12 @@ class OrsetPlane(_PlaneBase):
 
     def _purge_idx(self, idx):
         self.st = store.orset_purge_keys(
-            self.st, jnp.asarray([idx], dtype=np.int32))
+            self.st, np.asarray([idx], dtype=np.int32))
         self.elem_index[idx] = {}
         self.rev_elems[idx] = []
 
     def _device_gc(self, gst_dense):
-        self.st = store.orset_gc(self.st, jnp.asarray(gst_dense))
+        self.st = store.orset_gc(self.st, gst_dense)
 
     def _many_split(self, st, owned, idxs, pad, rv):
         # captured under the lock; safe after release (see
@@ -1083,8 +1085,7 @@ class OrsetPlane(_PlaneBase):
                 out[k] = state
             return out
 
-        return ((store.orset_read_keys,
-                 (st, jnp.asarray(pad), jnp.asarray(rv))), post)
+        return ((store.orset_read_keys, (st, pad, rv)), post)
 
 
 class CounterPlane(_PlaneBase):
@@ -1123,17 +1124,16 @@ class CounterPlane(_PlaneBase):
 
     def _purge_idx(self, idx):
         self.st = store.counter_purge_keys(
-            self.st, jnp.asarray([idx], dtype=np.int32))
+            self.st, np.asarray([idx], dtype=np.int32))
 
     def _device_gc(self, gst_dense):
-        self.st = store.counter_gc(self.st, jnp.asarray(gst_dense))
+        self.st = store.counter_gc(self.st, gst_dense)
 
     def _many_split(self, st, owned, idxs, pad, rv):
         def post(vals):
             return {k: int(vals[i]) for i, k in enumerate(owned)}
 
-        return ((store.counter_read_keys,
-                 (st, jnp.asarray(pad), jnp.asarray(rv))), post)
+        return ((store.counter_read_keys, (st, pad, rv)), post)
 
 
 class MvregPlane(OrsetPlane):
@@ -1179,7 +1179,7 @@ class MvregPlane(OrsetPlane):
         return [("asgn", v, dot, ()) for dot, v in state]
 
     def _device_gc(self, gst_dense):
-        self.st = store.mvreg_gc(self.st, jnp.asarray(gst_dense))
+        self.st = store.mvreg_gc(self.st, gst_dense)
 
     def _many_split(self, st, owned, idxs, pad, rv):
         val_lists = [self.rev_elems[i] for i in idxs]
@@ -1199,8 +1199,7 @@ class MvregPlane(OrsetPlane):
                 out[k] = frozenset(pairs)
             return out
 
-        return ((store.mvreg_read_keys,
-                 (st, jnp.asarray(pad), jnp.asarray(rv))), post)
+        return ((store.mvreg_read_keys, (st, pad, rv)), post)
 
 
 class FlagEwPlane(OrsetPlane):
@@ -1257,8 +1256,7 @@ class FlagEwPlane(OrsetPlane):
                 for i, k in enumerate(owned)
             }
 
-        return ((store.orset_read_keys,
-                 (st, jnp.asarray(pad), jnp.asarray(rv))), post)
+        return ((store.orset_read_keys, (st, pad, rv)), post)
 
 
 class RwsetPlane(OrsetPlane):
@@ -1342,12 +1340,12 @@ class RwsetPlane(OrsetPlane):
 
     def _purge_idx(self, idx):
         self.st = store.rwset_purge_keys(
-            self.st, jnp.asarray([idx], dtype=np.int32))
+            self.st, np.asarray([idx], dtype=np.int32))
         self.elem_index[idx] = {}
         self.rev_elems[idx] = []
 
     def _device_gc(self, gst_dense):
-        self.st = store.rwset_gc(self.st, jnp.asarray(gst_dense))
+        self.st = store.rwset_gc(self.st, gst_dense)
 
     @staticmethod
     def _dots_of(row, actors):
@@ -1375,8 +1373,7 @@ class RwsetPlane(OrsetPlane):
                 out[k] = state
             return out
 
-        return ((store.rwset_read_keys,
-                 (st, jnp.asarray(pad), jnp.asarray(rv))), post)
+        return ((store.rwset_read_keys, (st, pad, rv)), post)
 
 
 class FlagDwPlane(RwsetPlane):
@@ -1432,8 +1429,7 @@ class FlagDwPlane(RwsetPlane):
                 for i, k in enumerate(owned)
             }
 
-        return ((store.rwset_read_keys,
-                 (st, jnp.asarray(pad), jnp.asarray(rv))), post)
+        return ((store.rwset_read_keys, (st, pad, rv)), post)
 
 
 class SetGoPlane(OrsetPlane):
@@ -1488,12 +1484,12 @@ class SetGoPlane(OrsetPlane):
 
     def _purge_idx(self, idx):
         self.st = store.setgo_purge_keys(
-            self.st, jnp.asarray([idx], dtype=np.int32))
+            self.st, np.asarray([idx], dtype=np.int32))
         self.elem_index[idx] = {}
         self.rev_elems[idx] = []
 
     def _device_gc(self, gst_dense):
-        self.st = store.setgo_gc(self.st, jnp.asarray(gst_dense))
+        self.st = store.setgo_gc(self.st, gst_dense)
 
     def _many_split(self, st, owned, idxs, pad, rv):
         elem_lists = [self.rev_elems[i] for i in idxs]
@@ -1506,8 +1502,7 @@ class SetGoPlane(OrsetPlane):
                 for i, k in enumerate(owned)
             }
 
-        return ((store.setgo_read_keys,
-                 (st, jnp.asarray(pad), jnp.asarray(rv))), post)
+        return ((store.setgo_read_keys, (st, pad, rv)), post)
 
 
 #: tiebreak packing: rank << _TIE_SHIFT | seq (seq must fit the low bits)
@@ -1634,10 +1629,10 @@ class LwwPlane(_PlaneBase):
 
     def _purge_idx(self, idx):
         self.st = store.lww_purge_keys(
-            self.st, jnp.asarray([idx], dtype=np.int32))
+            self.st, np.asarray([idx], dtype=np.int32))
 
     def _device_gc(self, gst_dense):
-        self.st = store.lww_gc(self.st, jnp.asarray(gst_dense))
+        self.st = store.lww_gc(self.st, gst_dense)
 
     def _many_split(self, st, owned, idxs, pad, rv):
         # actors_sorted is REPLACED wholesale on a rank repack (which
@@ -1659,8 +1654,7 @@ class LwwPlane(_PlaneBase):
                               vals[int(val[i])])
             return out
 
-        return ((store.lww_read_keys,
-                 (st, jnp.asarray(pad), jnp.asarray(rv))), post)
+        return ((store.lww_read_keys, (st, pad, rv)), post)
 
 
 #: bottom (empty) nested states as the planes reconstruct them — used by
@@ -1940,8 +1934,7 @@ class RgaPlane(_PlaneBase):
         elems = list(self.rev_elems[idx])
 
         def run():
-            lam, act, elem, vis, n = rga_store.rga_read(
-                sti, jnp.asarray(rv))
+            lam, act, elem, vis, n = rga_store.rga_read(sti, rv)
             lam = np.asarray(lam)
             act = np.asarray(act)
             elem = np.asarray(elem)

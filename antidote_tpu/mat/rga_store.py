@@ -298,8 +298,7 @@ def rga_append_coalesced(st: RgaStoreState, ins_cols, del_cols,
     for j, a in zip((_PK_LAM, _PK_ACT, _PK_DC, _PK_CT), del_cols[:4]):
         dl[:c, j] = np.asarray(a)
     dl[:c, _PK_NSCAL:] = np.asarray(del_cols[4])
-    st, ok = rga_append_packed(st, jnp.asarray(packed), bp=bp,
-                               n_ins=b, n_del=c)
+    st, ok = rga_append_packed(st, packed, bp=bp, n_ins=b, n_del=c)
     ingest.note_dispatch(b + c, packed.nbytes)
     return st, ok
 
@@ -707,5 +706,5 @@ def rga_fold_host(st: RgaStoreState, gst) -> RgaStoreState:
         while new_pb < need:
             new_pb *= 2
         st = rga_grow(st, pb=new_pb)
-    st, _bn = rga_fold(st, jnp.asarray(gst))
+    st, _bn = rga_fold(st, gst)
     return st
