@@ -33,7 +33,7 @@ from dataclasses import dataclass, field
 import numpy as np
 
 from benchmark import reference, trace
-from benchmark.traffic import ORIGIN_DC, Keyspace, Mix, rng_for
+from benchmark.traffic import ORIGIN_DC, ClientStream, Keyspace, Mix, rng_for
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 LOAD_TXN = 1024
@@ -121,10 +121,14 @@ def load_cell(root: str, workload: str) -> Cell:
     readers = {m["name"]: _load_reader(_find(
         root, paths, "layer_metrics", m["name"] + ".py"))
         for m in per_layer}
-    return Cell(name=workload, chips=int(w["chips"]), config=config,
+    cell = Cell(name=workload, chips=int(w["chips"]), config=config,
                 mix=Mix.from_file(mix_file), mix_file=mix_file,
                 end_to_end=end_to_end, per_layer=per_layer,
                 readers=readers)
+    # the mix meets its keyspace here: a key generator that cannot draw
+    # over it refuses now, before set-up, not in a client after the load
+    ClientStream(cell.mix, cell.keyspace, 0, 0)
+    return cell
 
 
 # ------------------------------------------------------------ the watchers

@@ -1,14 +1,12 @@
 """Device planes (mat/device_plane.py): the host's time for one device
 dispatch in the traced slice, from argument preparation
 (``device_prepare``) through the enqueue (``device_dispatch``) to the
-values on the host (``device_fetch``, which waits for the device; a
-single key's fold, ``device_read``, is all three in one), per
+values on the host (``device_fetch``, which waits for the device), per
 dispatch.  From ``obs.prof.last_capture()``.  Moves ``read_p95_ms``:
 a read is one or two of these end to end."""
 
-PARTS = ("device_prepare", "device_dispatch", "device_fetch",
-         "device_read")
-DISPATCHES = ("device_dispatch", "device_read")
+PARTS = ("device_prepare", "device_dispatch", "device_fetch")
+DISPATCHES = ("device_dispatch",)
 
 
 def read(w):

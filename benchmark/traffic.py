@@ -6,10 +6,16 @@ basho_bench's ``antidote_pb`` driver names: closed-loop workers, weighted
 operations, keys per transaction, a key generator.  Nothing here knows a
 mix by name; a later PR adds a mix as a file.
 
-Every client draws the keys it reads and the keys it updates
-``uniform_int`` over the whole keyspace, as the source's workers do, so
-two writers can meet on a key and write-write certification can abort
-one of them; the client sends an aborted transaction again (client.py).
+Every client draws the keys it reads and the keys it updates through
+the mix's one key generator over the whole keyspace, as the source's
+workers do, so two writers can meet on a key and write-write
+certification can abort one of them; the client sends an aborted
+transaction again (client.py).
+
+The key generators are basho_bench's (``basho_bench_keygen.erl``), as
+the source spells them and with the source's constants: a mix names a
+kind and sets nothing: ``uniform_int``, and ``pareto_int``, whose draw
+is the key itself, so low keys are hot.
 """
 
 from __future__ import annotations
@@ -32,6 +38,48 @@ TYPE_PERIOD = 4
 def rng_for(seed: int, *stream: int) -> np.random.Generator:
     """``--seed`` is any whole number up to a little over 2**31."""
     return np.random.default_rng([abs(int(seed)), *stream])
+
+
+#: ``pareto_int``'s constants are the source's own, compiled into
+#: ``basho_bench_keygen.erl`` (``?PARETO_SHAPE``, and the mean as a share
+#: of ``MaxKey``): a mix does not set them
+PARETO_SHAPE = 1.5
+PARETO_MEAN_FRAC = 0.2
+
+
+def _uniform_int(n_keys: int):
+    return lambda rng, n: rng.integers(0, n_keys, size=n)
+
+
+def _pareto_int(n_keys: int):
+    """The source's ``{pareto_int, MaxKey}``, its ``pareto/2``:
+    ``trunc((U^(-1/Shape) - 1) * Mean * (Shape - 1))`` with ``Mean =
+    trunc(0.2 * MaxKey)`` and ``U`` uniform on (0, 1]: a fifth of the
+    keys takes four fifths of the draws.  The one departure: the source
+    lets a draw at or beyond ``MaxKey`` through as a new key (2.7 % of
+    the draws); here it is dropped and drawn again, so the lowest fifth
+    of the keys takes 83 % and ``draw`` returns ``n`` keys or fewer.
+    Let through, such keys are served and read right, but in 8 of 15
+    runs on the chip a read program then compiled inside the window
+    (PERF.md, PR 35): the warm-up knows the rows the load wrote."""
+    mean = int(PARETO_MEAN_FRAC * n_keys)
+    if mean < 1:
+        raise ValueError(f"pareto_int over {n_keys} keys: a mean of "
+                         f"{PARETO_MEAN_FRAC} of them is under one key, "
+                         "and every draw would be key 0")
+
+    def draw(rng, n):
+        u = 1.0 - rng.random(size=n)
+        x = np.trunc((u ** (-1.0 / PARETO_SHAPE) - 1.0)
+                     * mean * (PARETO_SHAPE - 1.0))
+        return x[x < n_keys].astype(np.int64)
+
+    return draw
+
+
+#: kind -> the draw over a keyspace of ``n_keys``: ``draw(rng, n)`` gives
+#: up to ``n`` keys.  A mix's entry is ``{"kind": <kind>}`` and no more
+KEY_GENERATORS = {"uniform_int": _uniform_int, "pareto_int": _pareto_int}
 
 
 @dataclass(frozen=True)
@@ -99,9 +147,10 @@ class Mix:
         unknown = set(doc["operations"]) - set(cls.KINDS)
         if unknown or not doc["operations"]:
             raise ValueError(f"{path}: unknown operations {unknown}")
-        if doc["key_generator"]["kind"] != "uniform_int":
-            raise ValueError(f"{path}: unknown key generator "
-                             f"{doc['key_generator']}")
+        known = [{"kind": kind} for kind in KEY_GENERATORS]
+        if doc["key_generator"] not in known:
+            raise ValueError(f"{path}: key generator "
+                             f"{doc['key_generator']} is none of {known}")
         return cls(name=doc["name"], clients=int(doc["clients"]),
                    operations=dict(doc["operations"]),
                    num_reads=int(doc["num_reads"]),
@@ -130,12 +179,14 @@ class ClientStream:
         kinds = [k for k in Mix.KINDS if mix.operations.get(k)]
         w = np.array([mix.operations[k] for k in kinds], dtype=float)
         self._kinds, self._cum = kinds, np.cumsum(w / w.sum())
+        self._draw = KEY_GENERATORS[mix.key_generator["kind"]](ks.n_keys)
 
     def _keys(self, n: int) -> list:
-        """``n`` distinct keys, uniform over the whole keyspace."""
+        """``n`` distinct keys over the whole keyspace, each a draw of
+        the mix's key generator."""
         out: dict = {}
         while len(out) < n:
-            for k in self.rng.integers(0, self.ks.n_keys, size=n):
+            for k in self._draw(self.rng, n):
                 out.setdefault(int(k), None)
                 if len(out) == n:
                     break

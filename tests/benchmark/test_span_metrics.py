@@ -50,7 +50,6 @@ SUMMARY = {
         "device_prepare": row("device", "work", 150, 0.06, 0.06),
         "device_dispatch": row("device", "work", 70, 0.14, 0.04),
         "device_fetch": row("device", "wait", 70, 0.35, 0.35),
-        "device_read": row("device", "work", 30, 0.15, 0.15),
     },
 }
 WANT = {
@@ -59,7 +58,7 @@ WANT = {
     "serve_queue_wait_p95_ms": 21.0,
     "manager_wait_ms_per_txn": 1000.0 * (0.10 + 0.03 + 0.07) / 100,
     "device_host_ms_per_dispatch":
-        1000.0 * (0.06 + 0.14 + 0.35 + 0.15) / (70 + 30),
+        1000.0 * (0.06 + 0.14 + 0.35) / 70,
     "host_busy_pct": 80.0,
 }
 
@@ -107,8 +106,11 @@ def test_without_a_summary_a_span_reader_returns_an_empty_sum(
 def test_the_new_entries_are_declared_as_the_issue_gives_them():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
+    # by name: where an entry stands in the list is the driver's
+    # matter (a later PR adds its own at the end)
     declared = {m["name"]: m for m in bench["per_layer"]}
-    assert [m["name"] for m in bench["per_layer"]][-5:] == list(NEW)
+    assert len(declared) == len(bench["per_layer"])
+    assert set(NEW) <= set(declared)
     for name, unit, layer, moves in [
             ("frontend_self_ms_per_txn", "ms/txn",
              "wire server, API, coordinator", "txn_per_s"),
@@ -160,10 +162,23 @@ def test_after_a_real_capture_each_reads_above_zero(tmp_path, monkeypatch,
     cap = prof.last_capture()
     assert cap["dropped"] == 0 and cap["requests_answered"] > 50
     assert 0.9 < cap["length_s"] < 2.5          # the slice, 0.3 x 4 s
+    spans = cap["spans"]
     for name in ("pb_request", "pb_decode", "api_static_read",
                  "api_static_update", "txn_snapshot", "txn_commit",
                  "read_serve_queue_wait", "read_serve_drain",
                  "read_serve_classify", "read_serve_fold",
                  "device_prepare", "device_dispatch", "device_fetch",
-                 "device_read", "pb_encode_send"):
-        assert cap["spans"].get(name, {}).get("count", 0) > 0, name
+                 "txn_state_read", "pb_encode_send"):
+        assert spans.get(name, {}).get("count", 0) > 0, name
+    # the program has had no ``device_read`` span since PR 30 (one key
+    # is a batch of one and records the three parts), so the reader
+    # that named it until PR 35 read what it reads now
+    assert "device_read" not in spans
+    until_pr_35 = 1000.0 * sum(
+        spans.get(n, {}).get("total_s", 0.0) for n in (
+            "device_prepare", "device_dispatch", "device_fetch",
+            "device_read")) / sum(
+        spans.get(n, {}).get("count", 0) for n in (
+            "device_dispatch", "device_read"))
+    assert line["metrics"]["device_host_ms_per_dispatch"]["value"] == \
+        pytest.approx(until_pr_35)
