@@ -115,7 +115,8 @@ class NodeInterDc:
             # config routes the ship knobs through (the gate_from_config
             # lesson: federated senders must honor interdc_ship too)
             sender = InterDcLogSender(self.dc_id, p, bus, enabled=False,
-                                      config=node.config)
+                                      config=node.config,
+                                      min_prepared=pm.min_prepared)
             sender.seed_watermark(pm.log.op_counters.get(self.dc_id, 0))
             pm.log.on_append = (
                 lambda rec, _s=sender: _s.on_append(rec))
@@ -200,7 +201,8 @@ class NodeInterDc:
                 pm = node.partitions[p]
                 sender = InterDcLogSender(self.dc_id, p, self.bus,
                                           enabled=bool(self.remote),
-                                          config=node.config)
+                                          config=node.config,
+                                          min_prepared=pm.min_prepared)
                 sender.seed_watermark(
                     pm.log.op_counters.get(self.dc_id, 0))
                 pm.log.on_append = (

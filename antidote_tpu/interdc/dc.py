@@ -227,7 +227,8 @@ class DataCenter(AntidoteTPU):
             s.close()
         self.senders = [
             InterDcLogSender(dc_id, p, self.bus, enabled=False,
-                             config=node.config)
+                             config=node.config,
+                             min_prepared=node.partitions[p].min_prepared)
             for p in range(n)
         ]
         self.dep_gates = [

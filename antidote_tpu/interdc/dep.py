@@ -6,16 +6,25 @@ txn's snapshot with the origin entry zeroed (the origin dependency is
 already guaranteed by FIFO order + opid continuity) — reference
 try_store, src/inter_dc_dep_vnode.erl:121-154.  Applying a txn appends
 its records to the local log without assigning local ids and pushes the
-effects into the materializer store (:144-152).  Heartbeats advance the
-origin's clock entry to their stamp MINUS ONE — a deliberate hardening
-over the reference's inclusive advance (:124-125): the heartbeat's
-contract is "no future txn commits with a SMALLER time"
-(inter_dc_log_sender_vnode.erl:92), and a commit at EXACTLY the stamp
-can still be in flight (Clock-SI commit time = max of prepare times =
-the max-prepare partition's min_prepared), so the inclusive form lets a
-causal reader pass the stable wait and miss that txn (see
-_process_host).  Queues are processed to fixpoint whenever the clock
-advances (:96-117).
+effects into the materializer store (:144-152).  Queues are processed to
+fixpoint whenever the clock advances (:96-117).
+
+What this DC may call applied of an origin — its entry of
+``applied_vc``, which ``Node.stable_vc()`` and every causal read's wait
+stand on — is ONE rule (``DependencyGate._raise_watermarks`` on the
+host, ``gate_kernels.fixpoint`` on the device): the newest heartbeat
+stamp received from that origin, lowered to the smallest commit time
+among its txns received and not yet applied, less one.  The stamp is
+the origin partition's min-prepared time, the only promise there is
+that nothing more will commit below a time; "less one" because a commit
+at EXACTLY the stamp can still be in flight (Clock-SI commit time = max
+of prepare times = the max-prepare partition's min_prepared).  The
+reference advances on every applied txn's commit time and on a blocked
+head's (inter_dc_dep_vnode.erl:122-154), which presumes that a stream
+arrives in commit-time order; it arrives in LOG order, and two txns
+prepared together log their commits in either order, so that rule let a
+read at a commit clock through before the commit had arrived (PERF.md
+§7.2).
 
 At a handful of DCs the fixpoint is a host walk over queue heads.  At
 hundreds of DCs (BASELINE config 5) the walk is the bottleneck, so past
@@ -89,19 +98,16 @@ def _note_gate_admitted(n: int) -> None:
             reg.gate_admitted_batched.value() / total)
 
 
-def _pack_txn_row(txn, cols: Dict[Any, int], ss_row) -> Tuple[int, bool]:
-    """Encode one queued txn into a dense dependency row: fill
-    ``ss_row`` (an int64[D] view) with the snapshot VC under the
-    ``cols`` column map and return (ts, is_ping), with the ping's
-    EXCLUSIVE ts-1 advance (see _process_host) already applied.  The
-    ONE row encoding shared by the ring append, the ring bulk load,
-    and the legacy repack packer — test_batched_matches_host_walk
-    relies on the three staying bit-for-bit equivalent."""
-    if txn.is_ping():
-        return txn.timestamp - 1, True
+def _pack_txn_row(txn, cols: Dict[Any, int], ts, ss, i: int) -> None:
+    """Encode one queued txn as row ``i`` of an upload: its commit
+    time into ``ts``, its snapshot VC into ``ss`` under the ``cols``
+    column map.  The ONE row encoding shared by the ring append, the
+    ring bulk load, and the legacy repack packer —
+    test_batched_matches_host_walk relies on the three staying
+    bit-for-bit equivalent."""
+    ts[i] = txn.timestamp
     for dc, t in txn.snapshot_vc.items():
-        ss_row[cols[dc]] = t
-    return txn.timestamp, False
+        ss[i, cols[dc]] = t
 
 
 def gate_from_config(pm, own_dc, now_us: Callable[[], int],
@@ -132,11 +138,22 @@ class DependencyGate:
         self.own_dc = own_dc
         self.now_us = now_us
         #: origin DC -> FIFO of InterDcTxn waiting on their dependencies
+        #: (heartbeats never queue: enqueue_batch keeps their stamp)
         self.queues: Dict[Any, deque] = {}
-        #: origin DC -> timestamp watermark of applied txns / heartbeats
-        #: (seeded from the recovered log's max commit VC at restart,
-        #: reference set_dependency_clock src/inter_dc_dep_vnode.erl:82-83)
+        #: origin DC -> watermark: every txn of the origin's stream
+        #: that commits at or below it is applied here.  Raised only by
+        #: _raise_watermarks (seeded from the recovered log's max commit
+        #: VC at restart, reference set_dependency_clock
+        #: src/inter_dc_dep_vnode.erl:82-83)
         self.applied_vc = VC()
+        #: origin DC -> newest heartbeat stamp received, and the local
+        #: µs it arrived at (its age is queue_stats()' lag diagnosis)
+        self.stamps: Dict[Any, int] = {}
+        self.stamp_at: Dict[Any, int] = {}
+        #: origin DC -> [(commit time, txid, origin commit wallclock)]
+        #: of txns applied above the watermark: not yet readable at
+        #: their commit clock (_advance records their visibility)
+        self._unseen: Dict[Any, list] = {}
         #: tap invoked after the partition VC advances (feeds the
         #: stable-time tracker, throttled by the caller if needed)
         self.on_clock_update: Callable[[], None] = lambda: None
@@ -209,30 +226,39 @@ class DependencyGate:
         """Stage one arrival — a single delivery or a whole wire
         batch's txns (ISSUE 6) — then run at most ONE gating pass: the
         ring appends the arrival in one scatter and the fixpoint
-        admits it in one dispatch, instead of a pass per txn.
+        admits it in one dispatch, instead of a pass per txn.  A
+        heartbeat is kept as its origin's stamp, wherever in the
+        arrival it stands: SubBuf delivered everything logged before
+        it, which is all the stamp's promise needs.
 
         Skip rules: txns landing behind their origins' blocked heads
         cannot change the fixpoint (FIFO: they only apply after the
-        head, whose dependencies are unchanged) — an all-backlogged
-        arrival skips the reprocess so ingest under a partition stays
-        O(1) per frame, except for an occasional pass that picks up
-        heads gated only on the advancing local wall clock.  And the
-        coalescing window (ISSUE 3): in the batched regime, arrivals
-        right after a pass stage instead of dispatching — the next
-        pass admits the whole burst with ONE device fixpoint."""
+        head, whose dependencies are unchanged) — an arrival that is
+        all backlog and brings no stamp skips the reprocess, except
+        for an occasional pass that picks up heads gated only on the
+        advancing local wall clock.  And the coalescing window
+        (ISSUE 3): in the batched regime, arrivals right after a pass
+        stage instead of dispatching — the next pass admits the whole
+        burst with ONE device fixpoint."""
         if not txns:
             return
         now = self.now_us()
-        head_new = False
+        news = False
         for txn in txns:
+            if txn.is_ping():
+                if txn.timestamp > self.stamps.get(txn.dc_id, 0):
+                    self.stamps[txn.dc_id] = txn.timestamp
+                    self.stamp_at[txn.dc_id] = now
+                    news = True
+                continue
             # gate-wait clock: _apply reads it back for the dep-gate
             # wait histogram and the admit span of the txn's trace tree
             txn._obs_enq_us = now
             q = self.queues.setdefault(txn.dc_id, deque())
             q.append(txn)
-            head_new |= len(q) == 1
+            news |= len(q) == 1
         since_proc = now - self._last_proc_us
-        if not head_new and since_proc < 50_000:
+        if not news and since_proc < 50_000:
             return
         if (self.coalesce_us > 0 and 0 <= since_proc < self.coalesce_us
                 and self.pending() >= self.batch_threshold):
@@ -241,39 +267,15 @@ class DependencyGate:
         self.process_queues()
 
     def process_queues(self) -> None:
-        """Drain every origin queue to fixpoint: applying a txn (or ping)
-        advances the clock, which may unblock other origins' heads.
-
-        A BLOCKED head still advances its origin's clock to
-        ``timestamp - 1`` (the reference's blocked-txn rule,
-        src/inter_dc_dep_vnode.erl:137-143): delivery is FIFO and
-        gap-repaired, so the origin's stream is complete below the
-        head's commit time, and another origin's head may depend on a
-        time up to it.  Without this, three DCs can cross-deadlock
-        after a partition window whose heartbeats were lost — each
-        head waiting on a clock entry only another blocked head's
-        stream can provide (caught by the multidc chaos test)."""
+        """Drain every origin queue to fixpoint: a stamp or an apply
+        raises a watermark, which may unblock other origins' heads."""
         self._last_proc_us = self.now_us()
-        advanced_any = False
-        while True:
-            pend = self.pending()
-            if pend == 0:
-                break
-            if pend >= self.batch_threshold:
-                advanced_any |= self._timed_pass(pend)
-            else:
-                advanced_any |= self._process_host()
-            head_advanced = False
-            for origin, q in self.queues.items():
-                if q and not q[0].is_ping() and \
-                        self.applied_vc.get_dc(origin) < \
-                        q[0].timestamp - 1:
-                    self._advance(origin, q[0].timestamp - 1)
-                    head_advanced = True
-            if not head_advanced:
-                break
-            advanced_any = True  # clock moved: rerun, it may unblock
-        if advanced_any:
+        pend = self.pending()
+        if pend and pend >= self.batch_threshold:
+            advanced = self._timed_pass(pend)
+        else:
+            advanced = self._process_host()
+        if advanced:
             self.on_clock_update()
 
     def _timed_pass(self, pend: int) -> bool:
@@ -314,51 +316,48 @@ class DependencyGate:
             return self._cost_batched >= self._cost_host
         return self._cost_batched < self._cost_host
 
+    def _raise_watermarks(self) -> bool:
+        """THE rule for what this DC may call applied of an origin
+        (module doc; ``gate_kernels.fixpoint`` is its device form):
+        the newest stamp received, lowered to the smallest commit time
+        still queued — EVERY queued txn bounds it, not the head alone,
+        since the queue is in log order — less one either way.  An
+        origin that never stamped stays where seed_clock put it."""
+        moved = False
+        for origin, stamp in self.stamps.items():
+            if stamp - 1 <= self.applied_vc.get_dc(origin):
+                continue  # already at its stamp: no queue to look through
+            q = self.queues.get(origin)
+            if q:
+                stamp = min(stamp, min(txn.timestamp for txn in q))
+            moved |= self._advance(origin, stamp - 1)
+        return moved
+
     def _process_host(self) -> bool:
+        """The fixpoint as a walk over queue heads, in the kernel's
+        rounds: raise the watermarks, apply every FIFO prefix that
+        clock admits, and again while either moved something."""
         advanced = False
-        progress = True
-        while progress:
-            progress = False
+        while True:
+            progress = self._raise_watermarks()
+            pvc = self.partition_vc()
             for origin, q in self.queues.items():
-                while q:
-                    txn = q[0]
-                    if txn.is_ping():
-                        # EXCLUSIVE advance: the ping's contract is "no
-                        # FUTURE txn will commit with a SMALLER time"
-                        # (reference inter_dc_log_sender_vnode.erl:92)
-                        # — the stream is complete only BELOW the
-                        # stamp.  A commit at EXACTLY the stamp can
-                        # still be in flight: Clock-SI picks commit
-                        # time = max(prepare times), so the max-prepare
-                        # partition's min_prepared EQUALS the pending
-                        # commit's time, and its heartbeat can outrun
-                        # the commit record.  The reference advances
-                        # inclusively (inter_dc_dep_vnode.erl:122-125)
-                        # and carries this µs-level race; in-process
-                        # delivery here hits it ~5% of runs (caught by
-                        # tests/multidc/test_ring_placement.py under
-                        # load), so we harden to ts-1.
-                        self._advance(origin, txn.timestamp - 1)
-                        q.popleft()
-                        progress = advanced = True
-                        continue
-                    deps = VC(txn.snapshot_vc).set_dc(origin, 0)
-                    if self.partition_vc().ge(deps):
-                        try:
-                            self._apply(txn)
-                        except PartitionRetired:
-                            # the slice is mid-handoff (cutover set the
-                            # retired flag before the ring re-aim): stop
-                            # this pass with the txn still queued — the
-                            # new owner's sub-buffers resume at the
-                            # transferred opid counters, so nothing is
-                            # lost when refresh_ring drops this gate
-                            return advanced
-                        q.popleft()
-                        progress = advanced = True
-                    else:
-                        break
-        return advanced
+                while q and pvc.ge(VC(q[0].snapshot_vc).set_dc(origin, 0)):
+                    try:
+                        self._apply(q[0])
+                    except PartitionRetired:
+                        # the slice is mid-handoff (cutover set the
+                        # retired flag before the ring re-aim): stop
+                        # this pass with the txn still queued — the
+                        # new owner's sub-buffers resume at the
+                        # transferred opid counters, so nothing is
+                        # lost when refresh_ring drops this gate
+                        return advanced or progress
+                    q.popleft()
+                    progress = True
+            if not progress:
+                return advanced
+            advanced = True
 
     # ------------------------------------------------- batched (device)
 
@@ -374,55 +373,19 @@ class DependencyGate:
         ring = self._ring
         ring.sync()
         if ring.n_live == 0:
-            return False
+            return self._raise_watermarks()
         napp, applied, rounds, new_pvc = ring.run_fixpoint()
-        advanced = False
-        completed = True
+        wave = []
         if napp:
-            # replay in (round, fifo pos) order: round-r txns depend
-            # only on rounds < r, so this is a causal apply order (see
-            # gate_kernels.ring_fixpoint)
-            order = sorted(ring.applied_entries(applied),
-                           key=lambda e: (int(rounds[e[0]]), e[2]))
+            wave = [(int(rounds[slot]), pos, origin, txn, slot)
+                    for slot, origin, pos, txn
+                    in ring.applied_entries(applied)]
             ring.begin_wave()
-            for slot, origin, _pos, txn in order:
-                q = self.queues[origin]
-                assert q[0] is txn, \
-                    "device fixpoint applied out of FIFO order"
-                q.popleft()
-                if txn.is_ping():
-                    # exclusive ping advance (see _process_host)
-                    ring.pop_applied(slot)
-                    self._advance(origin, txn.timestamp - 1)
-                else:
-                    try:
-                        self._apply(txn)
-                    except PartitionRetired:
-                        # mid-handoff (see _process_host): re-queue and
-                        # stop WITHOUT folding the fixpoint clock — the
-                        # fold would cover the unapplied remainder.
-                        # Slots admitted so far retire at the next sync.
-                        q.appendleft(txn)
-                        completed = False
-                        break
-                    ring.pop_applied(slot)
-                advanced = True
+        advanced, completed = self._replay(wave, ring.cols, new_pvc,
+                                           ring.pop_applied)
+        if napp:
             ring.finish_wave(completed)
             _note_gate_admitted(len(ring.last_wave))
-        if not completed:
-            return advanced
-        # fold the kernel's final clock back AFTER the replay (it
-        # includes the blocked-head ts-1 advances; advancing before the
-        # records hit the materializer would let a concurrent
-        # partition_vc() reader see a stable time covering unapplied
-        # txns).  Applied watermarks are already in via _apply, so only
-        # the ts-1 component is new; the own column carried `now`, not
-        # an applied watermark — skip it.
-        for dc, c in ring.cols.items():
-            if dc != self.own_dc and int(new_pvc[c]) > \
-                    self.applied_vc.get_dc(dc):
-                self._advance(dc, int(new_pvc[c]))
-                advanced = True
         return advanced
 
     def _process_batched_repack(self) -> bool:
@@ -433,10 +396,9 @@ class DependencyGate:
         monotone cascade, evaluated data-parallel) — and to the ring
         form, which amortizes exactly this path's per-pass repack,
         upload, and fetch (GATE_* counters record both)."""
-        import jax.numpy as jnp
-
         # dense columns: every DC named by a queued txn, the applied
-        # watermarks, and the local DC (whose entry reads `now`)
+        # watermarks, the stamps, and the local DC (whose entry reads
+        # `now`)
         cols: Dict[Any, int] = {}
 
         def col_of(dc):
@@ -445,106 +407,122 @@ class DependencyGate:
             return cols[dc]
 
         col_of(self.own_dc)
-        for dc in self.applied_vc:
+        for dc in (*self.applied_vc, *self.stamps):
             col_of(dc)
         flat = []  # (origin, pos, txn)
         for origin, q in self.queues.items():
             col_of(origin)
             for pos, txn in enumerate(q):
-                if not txn.is_ping():
-                    for dc in txn.snapshot_vc:
-                        col_of(dc)
+                for dc in txn.snapshot_vc:
+                    col_of(dc)
                 flat.append((origin, pos, txn))
         n = len(flat)
         if n == 0:
-            return False
-        d = len(cols)
-        # pad to stable shapes so the jit cache stays small; padding rows
-        # are never ready (deps=+inf) and never block (pos=+inf/2)
+            return self._raise_watermarks()
+        # pad to stable shapes so the jit cache stays small; padding
+        # rows are not live
         n_pad = max(8, 1 << (n - 1).bit_length())
-        d_pad = max(8, 1 << (d - 1).bit_length())
-        BIG = np.int64(2**62)
+        d_pad = max(8, 1 << (len(cols) - 1).bit_length())
         ss = np.zeros((n_pad, d_pad), dtype=np.int64)
-        # padding rows must never be ready: the sentinel sits in column 1
-        # because gate_fixpoint zeroes each row's own origin column
-        # (padding origin_col is 0, which would erase a column-0 sentinel)
-        ss[n:, 1] = BIG
         origin_col = np.zeros(n_pad, dtype=np.int32)
-        pos_arr = np.full(n_pad, np.iinfo(np.int32).max // 2, np.int32)
+        pos_arr = np.zeros(n_pad, dtype=np.int32)
         ts = np.zeros(n_pad, dtype=np.int64)
-        ping = np.zeros(n_pad, dtype=bool)
+        live = np.arange(n_pad) < n
         for i, (origin, pos, txn) in enumerate(flat):
             origin_col[i] = cols[origin]
             pos_arr[i] = pos
-            ts[i], ping[i] = _pack_txn_row(txn, cols, ss[i])
-        pvc = np.zeros(d_pad, dtype=np.int64)
-        for dc, c in cols.items():
-            pvc[c] = self.applied_vc.get_dc(dc)
-        # own entry is *replaced* by now, exactly like partition_vc()
-        # (the two gating paths must agree regardless of queue depth)
-        pvc[cols[self.own_dc]] = self.now_us()
+            _pack_txn_row(txn, cols, ts, ss, i)
+        pvc, stamp = self._dense_clocks(cols, d_pad)
 
         from antidote_tpu.obs import prof
 
         with prof.annotate("gate_fixpoint"):
-            applied, rounds, new_pvc = gate_fixpoint(
-                jnp.asarray(ss), jnp.asarray(origin_col),
-                jnp.asarray(pos_arr), jnp.asarray(ts), jnp.asarray(ping),
-                jnp.asarray(pvc))
-        applied = np.asarray(applied)
-        rounds = np.asarray(rounds)
-        new_pvc = np.asarray(new_pvc)
+            applied, rounds, new_pvc = (np.asarray(a) for a in gate_fixpoint(
+                ss, origin_col, pos_arr, ts, live, pvc, stamp))
         _note_gate_dispatch(
             "fixpoint",
             h2d=(ss.nbytes + origin_col.nbytes + pos_arr.nbytes
-                 + ts.nbytes + ping.nbytes + pvc.nbytes),
+                 + ts.nbytes + live.nbytes + pvc.nbytes + stamp.nbytes),
             d2h=applied.nbytes + rounds.nbytes + new_pvc.nbytes)
-
-        # replay in (round, fifo pos) order: round-r txns depend only on
-        # rounds < r, so this is a causal apply order (see gate_fixpoint)
-        order = sorted(
-            (i for i in range(n) if applied[i]),
-            key=lambda i: (int(rounds[i]), flat[i][1]))
-        advanced = False
-        admitted = 0
-        for i in order:
-            origin, pos, txn = flat[i]
-            q = self.queues[origin]
-            assert q[0] is txn, "device fixpoint applied out of FIFO order"
-            q.popleft()
-            if txn.is_ping():
-                # exclusive ping advance (see _process_host)
-                self._advance(origin, txn.timestamp - 1)
-            else:
-                try:
-                    self._apply(txn)
-                except PartitionRetired:
-                    # mid-handoff (see _process_host): re-queue and
-                    # stop WITHOUT folding the fixpoint clock — the
-                    # fold would cover the unapplied remainder
-                    q.appendleft(txn)
-                    _note_gate_admitted(admitted)
-                    return advanced
-            admitted += 1
-            advanced = True
-        _note_gate_admitted(admitted)
-        # fold the kernel's final clock back AFTER the replay (it
-        # includes the blocked-head ts-1 advances; advancing before the
-        # records hit the materializer would let a concurrent
-        # partition_vc() reader see a stable time covering unapplied
-        # txns).  Applied watermarks are already in via _apply, so only
-        # the ts-1 component is new; the own column carried `now`, not
-        # an applied watermark — skip it.
-        for dc, c in cols.items():
-            if dc != self.own_dc and int(new_pvc[c]) > \
-                    self.applied_vc.get_dc(dc):
-                self._advance(dc, int(new_pvc[c]))
-                advanced = True
+        before = self.pending()
+        advanced, _ = self._replay(
+            [(int(rounds[i]), pos, origin, txn, None)
+             for i, (origin, pos, txn) in enumerate(flat) if applied[i]],
+            cols, new_pvc)
+        _note_gate_admitted(before - self.pending())
         return advanced
 
-    def _advance(self, origin, ts: int) -> None:
-        if ts > self.applied_vc.get_dc(origin):
-            self.applied_vc = self.applied_vc.set_dc(origin, ts)
+    def _dense_clocks(self, cols: Dict[Any, int], d_pad: int):
+        """The fixpoint's two clock inputs under the column map: the
+        partition clock — the own entry *replaced* by now, exactly
+        like partition_vc() (the gating paths must agree regardless of
+        queue depth) — and the origins' newest stamps."""
+        pvc = np.zeros(d_pad, np.int64)
+        stamp = np.zeros(d_pad, np.int64)
+        for dc, c in cols.items():
+            pvc[c] = self.applied_vc.get_dc(dc)
+            stamp[c] = self.stamps.get(dc, 0)
+        pvc[cols[self.own_dc]] = self.now_us()
+        return pvc, stamp
+
+    def _replay(self, wave, cols: Dict[Any, int], new_pvc,
+                popped: Callable[[Any], None] = lambda slot: None
+                ) -> Tuple[bool, bool]:
+        """Apply a device fixpoint's answer: ``wave`` holds (round,
+        fifo pos, origin, txn, slot) of the txns it admitted,
+        ``new_pvc`` its final clock.  Replay in (round, fifo pos)
+        order: round-r txns depend only on rounds < r, so this is a
+        causal apply order (gate_kernels.fixpoint).  The clock is
+        adopted AFTER the replay — raised before the records hit the
+        materializer, a concurrent partition_vc() reader would see a
+        stable time covering unapplied txns — and only when the replay
+        ran to its end: mid-handoff (see _process_host) the txn stays
+        queued while the kernel's watermarks count it applied.  The
+        own column carried `now`, not a watermark — skip it.  Returns
+        (advanced, completed)."""
+        advanced = False
+        for _round, _pos, origin, txn, slot in sorted(
+                wave, key=lambda e: e[:2]):
+            q = self.queues[origin]
+            assert q[0] is txn, "device fixpoint applied out of FIFO order"
+            try:
+                self._apply(txn)
+            except PartitionRetired:
+                return advanced, False
+            q.popleft()
+            popped(slot)
+            advanced = True
+        for dc, c in cols.items():
+            if dc != self.own_dc:
+                advanced |= self._advance(dc, int(new_pvc[c]))
+        return advanced, True
+
+    def _advance(self, origin, ts: int) -> bool:
+        """Raise ``origin``'s watermark to ``ts`` (never lower it).
+        The txns it passes become readable at their commit clocks
+        HERE, not at their apply: the visibility SLO (ISSUE 7) — the
+        carried origin-commit wallclock (wire trace_ctx) turned into
+        the commit->remote-visible latency Cure's whole design is
+        about, per (observing dc, origin peer) — is recorded now."""
+        if ts <= self.applied_vc.get_dc(origin):
+            return False
+        self.applied_vc = self.applied_vc.set_dc(origin, ts)
+        unseen = self._unseen.get(origin)
+        if unseen:
+            now = time.time_ns() // 1000
+            self._unseen[origin] = still = []
+            for entry in unseen:
+                ct, txid, wall = entry
+                if ct > ts:
+                    still.append(entry)
+                    continue
+                vis_lag_s = max(now - wall, 0) / 1e6
+                stats.registry.vis_lag.observe(
+                    vis_lag_s, dc=str(self.own_dc), peer=str(origin))
+                tracer.instant("interdc_visible", "interdc", txid=txid,
+                               origin=str(origin),
+                               vis_lag_s=round(vis_lag_s, 6))
+        return True
 
     def _apply(self, txn: InterDcTxn) -> None:
         # getattr: harness fakes (tests/unit/test_dep_gate.py) enqueue
@@ -562,20 +540,14 @@ class DependencyGate:
         recorder.record("interdc", "depgate_admit", txid=txid,
                         origin=str(txn.dc_id), wait_s=wait_s,
                         timestamp=txn.timestamp)
-        # visibility SLO (ISSUE 7): the txn's records just landed in
-        # the local log + materializer — THIS is ingest-visibility
-        # time.  The carried origin-commit wallclock (wire trace_ctx)
-        # turns it into the commit->remote-visible latency Cure's
-        # whole design is about, per (observing dc, origin peer).
         tctx = getattr(txn, "trace_ctx", None)
         if tctx is not None:
-            vis_lag_s = max(time.time_ns() // 1000 - tctx[0], 0) / 1e6
-            stats.registry.vis_lag.observe(
-                vis_lag_s, dc=str(self.own_dc), peer=str(txn.dc_id))
-            tracer.instant("interdc_visible", "interdc", txid=txid,
-                           origin=str(txn.dc_id),
-                           vis_lag_s=round(vis_lag_s, 6))
-        self._advance(txn.dc_id, txn.timestamp)
+            # in the log and the materializer, not yet under the
+            # watermark: _advance records its visibility (a stream
+            # that never stamps — drop_ping — keeps the newest few)
+            unseen = self._unseen.setdefault(txn.dc_id, [])
+            unseen.append((txn.timestamp, txid, tctx[0]))
+            del unseen[:-4096]
 
     def pending(self) -> int:
         return sum(len(q) for q in self.queues.values())
@@ -583,8 +555,12 @@ class DependencyGate:
     def queue_stats(self) -> dict:
         """This gate's backlog + ring occupancy for the pipeline
         snapshot (obs/pipeline.py): per-origin queue depths, the
-        applied watermark vector, and — when the device ring is live —
-        its slot occupancy."""
+        applied watermark vector with each origin's newest stamp and
+        its age (a watermark that trails an old stamp waits for the
+        origin's heartbeat, one that trails a fresh stamp for a queued
+        txn), and — when the device ring is live — its slot
+        occupancy."""
+        now = self.now_us()
         ring = None
         if self._ring is not None:
             ring = {"live_slots": self._ring.n_live,
@@ -597,6 +573,9 @@ class DependencyGate:
                        if q},
             "applied_vc": {str(k): v
                            for k, v in dict(self.applied_vc).items()},
+            "stamps": {str(o): {"stamp": stamp,
+                                "age_us": now - self.stamp_at.get(o, now)}
+                       for o, stamp in dict(self.stamps).items()},
             # partially-subscribed origins (ISSUE 18): their applied
             # watermark means "within the subscribed ranges" — rendered
             # so a lag investigation doesn't mistake filtering for it
@@ -639,7 +618,7 @@ class _DeviceRing:
         self.cap = 0
         self.d_pad = 8
         self.cols: Dict[Any, int] = {}
-        self.dev = None  # (ss, origin, pos, ts, ping, live) on device
+        self.dev = None  # (ss, origin, pos, ts, live) on device
         self.mirror: Dict[Any, deque] = {}
         self.slot_entry: List[Optional[Tuple[Any, int, Any]]] = []
         self.free: List[int] = []
@@ -690,15 +669,15 @@ class _DeviceRing:
                     fresh.append((origin, txn))
         # 3. column map growth (persistent: existing rows keep their
         #    columns; a new DC is a fresh zero column)
-        self._col_of(gate.own_dc)
+        for dc in (gate.own_dc, *gate.stamps):
+            self._col_of(dc)
         for origin, txn in fresh:
             self._col_of(origin)
-            if not txn.is_ping():
-                for dc in txn.snapshot_vc:
-                    self._col_of(dc)
+            for dc in txn.snapshot_vc:
+                self._col_of(dc)
         need_d = max(8, 1 << (len(self.cols) - 1).bit_length())
         # 4a. empty-ring bulk fast path: with nothing resident, a large
-        #     arrival batch uploads as six dense arrays directly (the
+        #     arrival batch uploads as five dense arrays directly (the
         #     repack path's exact economy — no scatter, no stale state
         #     to reconcile), so a bulk-packed queue pays no ring
         #     penalty; the scatter append below is the incremental
@@ -768,9 +747,7 @@ class _DeviceRing:
         origin = np.zeros(self.cap, np.int32)
         pos = np.full(self.cap, gk.BIG_POS, np.int32)
         ts = np.zeros(self.cap, np.int64)
-        ping = np.zeros(self.cap, dtype=bool)
-        live = np.zeros(self.cap, dtype=bool)
-        live[:k] = True
+        live = np.arange(self.cap) < k
         self.mirror = {}
         self.slot_entry = [None] * self.cap
         self.pos_next = {}
@@ -780,17 +757,18 @@ class _DeviceRing:
             self.pos_next[o] = p + 1
             origin[i] = self.cols[o]
             pos[i] = p
-            ts[i], ping[i] = _pack_txn_row(txn, self.cols, ss[i])
+            _pack_txn_row(txn, self.cols, ts, ss, i)
             self.slot_entry[i] = (o, p, txn)
             self.mirror.setdefault(o, deque()).append((i, txn, p))
         self.n_live = k
         self.free = list(range(self.cap - 1, k - 1, -1))
         self.dev = tuple(jnp.asarray(a)
-                         for a in (ss, origin, pos, ts, ping, live))
+                         for a in (ss, origin, pos, ts, live))
         _note_gate_dispatch(
             "append",
             h2d=(ss.nbytes + origin.nbytes + pos.nbytes + ts.nbytes
-                 + ping.nbytes + live.nbytes))
+                 + live.nbytes))
+
 
     def invalidate(self) -> None:
         """Drop the device state; the next sync rebuilds from the
@@ -824,7 +802,7 @@ class _DeviceRing:
                 i += 1
         assert i == self.n_live
         n_live = np.asarray(i, np.int32)
-        self.dev = gk.ring_gather(*self.dev[:5], idx, n_live,
+        self.dev = gk.ring_gather(*self.dev[:4], idx, n_live,
                                   new_d=d_pad)
         _note_gate_dispatch("gather", h2d=idx.nbytes + n_live.nbytes)
         self.cap = new_cap
@@ -841,9 +819,7 @@ class _DeviceRing:
         k_pad = max(8, 1 << (k - 1).bit_length())
         slots = np.full(k_pad, self.cap, np.int32)  # padding: dropped
         slots[:k] = self.retire_pending
-        ss, origin, pos, ts, ping, live = self.dev
-        self.dev = (ss, origin, pos, ts, ping,
-                    gk.ring_retire(live, slots))
+        self.dev = (*self.dev[:4], gk.ring_retire(self.dev[4], slots))
         _note_gate_dispatch("retire", h2d=slots.nbytes)
         self.free.extend(self.retire_pending)
         self.retire_pending = []
@@ -857,7 +833,6 @@ class _DeviceRing:
         u_origin = np.zeros(k_pad, np.int32)
         u_pos = np.full(k_pad, gk.BIG_POS, np.int32)
         u_ts = np.zeros(k_pad, np.int64)
-        u_ping = np.zeros(k_pad, dtype=bool)
         slots = np.full(k_pad, self.cap, np.int32)  # padding: dropped
         for i, (origin, txn) in enumerate(fresh):
             slot = self.free.pop()
@@ -866,17 +841,17 @@ class _DeviceRing:
             slots[i] = slot
             u_origin[i] = self.cols[origin]
             u_pos[i] = pos
-            u_ts[i], u_ping[i] = _pack_txn_row(txn, self.cols, u_ss[i])
+            _pack_txn_row(txn, self.cols, u_ts, u_ss, i)
             self.slot_entry[slot] = (origin, pos, txn)
             self.mirror.setdefault(origin, deque()).append(
                 (slot, txn, pos))
             self.n_live += 1
         self.dev = gk.ring_append(*self.dev, slots, u_ss, u_origin,
-                                  u_pos, u_ts, u_ping)
+                                  u_pos, u_ts)
         _note_gate_dispatch(
             "append",
             h2d=(slots.nbytes + u_ss.nbytes + u_origin.nbytes
-                 + u_pos.nbytes + u_ts.nbytes + u_ping.nbytes))
+                 + u_pos.nbytes + u_ts.nbytes))
 
     # ----------------------------------------------------------- fixpoint
 
@@ -884,19 +859,14 @@ class _DeviceRing:
         """One device fixpoint over the resident ring.  Mandatory D2H
         is the scalar applied-count; the dense mask + rounds come back
         only when a wave actually admitted something, the final clock
-        always (it carries the blocked-head ts-1 advances)."""
+        always (it carries the watermarks the rule raised)."""
         from antidote_tpu.interdc import gate_kernels as gk
         from antidote_tpu.obs import prof
 
-        gate = self.gate
-        pvc = np.zeros(self.d_pad, np.int64)
-        for dc, c in self.cols.items():
-            pvc[c] = gate.applied_vc.get_dc(dc)
-        # own entry is *replaced* by now, exactly like partition_vc()
-        pvc[self.cols[gate.own_dc]] = gate.now_us()
+        pvc, stamp = self.gate._dense_clocks(self.cols, self.d_pad)
         with prof.annotate("gate_ring_fixpoint"):
             applied_d, rounds_d, pvc_d, live_d, n_d = gk.ring_fixpoint(
-                *self.dev, pvc)
+                *self.dev, pvc, stamp)
         napp = int(np.asarray(n_d))
         d2h = np.dtype(np.int32).itemsize  # the scalar count
         if napp:
@@ -907,7 +877,8 @@ class _DeviceRing:
             applied = rounds = None
         new_pvc = np.asarray(pvc_d)
         d2h += new_pvc.nbytes
-        _note_gate_dispatch("fixpoint", h2d=pvc.nbytes, d2h=d2h)
+        _note_gate_dispatch("fixpoint", h2d=pvc.nbytes + stamp.nbytes,
+                            d2h=d2h)
         self._pending_live = live_d
         return napp, applied, rounds, new_pvc
 
@@ -940,123 +911,37 @@ class _DeviceRing:
         extra dispatches); otherwise keep the old live mask and retire
         the partial wave's slots at the next sync."""
         if completed and self._pending_live is not None:
-            ss, origin, pos, ts, ping, _live = self.dev
-            self.dev = (ss, origin, pos, ts, ping, self._pending_live)
+            self.dev = (*self.dev[:4], self._pending_live)
             self.free.extend(self.last_wave)
         else:
             self.retire_pending.extend(self.last_wave)
         self._pending_live = None
 
 
-def ready_mask(queued_ss, queued_origin, partition_vc):
-    """Batched dependency check on device: which queued txns may apply now.
-
-    ``queued_ss``: int64[N, D] snapshot VCs; ``queued_origin``: int32[N]
-    dense origin columns; ``partition_vc``: int64[D].  Returns bool[N].
-    The origin entry is zeroed before the dominance test exactly as in
-    try_store (reference src/inter_dc_dep_vnode.erl:131-136).
-    """
-    from antidote_tpu.clocks import dense
-
-    deps = dense.set_dc(queued_ss, queued_origin, 0)
-    return dense.ge(partition_vc, deps)
-
-
 _GATE_JIT = None
 
 
-def gate_fixpoint(ss, origin, pos, ts, is_ping, pvc):
-    """Device iterate-until-stable over the whole queued set: returns
-    (applied bool[N], round int32[N], final partition VC int64[D]).
-
-    Each round evaluates, data-parallel over all N queued txns:
-      ready    = ping | (pvc >= deps)           (:func:`ready_mask`)
-      applied  = ready ∧ FIFO-prefix            (a txn applies only if
-                 every earlier txn of its origin queue applies — the
-                 per-origin min position of a not-ready txn bounds it)
-      pvc     |= per-origin max commit ts of applied txns
-    and repeats while pvc still advances — the same monotone cascade the
-    host walk performs head-by-head (reference
-    src/inter_dc_dep_vnode.erl:96-154), as one ``lax.while_loop``.
-    Terminates because applied/pvc are monotone; the round count is
-    bounded by the longest dependency chain through the queues (up to
-    the total queued-txn count for a fully serialized cascade).
-
-    ``round[i]`` is the round at which txn i became applicable.  A
-    round-r txn's dependencies were satisfied by the clock of round r-1,
-    so it cannot depend on any other round-r txn: replaying applies
-    sorted by (round, fifo pos) is causally safe, which is how the host
-    caller restores the reference's apply-in-dependency-order behavior.
-
-    This is the legacy repack path's kernel; the resident-ring form is
-    :func:`antidote_tpu.interdc.gate_kernels.ring_fixpoint` (the same
-    cascade with a ``live`` mask instead of sentinel padding rows).
-    """
+def gate_fixpoint(ss, origin, pos, ts, live, pvc, stamp):
+    """The legacy repack path's device program: one
+    :func:`gate_kernels.fixpoint` over freshly packed rows — ``ss``
+    int64[N, D] snapshot VCs, ``origin`` int32[N] dense origin
+    columns, ``pos`` int32[N] FIFO positions, ``ts`` int64[N] commit
+    times, ``live`` bool[N] (padding rows are dead), ``pvc`` and
+    ``stamp`` int64[D].  Returns (applied bool[N], round int32[N],
+    final partition VC int64[D]).  The resident-ring form is
+    :func:`antidote_tpu.interdc.gate_kernels.ring_fixpoint`, the same
+    body over rows that stay on device."""
     global _GATE_JIT
     if _GATE_JIT is None:
         import jax
-        import jax.numpy as jnp
 
-        from antidote_tpu.clocks import dense
-
-        def _fixpoint(ss, origin, pos, ts, is_ping, pvc):
-            d = pvc.shape[0]
-            n = ss.shape[0]
-            big = jnp.asarray(np.iinfo(np.int32).max, jnp.int32)
-
-            def round_(pvc):
-                ready = is_ping | ready_mask(ss, origin, pvc)   # [N]
-                notready_pos = jnp.where(ready, big, pos)
-                blocked_min = jnp.full((d,), big, jnp.int32).at[origin].min(
-                    notready_pos, mode="drop")
-                applied = ready & (pos < blocked_min[origin])
-                wm = jnp.zeros((d,), ts.dtype).at[origin].max(
-                    jnp.where(applied, ts, 0), mode="drop")
-                # blocked-head rule (reference
-                # src/inter_dc_dep_vnode.erl:137-143): a head that
-                # cannot apply still advances its origin's clock to
-                # ts-1 — FIFO + gap repair mean the origin's stream is
-                # complete below it, and other origins' heads may
-                # depend on a time up to it.  Padding rows contribute
-                # ts-1 = -1, which the max-with-0 init discards.
-                head_blocked = (~ready) & (pos == blocked_min[origin])
-                hb = jnp.zeros((d,), ts.dtype).at[origin].max(
-                    jnp.where(head_blocked, ts - 1, 0), mode="drop")
-                return applied, jnp.maximum(pvc, jnp.maximum(wm, hb))
-
-            def note_round(rounds, applied, r):
-                newly = applied & (rounds < 0)
-                return jnp.where(newly, r, rounds)
-
-            def cond(carry):
-                _, _, _, changed = carry
-                return changed
-
-            def body(carry):
-                rounds, pvc, r, _ = carry
-                applied, new_pvc = round_(pvc)
-                rounds = note_round(rounds, applied, r)
-                return (rounds, new_pvc, r + 1,
-                        jnp.any(new_pvc != pvc))
-
-            rounds0 = jnp.full((n,), -1, jnp.int32)
-            rounds, pvc, r, _ = jax.lax.while_loop(
-                cond, body,
-                (rounds0, pvc, jnp.asarray(0, jnp.int32),
-                 jnp.asarray(True)))
-            # the loop exits after a round that did not advance pvc;
-            # evaluate once more at the stable clock (covers the
-            # no-progress-first-round case)
-            applied, _ = round_(pvc)
-            rounds = note_round(rounds, applied, r)
-            return applied, rounds, pvc
-
+        from antidote_tpu.interdc import gate_kernels as gk
         from antidote_tpu.obs import prof as _prof
 
         # kernel-span wrapped: the gate's padded-shape jit cache is the
         # classic recompilation-storm source (every new (n_pad, d_pad)
         # pair compiles), which the compile-miss counter now attributes
         _GATE_JIT = _prof.profiler.wrap(
-            jax.jit(_fixpoint), name="gate_fixpoint",
+            jax.jit(gk.fixpoint), name="gate_fixpoint",
             subsystem="interdc.dep")
-    return _GATE_JIT(ss, origin, pos, ts, is_ping, pvc)
+    return _GATE_JIT(ss, origin, pos, ts, live, pvc, stamp)

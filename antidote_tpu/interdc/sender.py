@@ -15,10 +15,13 @@ window + byte/txn budget (``interdc_ship_us`` / ``interdc_ship_bytes``
 / ``interdc_ship_txns``) into ONE columnar batch frame
 (wire.InterDcBatch) and publishes it off the commit path, with a
 bounded buffer backpressuring committers so a stalled transport cannot
-let staged txns grow without bound.  Heartbeats piggyback on batch
-frames while the stream has traffic and only pay a standalone ping
-frame when it is quiet.  ``interdc_ship=False`` keeps the legacy
-one-frame-per-txn path as the benches' comparison baseline.
+let staged txns grow without bound.  Every batch frame carries a
+heartbeat stamp drawn as it closes (``_frame_stamp_locked``): a peer
+may read a txn at its commit clock only once a stamp above it has
+arrived (interdc/dep.py), so the stamp rides with the txns and not only
+on the ticker's; a quiet stream still pays the standalone ping frame.
+``interdc_ship=False`` keeps the legacy one-frame-per-txn path as the
+benches' comparison baseline (its txns wait for the ticker's stamp).
 
 Both paths publish through a per-stream ordered outbox: frames enter
 it in watermark order inside the same critical section that advances
@@ -34,7 +37,7 @@ import logging
 import threading
 import time
 from collections import deque
-from typing import List, Optional
+from typing import Callable, List, Optional
 
 from antidote_tpu import stats
 from antidote_tpu.config import Config as _Config
@@ -120,10 +123,15 @@ def est_txn_bytes(txn: InterDcTxn) -> int:
 
 class InterDcLogSender:
     def __init__(self, dc_id, partition: int, transport: Transport,
-                 enabled: bool = True, config=None):
+                 enabled: bool = True, config=None,
+                 min_prepared: Optional[Callable[[], int]] = None):
         self.dc_id = dc_id
         self.partition = partition
         self.transport = transport
+        #: the partition's PartitionManager.min_prepared (lock-free):
+        #: what a heartbeat stamps, drawn here for each closing frame;
+        #: None (direct constructions) = the ticker's stamps only
+        self.min_prepared = min_prepared
         #: publishing gate: off until the DC joins a cluster (reference
         #: start_bg_processes ordering, src/inter_dc_manager.erl:112-145)
         self.enabled = enabled
@@ -299,6 +307,29 @@ class InterDcLogSender:
         self._buf_bytes -= total
         return chunk
 
+    def _frame_stamp_locked(self) -> Optional[int]:
+        """The heartbeat stamp of the frame that closes now (after
+        ``_chunk_locked``): the newer of the ticker's pending stamp
+        and a fresh ``min_prepared()``.  A stamp ``v`` promises that
+        every txn of this stream that commits below ``v`` is on the
+        wire AHEAD of it.  Drawn under ``_lock`` that holds for all
+        that is staged — a txn below ``v`` had left the prepared table
+        before the draw, so its on_append, which comes first, is done —
+        but staged is not yet on the wire: what a full budget left in
+        ``_buf`` ships BEHIND this frame, so the stamp is lowered to
+        the smallest commit time still staged (the receiver's rule,
+        ``DependencyGate._raise_watermarks``, applied at the sender).
+        A lone txn's frame closes ``ship_us`` after its staging, when
+        its committer has left the table: the stamp is then a clock
+        reading above the commit, and the peer need not wait for the
+        ticker."""
+        stamp, self._pending_ping = self._pending_ping, None
+        if self.min_prepared is not None:
+            stamp = max(stamp or 0, self.min_prepared())
+        if stamp is not None and self._buf:
+            stamp = min(stamp, min(t.timestamp for t, _est in self._buf))
+        return stamp
+
     def _ship_loop(self) -> None:
         while True:
             with self._lock:
@@ -319,7 +350,7 @@ class InterDcLogSender:
                         break
                     self._cv.wait(remaining)
                 chunk = self._chunk_locked()
-                ping, self._pending_ping = self._pending_ping, None
+                ping = self._frame_stamp_locked()
                 if self._buf:
                     self._buf_since = time.monotonic()
                 stats.registry.ship_queue_depth.set(
@@ -528,7 +559,7 @@ class InterDcLogSender:
         chunks = []
         while self._buf:
             chunks.append(self._chunk_locked())
-        ping, self._pending_ping = self._pending_ping, None
+        ping = self._frame_stamp_locked()
         for i, chunk in enumerate(chunks):
             batch = InterDcBatch.from_txns(
                 chunk, ping_ts=ping if i == len(chunks) - 1 else None,

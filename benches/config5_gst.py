@@ -87,13 +87,21 @@ def host_round_seconds(N=64, P=8):
     return time.perf_counter() - t0
 
 
+#: every origin's heartbeat stamp in the gate probes: its whole stream
+#: is on the wire
+_STREAM_END = 10**11
+
+
 def _gate_cascade(N, q_len=8):
     """The canonical gate workload, shared by BOTH gate probes so the
     kernel-only and end-to-end rates measure the same cascade: yields
     (origin_idx, pos, ts, deps) rows where deps maps origin_idx ->
     timestamp.  Txn at phase p > 0 carries two cross-origin
     dependencies on strictly earlier phases, so the cascade drains
-    fully by induction on p with ~q_len rounds."""
+    fully by induction on p with ~q_len rounds — given every origin's
+    stamp above its stream (``_STREAM_END``): a gate calls an origin
+    applied up to its stamp, bounded by what it still holds queued,
+    never up to an applied commit time (interdc/dep.py)."""
     rng = np.random.default_rng(7)
     rows = []
     for oi in range(N):
@@ -149,6 +157,7 @@ def gate_throughput(N, q_len=8, batched=True):
             snapshot_vc=VC(snap), timestamp=ts, records=["r"]))
         total += 1
     gate.queues.update(queues)
+    gate.stamps.update((o, _STREAM_END) for o in origins)
 
     t0 = time.perf_counter()
     gate.process_queues()
@@ -208,6 +217,7 @@ def gate_steady_stream(N, q_len=4, mode="ring"):
         gate = DependencyGate(pm, "self", now_us, batch_threshold=1,
                               adapt=False, device_ring=True)
     rows = _gate_cascade(N, q_len)
+    gate.stamps.update((o, _STREAM_END) for o in origins)
     arrival = sorted(range(len(rows)),
                      key=lambda i: (rows[i][1], rows[i][0]))
     reg = _stats.registry
@@ -307,10 +317,12 @@ def gate_device_kernel_rate(jax, N, q_len=8, iters=8):
         for dep_oi, dep_ts in deps.items():
             ss[i, dep_oi] = dep_ts
     ss, origin, pos, ts = map(jnp.asarray, (ss, origin, pos, ts))
-    is_ping = jnp.zeros((n,), bool)
+    live = jnp.ones((n,), bool)
     pvc0 = jnp.zeros((N,), jnp.int64)
+    stamp = jnp.full((N,), _STREAM_END, jnp.int64)
 
-    applied, rounds, _ = gate_fixpoint(ss, origin, pos, ts, is_ping, pvc0)
+    applied, rounds, _ = gate_fixpoint(ss, origin, pos, ts, live, pvc0,
+                                       stamp)
     fetch(applied)
     assert bool(applied.all())
     # min of several overhead probes AND min over repeated runs: one
@@ -325,7 +337,7 @@ def gate_device_kernel_rate(jax, N, q_len=8, iters=8):
             # data-dependent on the previous call, so calls chain
             dep0 = jnp.minimum(rounds[0], 0).astype(pvc0.dtype)
             applied, rounds, _ = gate_fixpoint(
-                ss, origin, pos, ts, is_ping, pvc0 + dep0)
+                ss, origin, pos, ts, live, pvc0 + dep0, stamp)
         fetch(applied)
         dt = max(time.perf_counter() - t0 - oh, 1e-9) / iters
         best = dt if best is None else min(best, dt)

@@ -260,6 +260,60 @@ class TestPingPiggyback:
         assert stats.registry.ship_piggybacked_pings.value() == before + 1
         s.close()
 
+    def test_frame_stamp_does_not_overtake_what_is_still_staged(self):
+        """A stamp says "all that commits below me is on the wire
+        ahead of me".  When a full budget closes a frame with txns
+        still staged, they ship BEHIND it: the frame's stamp — the
+        ticker's pending one or a fresh min-prepared — is lowered to
+        the smallest commit time left staged (commit times are NOT in
+        stream order: feed them descending)."""
+        cap = Capture()
+        s = InterDcLogSender("dc1", 0, cap, min_prepared=lambda: 99_000,
+                             config=cfg(interdc_ship_us=500_000))
+        opid = 0
+        for i in (5, 4, 3, 2, 1):  # commit times 10_005 .. 10_001
+            opid = feed_txn(s, i, opid)
+        s.ping(50_000)
+        assert not cap.frames  # all five staged, the window still open
+        s.ship_txns = 2        # ...and now a budget of two a frame
+        s.flush_ship()
+        frames = cap.decoded()
+        assert [len(f.txns()) for f in frames] == [2, 2, 1]
+        # each frame's stamp covers nothing staged behind it...
+        assert [f.ping_ts for f in frames] == [10_001, 10_001, 99_000]
+        # ...and the last, with nothing behind, is the fresh reading
+        stamps_ok = all(
+            f.ping_ts <= t.timestamp
+            for n, f in enumerate(frames) for later in frames[n + 1:]
+            for t in later.txns())
+        assert stamps_ok
+        s.close()
+
+    def test_lone_txn_on_a_quiet_stream_carries_a_stamp_above_it(self):
+        """At on_append the committer is still in the prepared table
+        (commit() appends, publishes, then pops), so a reading taken
+        at staging lies at or below its own commit time and the peer
+        would wait for the ticker's heartbeat (1 s by default) to read
+        at the commit clock.  The stamp is drawn when the frame
+        CLOSES, ship_us later, when the pop has happened."""
+        cap = Capture()
+        prepared = [10_000]  # the committer's own entry: its commit time
+
+        def min_prepared():
+            return prepared[0] if prepared else 20_000  # else the clock
+
+        s = InterDcLogSender("dc1", 0, cap, min_prepared=min_prepared,
+                             config=cfg(interdc_ship_us=20_000))
+        feed_txn(s, 0, 0)       # commit time 10_000, staged
+        prepared.clear()        # ...and the committer pops
+        deadline = time.monotonic() + 2.0
+        while not cap.frames and time.monotonic() < deadline:
+            time.sleep(0.002)
+        (f,) = cap.decoded()    # no ticker ran: the frame's own stamp
+        assert isinstance(f, InterDcBatch) and len(f.txns()) == 1
+        assert f.ping_ts == 20_000 > f.txns()[0].timestamp
+        s.close()
+
     def test_ping_not_gated_on_enabled(self):
         cap = Capture()
         s = InterDcLogSender("dc1", 0, cap, enabled=False, config=cfg())

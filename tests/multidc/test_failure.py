@@ -155,8 +155,10 @@ def test_chaos_all_types_converge(bus, tmp_path, seed):
     causal clock — dependency gating, gap repair, recovery, and every
     materializer path exercised at once.  (counter_b is excluded: its
     decrements legitimately abort on rights, covered by its own suite.)
-    This harness found the cross-origin dependency-gate deadlock the
-    blocked-head rule now fixes (interdc/dep.py)."""
+    This harness found the cross-origin dependency-gate deadlock that
+    a stamp counted behind a blocked head resolves (interdc/dep.py
+    ``_raise_watermarks``; until PR 36 the reference's blocked-head
+    rule)."""
     import random
 
     from antidote_tpu.clocks import vc_max

@@ -61,6 +61,10 @@ def txn(origin, ts, snapshot, ping=False):
         records=[] if ping else ["r"])
 
 
+#: the stamp that lets origin z's commit at 5000 count as applied
+STAMP_Z = txn("z", 5001, {}, ping=True)
+
+
 def make_gate(pm=None, clock=None, **kw):
     pm = pm or FakePM()
     clock = clock or Clock()
@@ -96,7 +100,8 @@ def test_incremental_append_beats_repack_on_h2d_bytes():
         for i in range(n):
             gate.enqueue(txn(f"dc{i}", 100 + i, {"z": 5000}))
             clock.advance(60_000)  # outlive the backlog-skip window
-        gate.enqueue(txn("z", 5000, {}))
+        # the commit and the stamp that says z is complete at 5000
+        gate.enqueue_batch([txn("z", 5000, {}), STAMP_Z])
         gate.process_queues()
         assert gate.pending() == 0
         assert len(pm.applied) == n + 1
@@ -139,7 +144,7 @@ def test_ring_grows_past_initial_capacity():
         gate.enqueue(txn(f"dc{i}", 100 + i, {"z": 5000}))
         clock.advance(60_000)
     assert gate._ring.cap >= n
-    gate.enqueue(txn("z", 5000, {}))
+    gate.enqueue_batch([txn("z", 5000, {}), STAMP_Z])
     gate.process_queues()
     assert gate.pending() == 0 and len(pm.applied) == n + 1
     assert dispatches("gather") > 0  # at least one growth re-layout
@@ -150,7 +155,7 @@ def test_ring_compacts_after_backlog_drains():
     for i in range(40):
         gate.enqueue(txn(f"dc{i}", 100 + i, {"z": 5000}))
         clock.advance(60_000)
-    gate.enqueue(txn("z", 5000, {}))
+    gate.enqueue_batch([txn("z", 5000, {}), STAMP_Z])
     gate.process_queues()
     grown = gate._ring.cap
     assert grown > 8
@@ -173,8 +178,10 @@ def test_host_walk_interleave_retires_ring_rows():
     gate.queues["b"] = deque([txn("b", 200, {"z": 5000})])
     gate._process_batched()
     assert gate._ring.n_live == 2 and pm.applied == []
-    # z's commit lands and a HOST pass drains everything
+    # z's commit lands, with the stamp that covers it, and a HOST
+    # pass drains everything
     gate.queues["z"] = deque([txn("z", 5000, {})])
+    gate.stamps["z"] = STAMP_Z.timestamp
     gate._process_host()
     assert sorted(pm.applied) == [("a", 100), ("b", 200), ("z", 5000)]
     r0 = dispatches("retire")
@@ -195,10 +202,11 @@ def test_partition_retired_aborts_wave_and_recovers():
     gate.queues["a"] = deque([txn("a", 100, {})])
     gate.queues["b"] = deque([txn("b", 200, {})])
     gate.queues["c"] = deque([txn("c", 300, {})])
+    gate.stamps["b"] = 201
     gate.process_queues()
-    # the poisoned txn stays re-queued; the fixpoint clock did NOT
-    # fold over the unapplied remainder (199 = blocked-head ts-1 at
-    # most, never the commit time itself)
+    # the poisoned txn stays queued; the fixpoint clock, which counted
+    # it applied (b at its stamp, 200), was NOT adopted over the
+    # unapplied remainder
     assert ("b", 200) not in pm.applied
     assert gate.pending() >= 1
     assert gate.applied_vc.get_dc("b") < 200
@@ -206,14 +214,21 @@ def test_partition_retired_aborts_wave_and_recovers():
     gate.process_queues()
     assert sorted(pm.applied) == [("a", 100), ("b", 200), ("c", 300)]
     assert gate.pending() == 0
+    # the stamp less one, now that nothing queued bounds it
     assert gate.applied_vc.get_dc("b") == 200
+    # a and c never stamped: applying their txns promised nothing
+    assert gate.applied_vc.get_dc("a") == gate.applied_vc.get_dc("c") == 0
 
 
 def test_ping_rows_flow_through_ring():
+    """A heartbeat is no ring row any more: the gate keeps its stamp
+    and the fixpoint takes the stamps as a vector, so a stamp of an
+    origin with nothing queued still releases another's head."""
     gate, pm, clock = make_gate(adapt=False)
-    gate.queues["a"] = deque([txn("a", 150, {"b": 500})])
-    gate.queues["b"] = deque([txn("b", 501, {}, ping=True)])
-    gate.process_queues()
+    gate.enqueue(txn("a", 150, {"b": 500}))
+    assert gate._ring.n_live == 1 and pm.applied == []
+    gate.enqueue(txn("b", 501, {}, ping=True))
+    assert gate._ring.n_live == 0 and "b" in gate._ring.cols
     assert pm.applied == [("a", 150)]
     assert gate.applied_vc.get_dc("b") == 500  # exclusive ping advance
     assert gate.pending() == 0
