@@ -22,6 +22,10 @@ halves natively for the TPU serving stack:
   compile-cache-miss counters, device-buffer high-watermarks, and the
   XProf capture API (the old ``antidote_tpu.tracing`` shim was retired
   to a one-release import error, ISSUE 7).
+- :mod:`antidote_tpu.obs.host` — the host process's account: the
+  collector's pauses by generation, the partition locks' holds by
+  acquiring site, and CPU by process and by Python thread kind; read
+  by the registry when scraped and over every profiler capture.
 - :mod:`antidote_tpu.obs.pipeline` — the pipeline snapshot (ISSUE 7):
   every registered DC's ship buffers, SubBuf gap state, gate
   backlogs, ingest staging, and stable watermarks as ONE JSON
@@ -48,8 +52,12 @@ def configure(sample_rate: float | None = None,
     """Apply config knobs to the process-global tracer/recorder/probe/
     profiler (Node.__init__ forwards Config.trace_sample_rate & friends
     here).  ``None`` leaves a setting untouched, so tests and operators
-    can override a single knob without reciting the rest."""
+    can override a single knob without reciting the rest.  The first
+    call also starts timing the collector (``obs.host.install``)."""
+    from antidote_tpu.obs import host as _host
     from antidote_tpu.obs import probe as _probe
+
+    _host.install()
 
     if sample_rate is not None:
         tracer.sample_rate = float(sample_rate)

@@ -36,7 +36,9 @@ of always-on, sampled production profiling:
   the device plane and on its clock.  :func:`stop` keeps the spans of
   the capture; :func:`last_capture` reduces them to one summary
   (per-name totals and self times, per-kind request totals,
-  ``host_busy_s``).
+  ``host_busy_s``) and adds, under ``host``, what the process did
+  between the capture's bounds (``obs.host.difference``: CPU, the
+  partition locks' holds, the collector's pauses).
 
 Cost discipline: with ``profiler.enabled`` False every hook is a single
 attribute check + passthrough (no tree flattening, no jnp ops, zero
@@ -56,6 +58,7 @@ import threading
 import time
 from typing import Any, Dict, Optional
 
+from antidote_tpu.obs import host
 from antidote_tpu.obs.spans import summarize, tracer
 
 # ------------------------------------------------------------------ capture
@@ -67,6 +70,10 @@ _active_dir: Optional[str] = None
 #: summary once last_capture() has been asked for it
 _last_raw: Optional[Dict[str, Any]] = None
 _last_summary: Optional[Dict[str, Any]] = None
+#: obs.host.account() as the open capture began; the last capture's
+#: difference
+_host_from: Optional[Dict[str, Any]] = None
+_last_host: Optional[Dict[str, Any]] = None
 
 
 def annotate(name: str):
@@ -92,7 +99,7 @@ def start(log_dir: str) -> None:
     """Begin a capture (idempotent per process: one capture at a time).
     While the window is open the span tracer records every span and
     annotates the profiler's timeline with the work spans' names."""
-    global _active_dir
+    global _active_dir, _host_from
     import jax
 
     with _capture_lock:
@@ -101,21 +108,23 @@ def start(log_dir: str) -> None:
                 f"profiler already capturing to {_active_dir}")
         jax.profiler.start_trace(log_dir)
         _active_dir = log_dir
+        _host_from = host.account()
         tracer.capture_begin(jax.profiler.TraceAnnotation)
 
 
 def stop() -> str:
     """End the capture; returns the trace directory.  The spans
     recorded since :func:`start` are kept for :func:`last_capture`."""
-    global _active_dir, _last_raw, _last_summary
+    global _active_dir, _last_raw, _last_summary, _last_host
     import jax
 
     with _capture_lock:
         if _active_dir is None:
             raise RuntimeError("no profiler capture active")
-        # the spans first: writing the trace out takes seconds, which
-        # are not the capture's
+        # the spans and the accounts first: writing the trace out takes
+        # seconds, which are not the capture's
         _last_raw, _last_summary = tracer.capture_end(), None
+        _last_host = host.difference(_host_from, host.account())
         jax.profiler.stop_trace()
         out, _active_dir = _active_dir, None
         return out
@@ -127,13 +136,15 @@ def active_dir() -> Optional[str]:
 
 def last_capture() -> Optional[Dict[str, Any]]:
     """The summary of the last finished capture's spans
-    (``spans.summarize``), or None when this process has finished
-    none.  Reduced on first demand, not inside :func:`stop`, which
-    runs while the capture's traffic is still being served."""
+    (``spans.summarize``) with the host's account of it under
+    ``host``, or None when this process has finished none.  Reduced on
+    first demand, not inside :func:`stop`, which runs while the
+    capture's traffic is still being served."""
     global _last_summary
     with _capture_lock:
         if _last_summary is None and _last_raw is not None:
             _last_summary = summarize(**_last_raw)
+            _last_summary["host"] = _last_host
         return _last_summary
 
 

@@ -36,7 +36,10 @@ TraceAnnotation`` of its name: a child's entry closes its parent's
 annotation and its exit opens it again, so the ``.xplane.pb`` holds
 one flat run of self-time segments per thread, beside the device
 plane and on its clock.  Wait spans and roots are never annotated: a
-thread asleep under a device gap did not cause it.
+thread asleep under a device gap did not cause it.  The one nested
+annotation is a span a callback times (:meth:`Tracer.open_timed`: the
+collector's pause, obs/host.py), which opens inside whatever its
+thread was running.
 :func:`summarize` reduces a capture's spans to per-name totals, self
 times and ``host_busy_s``.
 
@@ -248,6 +251,11 @@ class Tracer:
         self._capacity = capacity
         self._spans: deque = deque(maxlen=capacity)
         self._lock = threading.Lock()
+        #: spans a callback recorded (close_timed), queued without the
+        #: lock: the collector's callback runs inside whatever
+        #: allocated, this tracer's own critical sections among them.
+        #: Moved into the ring by the next section that takes the lock
+        self._timed: deque = deque()
         #: spans ever added: a capture's drops are what it added beyond
         #: the ring's capacity
         self._added = 0
@@ -461,8 +469,46 @@ class Tracer:
             int(dur_us), tid if tid is not None else
             threading.get_ident(), args, kind, req))
 
+    def open_timed(self, name: str):
+        """The start of a work span that a callback times on the
+        calling thread (the collector's pause, obs/host.py), when the
+        thread's call chain records or a capture is open; else None.
+        It holds a stamp (parent: the thread's innermost span) and,
+        inside a capture, an annotation of ``name`` nested in the
+        thread's own: the thread's stack of open spans is not touched,
+        so a callback that fires inside the tracer's own bookkeeping
+        leaves it whole.  The span takes its parent's txid, as an
+        opened span does."""
+        stamp = self.stamp()
+        if stamp is None:
+            return None
+        top = self.current()
+        ann, factory = None, self._annotation
+        if factory is not None:
+            ann = factory(name)
+            ann.__enter__()
+        return stamp, top.txid if top is not None else None, ann
+
+    def close_timed(self, opened, name: str, cat: str, **args) -> None:
+        """Record the span :meth:`open_timed` began, ending now.  Takes
+        no lock (``_timed``)."""
+        stamp, txid, ann = opened
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        start_us, parent_id, req, tid = stamp
+        self._timed.append(Span(
+            next(_SPAN_IDS), parent_id, name, cat, txid, start_us,
+            time.time_ns() // 1000 - start_us, tid, args, "work", req))
+
+    def _take_timed_locked(self) -> None:
+        timed = self._timed
+        while timed:
+            self._spans.append(timed.popleft())
+            self._added += 1
+
     def _add(self, span: Span) -> None:
         with self._lock:
+            self._take_timed_locked()
             self._spans.append(span)
             self._added += 1
 
@@ -489,6 +535,7 @@ class Tracer:
         self._annotation = None
         t0_us, added0 = self._capture_from
         with self._lock:
+            self._take_timed_locked()
             added = self._added - added0
             kept = min(added, len(self._spans))
             spans = list(itertools.islice(
@@ -503,6 +550,7 @@ class Tracer:
         """Finished spans, oldest first, filtered by any of
         txid/name/cat (the in-process query surface tests assert on)."""
         with self._lock:
+            self._take_timed_locked()
             out = list(self._spans)
         if txid is not None:
             out = [s for s in out if s.txid == txid]
@@ -535,10 +583,12 @@ class Tracer:
 
     def clear(self) -> None:
         with self._lock:
+            self._timed.clear()
             self._spans.clear()
 
     def __len__(self) -> int:
         with self._lock:
+            self._take_timed_locked()
             return len(self._spans)
 
     # --------------------------------------------------------------- export

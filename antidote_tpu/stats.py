@@ -52,11 +52,14 @@ class _LabeledMetric:
     def _key(self, labels: Dict) -> Tuple:
         return tuple(labels.get(n, "") for n in self.label_names)
 
+    def _samples(self) -> list:
+        with self._lock:
+            return list(self._values.items())
+
     def expose(self) -> Iterable[str]:
         yield f"# HELP {self.name} {self.help}"
         yield f"# TYPE {self.name} {self.kind}"
-        with self._lock:
-            items = list(self._values.items())
+        items = self._samples()
         if not items and self._zero_when_empty:
             items = [((), 0.0)]
         for key, v in items:
@@ -75,6 +78,54 @@ class Counter(_LabeledMetric):
     def value(self, **labels) -> float:
         with self._lock:
             return self._values.get(self._key(labels), 0.0)
+
+
+class ReadCounter(_LabeledMetric):
+    """A counter family read when scraped: ``read()`` gives ``{label
+    values: value}`` from an account the program keeps where it is
+    written (a partition lock's table, the collector's callback, the
+    threads' CPU clocks), so the path that writes it never calls the
+    registry."""
+
+    kind = "counter"
+    _zero_when_empty = True
+
+    def __init__(self, name: str, help_: str, labels: Tuple[str, ...],
+                 read):
+        super().__init__(name, help_, labels)
+        self._read = read
+
+    def _samples(self) -> list:
+        return list(self._read().items())
+
+    def value(self, **labels) -> float:
+        return self._read().get(self._key(labels), 0.0)
+
+
+def _host():
+    # lazy: stats stays importable alone (obs pulls nothing heavy)
+    from antidote_tpu.obs import host
+
+    return host
+
+
+def _lock_sites(field: str, scale: float):
+    return lambda: {(site, ): d[field] * scale
+                    for site, d in _host().lock_sites().items()}
+
+
+def _gc_pauses(index: int):
+    return lambda: {(str(g), ): v[index]
+                    for g, v in _host().gc_pauses().items()}
+
+
+#: the process's CPU (every thread, the interpreter's and the native
+#: runtime's), the standard ``process_cpu_seconds_total``: one number
+#: for the registry and for process_metrics()
+PROCESS_CPU = ReadCounter(
+    "process_cpu_seconds_total",
+    "Total user and system CPU time spent in seconds",
+    (), lambda: {(): time.process_time()})
 
 
 class LabeledGauge(_LabeledMetric):
@@ -939,6 +990,46 @@ class Registry:
             "across all shards (100 * resident / (resident + "
             "host_only)) — the per-shard routing economy's headline")
 
+        # ---- the host process's account (antidote_tpu/obs/host.py):
+        # a server bound by one interpreter is measured by the CPU a
+        # transaction costs and by what holds its threads still.  Read
+        # when scraped from the accounts the program keeps where they
+        # are written; the partition-lock families sum over partitions
+        self.process_cpu = PROCESS_CPU
+        self.thread_cpu = ReadCounter(
+            "antidote_thread_cpu_seconds_total",
+            "CPU seconds of the process's Python threads, each on its "
+            "own clock, by kind (a pool's threads under one name: "
+            "'handlers' are the wire server's connections); what "
+            "process_cpu_seconds_total holds beyond their sum ran in "
+            "native threads",
+            ("kind",), lambda: {(k, ): v for k, v in
+                                _host().thread_cpu().items()})
+        self.pm_lock_held = ReadCounter(
+            "antidote_pm_lock_held_seconds_total",
+            "Seconds partition locks were held, by acquiring function "
+            "(a hold ends at a wait on the condition and a new one "
+            "begins when the wait returns)",
+            ("site",), _lock_sites("held_ns", 1e-9))
+        self.pm_lock_holds = ReadCounter(
+            "antidote_pm_lock_holds_total",
+            "Holds of partition locks, by acquiring function",
+            ("site",), _lock_sites("holds", 1))
+        self.pm_lock_waited = ReadCounter(
+            "antidote_pm_lock_waited_seconds_total",
+            "Seconds spent waiting for a partition lock another thread "
+            "held, by acquiring function",
+            ("site",), _lock_sites("waited_ns", 1e-9))
+        self.gc_pause = ReadCounter(
+            "antidote_gc_pause_seconds_total",
+            "Seconds the cyclic collector stopped the process, by "
+            "generation (every thread waits for a pass)",
+            ("generation",), _gc_pauses(0))
+        self.gc_collections = ReadCounter(
+            "antidote_gc_collections_total",
+            "Passes of the cyclic collector, by generation",
+            ("generation",), _gc_pauses(1))
+
     def metrics(self):
         return (self.error_count, self.staleness, self.open_transactions,
                 self.aborted_transactions, self.operations,
@@ -1015,7 +1106,9 @@ class Registry:
                 self.shard_serve_drains,
                 self.shard_read_dispatches_per_drain,
                 self.shard_collective_seconds,
-                self.shard_device_resident_pct)
+                self.shard_device_resident_pct,
+                self.thread_cpu, self.pm_lock_held, self.pm_lock_holds,
+                self.pm_lock_waited, self.gc_pause, self.gc_collections)
 
     def exposition(self) -> str:
         lines = []
@@ -1026,23 +1119,22 @@ class Registry:
 
 
 def process_metrics() -> list:
-    """Process-level gauges from /proc — the
-    prometheus_process_collector role (reference rebar.config dep;
-    standard process_* metric names).  Empty off Linux."""
+    """Process-level metrics — the prometheus_process_collector role
+    (reference rebar.config dep; standard process_* metric names):
+    the CPU from the registry's own counter (:data:`PROCESS_CPU`), the
+    rest from /proc, which is empty off Linux."""
+    cpu = list(PROCESS_CPU.expose())
     out = []
     try:
         with open("/proc/self/stat") as f:
             parts = f.read().split()
         tick = os.sysconf("SC_CLK_TCK")
         page = os.sysconf("SC_PAGE_SIZE")
-        utime, stime = int(parts[13]), int(parts[14])
         vsize, rss_pages = int(parts[22]), int(parts[23])
         start_ticks = int(parts[21])
         with open("/proc/uptime") as f:
             uptime = float(f.read().split()[0])
         out += [
-            "# TYPE process_cpu_seconds_total counter",
-            f"process_cpu_seconds_total {(utime + stime) / tick:.3f}",
             "# TYPE process_virtual_memory_bytes gauge",
             f"process_virtual_memory_bytes {vsize}",
             "# TYPE process_resident_memory_bytes gauge",
@@ -1064,8 +1156,8 @@ def process_metrics() -> list:
                     ]
                     break
     except (OSError, ValueError, IndexError):
-        return []
-    return out
+        return cpu
+    return cpu + out
 
 
 #: process-wide registry (the reference's metrics are BEAM-node-global)

@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import logging
 import queue
+import sys
 import threading
 import time
 from dataclasses import replace as dc_replace
@@ -36,6 +37,7 @@ from antidote_tpu.mat.materializer import (
     materialize_from_log,
 )
 from antidote_tpu.obs.events import recorder
+from antidote_tpu.obs.host import LockSite, track_lock_table
 from antidote_tpu.obs.spans import tracer
 from antidote_tpu.oplog.partition import PartitionLog
 from antidote_tpu.oplog.records import commit_certified
@@ -120,6 +122,106 @@ class DeviceFlusher:
             # under it and the interpreter unwind this daemon thread
             # mid-XLA-call (an abort at exit, not an exception)
             t.join()
+
+
+#: frames that take a partition lock on a caller's behalf
+_ACQUIRE_THROUGH = ("__enter__", "acquire")
+
+
+class _SiteCondition:
+    """``pm._lock``: a re-entrant ``threading.Condition`` that keeps,
+    for every function that takes it, its holds, its contended waits
+    and its sleeps on the condition (``obs.host.LockSite``).  The site
+    is the acquiring function's qualified name, found past the frames
+    that acquire on a caller's behalf (``_TimedLock.__enter__``, this
+    class's own); a re-entrant acquire belongs to the hold it is
+    inside; ``wait`` closes the hold, counts its sleep and opens a new
+    hold when it returns.  The table is written only by the holder, so
+    it needs no lock of its own, and nothing here calls the registry:
+    it reads the tables when scraped (obs/host.py).  A hold costs three
+    clock reads and a walk of one or two frames."""
+
+    __slots__ = ("_cond", "_take", "_give", "_owner", "_depth", "_site",
+                 "_t_held", "sites")
+
+    def __init__(self):
+        self._cond = threading.Condition()
+        self._take, self._give = self._cond.acquire, self._cond.release
+        #: the holder's thread, its re-entry depth, its site and when
+        #: its hold began; written by the holder only
+        self._owner = None
+        self._depth = 0
+        self._site: Optional[LockSite] = None
+        self._t_held = 0
+        self.sites: Dict[str, LockSite] = {}
+        track_lock_table(self.sites)
+
+    def acquire(self, blocking: bool = True, timeout: float = -1) -> bool:
+        me = threading.get_ident()
+        if self._owner == me:
+            self._take()
+            self._depth += 1
+            return True
+        f = sys._getframe(1)
+        while f.f_code.co_name in _ACQUIRE_THROUGH and f.f_back is not None:
+            f = f.f_back
+        name = f.f_code.co_qualname
+        t0 = time.perf_counter_ns()
+        waited = not self._take(False)
+        if waited and not (blocking and self._take(True, timeout)):
+            return False
+        now = time.perf_counter_ns()
+        site = self.sites.get(name)
+        if site is None:
+            site = self.sites[name] = LockSite()
+        if waited:
+            site.waits += 1
+            site.waited_ns += now - t0
+        self._owner, self._depth, self._site, self._t_held = \
+            me, 1, site, now
+        return True
+
+    __enter__ = acquire
+
+    def release(self) -> None:
+        if self._owner != threading.get_ident():
+            raise RuntimeError("cannot release un-acquired lock")
+        self._depth -= 1
+        if not self._depth:
+            site = self._site
+            site.holds += 1
+            site.held_ns += time.perf_counter_ns() - self._t_held
+            self._owner = None
+        self._give()
+
+    def __exit__(self, *exc):
+        self.release()
+        return False
+
+    def wait(self, timeout: Optional[float] = None) -> bool:
+        if self._owner != threading.get_ident():
+            raise RuntimeError("cannot wait on un-acquired lock")
+        depth, site = self._depth, self._site
+        t0 = time.perf_counter_ns()
+        site.holds += 1
+        site.held_ns += t0 - self._t_held
+        self._owner = None
+        try:
+            # lock-ok: this class IS the held condition; the wait gives
+            # back the very lock its caller holds, as Condition.wait does
+            return self._cond.wait(timeout)
+        finally:
+            now = time.perf_counter_ns()
+            self._owner, self._depth, self._site, self._t_held = \
+                threading.get_ident(), depth, site, now
+            site.sleeps += 1
+            site.slept_ns += now - t0
+
+    def notify(self, n: int = 1) -> None:
+        self._cond.notify(n)
+
+    def notify_all(self) -> None:
+        self._cond.notify_all()
 
 
 class _TimedLock:
@@ -233,7 +335,7 @@ class PartitionManager:
         #: horizon is merely conservative for GC)
         self._stable_cache = VC()
         self._stable_cached_at = 0.0
-        self._lock = threading.Condition()
+        self._lock = _SiteCondition()
         self._locked = _TimedLock(self._lock, partition)
         #: set (under self._lock) by the handoff cutover at the moment
         #: the final log tail is snapshot: appends require self._lock,

@@ -216,8 +216,10 @@ _WAIT_NAMES = {"wait", "wait_for"}
 #: self._lock, ...)`` (txn/manager.py: the same lock, its contended
 #: wait recorded as a ``pm_lock_wait`` span).  Classified like a
 #: Condition around the lock, so ``with pm._locked:`` holds
-#: ``pm._lock`` for every rule below
-_LOCK_WRAPPERS = {"_TimedLock"}
+#: ``pm._lock`` for every rule below.  ``_SiteCondition()`` (the same
+#: file's ``pm._lock`` itself: a Condition that keeps its holds by
+#: acquiring site) is classified as the Condition it is
+_LOCK_WRAPPERS = {"_TimedLock", "_SiteCondition"}
 
 #: collective-program builders: a name assigned from a call reaching
 #: one of these is a multi-chip launcher and must only be CALLED under
