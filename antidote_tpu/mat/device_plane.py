@@ -197,6 +197,42 @@ def _start_warm(run, name: str) -> None:
     threading.Thread(target=run, name=name).start()
 
 
+class _Flight:
+    """One routine flush taken out of the partition lock
+    (``PartitionManager.flush_scheduled``): the rows it carries and
+    their keys, which every reader treats as pending until it settles,
+    and how far it got.  ``donating``: the plane's state is the donated
+    argument of a dispatch in progress — or, on the overflow path, of
+    the retry about to run — so nothing may capture it.  ``outs`` and
+    ``dispatched`` are written under the plane's ``_flight_lock``,
+    ``overflow`` by whoever fetches the mask first, ``settled`` under
+    the partition lock."""
+
+    __slots__ = ("rows", "keys", "capacity", "d", "stable", "ring_bound",
+                 "donating", "outs", "dispatched", "overflow", "error",
+                 "settled", "t0")
+
+    def __init__(self, rows, keys, capacity: int, d: int,
+                 stable: Optional[VC], ring_bound: VC):
+        self.rows, self.keys = rows, keys
+        #: the widths the rows were decoded at: a grow lands the
+        #: flight first (_join_flight), so they hold until it settles
+        self.capacity, self.d = capacity, d
+        #: the overflow retry's fold horizon and bound, as they were
+        #: when the rows left: every op at or below them was staged by
+        #: then, so it is in the ring or aboard.  Rows staged during
+        #: the flight may lie below what the plane has learned since,
+        #: and a fold there would cover them before they land
+        self.stable, self.ring_bound = stable, ring_bound
+        self.donating = True
+        self.outs: list = []  # (device overflow mask, rows in chunk)
+        self.dispatched = False
+        self.overflow: Optional[np.ndarray] = None
+        self.error: Optional[BaseException] = None
+        self.settled = False
+        self.t0 = time.perf_counter()
+
+
 class _PlaneBase:
     """Shared machinery: key directory, pending rows, flush/gc plumbing."""
 
@@ -276,6 +312,19 @@ class _PlaneBase:
         #: chip this plane's state is committed to under ring placement
         #: (set by DevicePlane.place_on; None = the default device)
         self._device = None
+        #: the routine flush out of the partition lock, if one is out
+        #: (begin_flight .. settle_flight); at most one a plane
+        self._flight: Optional[_Flight] = None
+        #: held across a flight's dispatch, by the flusher or by the
+        #: thread that lands the flight first (_join_flight)
+        self._flight_lock = threading.Lock()
+        #: a map's sub-plane (MapPlane._sub): read through the map's
+        #: pending set, so its flushes stay in one hold (split_flush)
+        self._in_map = False
+        #: called under the partition lock when a flight settles: the
+        #: owning PartitionManager's notify_all, which wakes the
+        #: threads waiting for the flight (DevicePlane.set_settle_notify)
+        self.on_settled: Callable[[], None] = lambda: None
 
     # -- subclass hooks -----------------------------------------------------
 
@@ -465,10 +514,18 @@ class _PlaneBase:
         n = len(rows)
         if n == 0:
             return np.zeros(0, dtype=bool)
+        overflow = self._dispatch_rows(rows, self.capacity, self.domain.d)
+        return self._fetch_overflow([(overflow, n)])
+
+    def _dispatch_rows(self, rows: List[tuple], capacity: int, d: int):
+        """The dispatch half of :meth:`_append_rows`: pack ``rows``
+        (decoded at ``capacity`` keys and ``d`` clock columns) and
+        enqueue the append on ``self.st``, which it donates; returns
+        the overflow mask as a device array."""
+        n = len(rows)
         perm = self._packed_perm()
         if self._ingest.enabled and perm is not None:
-            packed = ingest.pack_rows(rows, self.capacity,
-                                      self.domain.d, self._row_cols,
+            packed = ingest.pack_rows(rows, capacity, d, self._row_cols,
                                       perm)
             with self._collective_cm(), \
                     tracer.span("device_dispatch", "device",
@@ -479,16 +536,22 @@ class _PlaneBase:
                 replicas=(self._mesh.shape["part"]
                           if self._mesh is not None else 1))
         else:
-            ki, lo, arrays = _pack_rows(rows, self.capacity,
-                                        self.domain.d, self._row_cols)
+            ki, lo, arrays = _pack_rows(rows, capacity, d, self._row_cols)
             with self._collective_cm(), \
                     tracer.span("device_dispatch", "device",
                                 plane=self.type_name, rows=n):
                 self.st, overflow = type(self)._append_fn(
                     self.st, ki, lo, *arrays)
+        return overflow
+
+    def _fetch_overflow(self, outs: list) -> np.ndarray:
+        """The fetch half: the masks of ``outs`` = [(device mask, rows
+        in its chunk)] on the host, one bool a row."""
         with tracer.wait_span("device_fetch", "device",
                               plane=self.type_name):
-            return np.asarray(overflow)[:n]
+            return np.concatenate(
+                [np.asarray(o)[:n] for o, n in outs]
+                or [np.zeros(0, dtype=bool)])
 
     def _purge_idx(self, idx: int) -> None:
         raise NotImplementedError
@@ -596,7 +659,12 @@ class _PlaneBase:
         the same: each is built fresh per capture and nothing writes
         it afterwards.  This is the read-concurrency analogue of the
         reference's shared-ETS readers next to the vnode process
-        (reference src/clocksi_readitem_server.erl:95-110)."""
+        (reference src/clocksi_readitem_server.erl:95-110).  A flight
+        out that holds the state or carries one of ``keys`` lands
+        first; ``PartitionManager.read_many_begin`` refuses before it
+        gets here, so a read's capture never waits."""
+        if self.flight_blocks(keys):
+            self._join_flight()
         if self.pending_keys and not self.pending_keys.isdisjoint(keys):
             self.flush("read")
         owned = [k for k in keys if k in self.key_index]
@@ -729,6 +797,7 @@ class _PlaneBase:
         path (on_evict replays the log into the host store; with no log
         to replay, the pre-purge device fold travels along — the state
         the host store is seeded from)."""
+        self._join_flight()  # the purge donates the state
         idx = self.key_index.get(key)
         if idx is None:
             return
@@ -794,18 +863,174 @@ class _PlaneBase:
         if due_gc:
             self.gc(self._last_stable or stable_vc)
 
-    def flush_gc_now(self) -> None:
-        """Flusher-thread entry: run any due flush/GC (caller holds the
-        partition lock and has quiesced device readers)."""
+    def flush_due(self) -> Optional[str]:
+        """The kind of flush the flusher owes this plane now — ``rows``
+        past the threshold, ``window`` past the coalescing window — or
+        None."""
         n_rows = len(self.rows)
         if n_rows >= self.flush_ops:
-            self.flush("rows")
-        elif self._window_due(n_rows):
-            self.flush("window")
-        if self._last_stable is not None \
-                and self._ops_since_gc >= self.gc_ops:
+            return "rows"
+        if self._window_due(n_rows):
+            return "window"
+        return None
+
+    def flush_gc_now(self) -> None:
+        """Flusher-thread entry: run any due flush/GC in one hold
+        (caller holds the partition lock and has quiesced device
+        readers)."""
+        kind = self.flush_due()
+        if kind is not None:
+            self.flush(kind)
+        self.gc_grow_now()
+
+    def gc_grow_due(self) -> bool:
+        """Whether :meth:`gc_grow_now` has anything to do."""
+        return self._gc_due() or self._grow_due()
+
+    def gc_grow_now(self) -> None:
+        """The rest of :meth:`flush_gc_now`: a due GC fold and the
+        speculative grow."""
+        if self._gc_due():
             self.gc(self._last_stable)
         self._maybe_speculative_grow()
+
+    def _gc_due(self) -> bool:
+        return (self._last_stable is not None
+                and self._ops_since_gc >= self.gc_ops)
+
+    def _grow_due(self) -> bool:
+        return len(self.rev_keys) * 8 >= self.capacity * 7
+
+    # -- the routine flush out of the partition lock ------------------------
+
+    @property
+    def split_flush(self) -> bool:
+        """Whether the flusher's routine flush may leave the partition
+        lock across its dispatch and its fetch: a single-chip plane
+        whose state is one donated pytree appended by
+        :meth:`_append_rows`.  Mesh-sharded planes (one multi-chip
+        program under the collective lock), a map's sub-planes (whose
+        readers check the map's pending set, not theirs) and RGA's
+        per-document states flush in one hold."""
+        return (self._mesh is None and not self._in_map
+                and type(self)._append_rows is _PlaneBase._append_rows)
+
+    def flight_blocks(self, keys) -> bool:
+        """True while the flight out holds the state (``donating``) or
+        carries one of ``keys``: a capture must not be made (under the
+        partition lock)."""
+        f = self._flight
+        return f is not None and (f.donating or not f.keys.isdisjoint(keys))
+
+    def begin_flight(self, kind: str) -> _Flight:
+        """Under the partition lock, device readers quiesced: take the
+        staged rows and their keys out as a flight whose state is
+        marked donated.  Keys staged from here on are pending again
+        through ``pending_keys``."""
+        self._join_flight()
+        ingest.note_flush(kind)
+        f = _Flight(self.rows, self.pending_keys, self.capacity,
+                    self.domain.d, self._last_stable, self._ring_vc_bound)
+        self.rows, self.pending_keys = [], set()
+        self._flight = f
+        return f
+
+    def dispatch_flight(self, f: _Flight) -> None:
+        """The flight's dispatch, outside every partition lock: its
+        rows enqueued (chunked as :meth:`flush` chunks), which ends the
+        donation — captures of keys it does not carry may go on.  A
+        thread that had to touch the plane may have done it first
+        (_join_flight)."""
+        with self._flight_lock:
+            if not f.dispatched:
+                self._dispatch_flight(f)
+
+    def fetch_flight(self, f: _Flight) -> None:
+        """The flight's fetch of the overflow mask, outside every
+        partition lock (nothing to do once another thread settled
+        it)."""
+        if not f.settled and f.error is None:
+            f.overflow = self._fetch_overflow(f.outs)
+
+    def _dispatch_flight(self, f: _Flight) -> None:
+        """Enqueue the flight's appends; under ``_flight_lock``."""
+        try:
+            step = max(self.flush_ops, _MIN_BUCKET)
+            for i in range(0, len(f.rows), step):
+                chunk = f.rows[i:i + step]
+                f.outs.append((self._dispatch_rows(chunk, f.capacity, f.d),
+                               len(chunk)))
+        except BaseException as e:
+            f.error = e
+            raise
+        finally:
+            f.donating, f.dispatched = False, True
+
+    def _join_flight(self) -> None:
+        """Land the flight out before this plane's state is touched:
+        every path that mutates, copies or captures what the flight
+        donates or carries comes here first, under the partition lock,
+        and the hold stays one hold — a publish that grows, evicts or
+        flushes this plane must not give the lock away (a commit record
+        never sits in the log across a release before its effects are
+        published).  So it takes ``_flight_lock`` after the partition
+        lock: held by the flusher only across its dispatch, inside
+        which it takes no other lock but leaves (counters, the tracer),
+        it comes free.  A flight not yet dispatched is dispatched here;
+        then the mask is fetched, if the flusher has not, and the
+        flight settles."""
+        f = self._flight
+        if f is None:
+            return
+        stats.registry.device_flush_inflight_waits.inc()
+        try:
+            if not self._flight_lock.acquire(False):
+                with tracer.wait_span("device_quiesce_wait", "device",
+                                      plane=self.type_name, flush=1):
+                    self._flight_lock.acquire()
+            try:
+                if not f.dispatched:
+                    self._dispatch_flight(f)
+            finally:
+                self._flight_lock.release()
+        finally:
+            if f.overflow is None and f.error is None:
+                f.overflow = self._fetch_overflow(f.outs)
+            self.settle_flight(f, f.overflow)
+
+    def settle_flight(self, f: _Flight,
+                      overflow: Optional[np.ndarray]) -> None:
+        """Under the partition lock, once: take the flight off the
+        plane and account for it.  With no row overflowed that is all
+        (keys staged again meanwhile stay pending through
+        ``pending_keys``); otherwise today's retry path runs here, in
+        this hold — the caller has quiesced device readers and marked
+        the state donated.  A flight whose dispatch or fetch failed
+        lost its rows, as a failed flush in one hold does."""
+        if f.settled:
+            return
+        f.settled = True
+        self._flight = None
+        try:
+            if f.error is not None or overflow is None:
+                return
+            self._ops_since_gc += len(f.rows)
+            if overflow.any():
+                stats.registry.device_flush_split.inc(outcome="overflow")
+                with tracer.span(f"device_flush:{self.type_name}",
+                                 "device", rows=len(f.rows)):
+                    self._settle_overflow(f.rows, overflow, f.stable,
+                                          f.ring_bound)
+            else:
+                stats.registry.device_flush_split.inc(outcome="clean")
+            self._reshard()
+            stats.registry.device_flush_latency.observe(
+                time.perf_counter() - f.t0)
+            recorder.record("device", "flush", plane=self.type_name,
+                            rows=len(f.rows),
+                            overflow=int(overflow.sum()))
+        finally:
+            self.on_settled()
 
     def _maybe_speculative_grow(self) -> None:
         """Double the key directory BEFORE stage() must do it inline:
@@ -815,7 +1040,7 @@ class _PlaneBase:
         doubling).  Here it runs on the background flusher, under the
         partition lock with readers quiesced, and the new programs
         warm before the serving threads first use them."""
-        if len(self.rev_keys) * 8 >= self.capacity * 7:
+        if self._grow_due():
             self.flush("grow")
             self.capacity *= 2
             self._grow_keys(self.capacity)
@@ -826,10 +1051,14 @@ class _PlaneBase:
         Rows whose key ring is full force a GC at the newest stable
         snapshot and one retry; still-overflowing keys evict to the
         host path.  ``kind`` labels the flush trigger for the INGEST_*
-        counters (mat/ingest.py INGEST_FLUSH_KINDS)."""
+        counters (mat/ingest.py INGEST_FLUSH_KINDS).  One hold: the
+        flusher's routine flush of a :attr:`split_flush` plane takes
+        the other road (begin_flight .. settle_flight)."""
+        self._join_flight()
         if not self.rows:
             return
         ingest.note_flush(kind)
+        stats.registry.device_flush_split.inc(outcome="whole")
         rows, self.rows = self.rows, []
         self.pending_keys.clear()
         # chunk at the configured batch size: a backlog above flush_ops
@@ -849,63 +1078,68 @@ class _PlaneBase:
                     rows[i:i + step])
             self._ops_since_gc += len(rows)
             if overflow.any():
-                retry = [r for r, o in zip(rows, overflow) if o]
-                gst = None
-                if self._last_stable is not None:
-                    pairs = self._ss_pairs(self._last_stable)
-                    if pairs is not None:
-                        gst = self._dense_vc(pairs)
-                        self._run_device_gc(gst)
-                        self._base_vc = self._base_vc.join(
-                            self._last_stable)
-                        self._has_base = True
-                        self._ops_since_gc = 0
-                overflow2 = self._append_rows(retry)
-                if gst is not None:
-                    # invariant: every ring op with commit VC <=
-                    # base_vc must be folded INTO the base — the
-                    # retried rows landed after the fold above, so fold
-                    # once more at the same horizon (rows above it are
-                    # untouched)
-                    self._run_device_gc(gst)
-                if overflow2.any() and self.no_log_replay:
-                    # EMERGENCY fold (unlogged mode): dropping an
-                    # overflowed row here is permanent data loss — no
-                    # log exists to replay it from — so fold the WHOLE
-                    # ring into the base to free lanes and retry once
-                    # more.  Sound: every ring op is published, so the
-                    # host-side join of staged commit VCs bounds them;
-                    # reads below the raised base take the log-replay
-                    # path, which unlogged mode already degrades.
-                    inf = np.full(self.domain.d, _VC_INF,
-                                  dtype=np.int64)
-                    self._run_device_gc(inf)
-                    self._base_vc = self._base_vc.join(
-                        self._ring_vc_bound)
-                    self._has_base = True
-                    self._ops_since_gc = 0
-                    retry2 = [r for r, o in zip(retry, overflow2) if o]
-                    overflow3 = self._append_rows(retry2)
-                    if overflow3.any():
-                        # structural caps (slots / DC columns): the
-                        # rows are unrepresentable and, unlogged,
-                        # unrecoverable — keep the loss loud
-                        recorder.record(
-                            "device", "evict_lost_rows",
-                            plane=self.type_name,
-                            rows=int(overflow3.sum()))
-                    retry, overflow2 = retry2, overflow3
-                bad_keys = {self.rev_keys[r[0]]
-                            for r, o in zip(retry, overflow2) if o}
-                for key in bad_keys:
-                    if key is not _Evicted:
-                        self.evict(key)
+                self._settle_overflow(rows, overflow, self._last_stable,
+                                      self._ring_vc_bound)
         self._reshard()
         stats.registry.device_flush_latency.observe(
             time.perf_counter() - t0)
         recorder.record("device", "flush", plane=self.type_name,
                         rows=len(rows),
                         overflow=int(overflow.sum()))
+
+    def _settle_overflow(self, rows: List[tuple], overflow: np.ndarray,
+                         stable: Optional[VC], ring_bound: VC) -> None:
+        """The retry path of a flush some of whose rows overflowed
+        their key's ring: a GC at the stable snapshot ``stable`` and
+        one retry; still-overflowing keys evict to the host path.
+        Under the partition lock with device readers quiesced,
+        whichever road the flush took; ``stable`` and ``ring_bound``
+        are the plane's as the rows were taken out."""
+        retry = [r for r, o in zip(rows, overflow) if o]
+        gst = None
+        if stable is not None:
+            pairs = self._ss_pairs(stable)
+            if pairs is not None:
+                gst = self._dense_vc(pairs)
+                self._run_device_gc(gst)
+                self._base_vc = self._base_vc.join(stable)
+                self._has_base = True
+                self._ops_since_gc = 0
+        overflow2 = self._append_rows(retry)
+        if gst is not None:
+            # invariant: every ring op with commit VC <= base_vc must be
+            # folded INTO the base — the retried rows landed after the
+            # fold above, so fold once more at the same horizon (rows
+            # above it are untouched)
+            self._run_device_gc(gst)
+        if overflow2.any() and self.no_log_replay:
+            # EMERGENCY fold (unlogged mode): dropping an overflowed row
+            # here is permanent data loss — no log exists to replay it
+            # from — so fold the WHOLE ring into the base to free lanes
+            # and retry once more.  Sound: every ring op is published,
+            # so the host-side join of staged commit VCs bounds them;
+            # reads below the raised base take the log-replay path,
+            # which unlogged mode already degrades.
+            inf = np.full(self.domain.d, _VC_INF, dtype=np.int64)
+            self._run_device_gc(inf)
+            self._base_vc = self._base_vc.join(ring_bound)
+            self._has_base = True
+            self._ops_since_gc = 0
+            retry2 = [r for r, o in zip(retry, overflow2) if o]
+            overflow3 = self._append_rows(retry2)
+            if overflow3.any():
+                # structural caps (slots / DC columns): the rows are
+                # unrepresentable and, unlogged, unrecoverable — keep
+                # the loss loud
+                recorder.record("device", "evict_lost_rows",
+                                plane=self.type_name,
+                                rows=int(overflow3.sum()))
+            retry, overflow2 = retry2, overflow3
+        bad_keys = {self.rev_keys[r[0]]
+                    for r, o in zip(retry, overflow2) if o}
+        for key in bad_keys:
+            if key is not _Evicted:
+                self.evict(key)
 
     def gc(self, stable_vc: VC) -> None:
         """Fold ops at/below the gossiped stable snapshot into the base
@@ -1994,6 +2228,7 @@ class MapPlane:
         self._subs: Dict[str, _PlaneBase] = {}
         self._presence = make_presence() if make_presence else None
         if self._presence is not None:
+            self._presence._in_map = True
             self._presence.on_evict = \
                 lambda mkey, t, state=None: self._presence_evicted(
                     mkey, state)
@@ -2060,6 +2295,10 @@ class MapPlane:
     def owns(self, key) -> bool:
         return key in self.fields
 
+    def flight_blocks(self, keys) -> bool:
+        """Never: a map's sub-planes flush in one hold (split_flush)."""
+        return False
+
     @property
     def key_index(self) -> Dict[Any, set]:
         """Key directory (uniform with _PlaneBase.key_index: len() =
@@ -2070,6 +2309,7 @@ class MapPlane:
         sub = self._subs.get(ntype)
         if sub is None:
             sub = self._make_sub(ntype)
+            sub._in_map = True
             sub.on_evict = \
                 lambda skey, t, state=None: self._sub_evicted(
                     skey, state)
@@ -2585,6 +2825,20 @@ class DevicePlane:
                     s.evict_export = export_state
                 if p._presence is not None:
                     p._presence.evict_export = export_state
+
+    def set_settle_notify(self, fn: Callable[[], None]) -> None:
+        """Wire what a settling flight calls under the partition lock:
+        the owner's ``notify_all``, for the threads waiting for it
+        (planes that never fly — maps, RGA — have no use for it)."""
+        for p in self.planes.values():
+            if isinstance(p, _PlaneBase):
+                p.on_settled = fn
+
+    def flying(self) -> bool:
+        """Whether any type plane has a flight out (under the partition
+        lock)."""
+        return any(getattr(p, "_flight", None) is not None
+                   for p in self.planes.values())
 
     def accepts(self, type_name: str, key) -> bool:
         if type_name not in self.planes or key in self.host_only:

@@ -443,6 +443,20 @@ class Registry:
             "antidote_ingest_ops_per_dispatch",
             "Amortization ratio of the coalesced ingest plane: ops "
             "per packed device dispatch over the process lifetime")
+        # ---- where a flush holds the partition lock
+        # (mat/device_plane.py begin_flight .. settle_flight)
+        self.device_flush_split = Counter(
+            "antidote_device_flush_split_total",
+            "Device flushes by road: clean (the flusher's routine flush "
+            "settled outside the partition lock), overflow (settled "
+            "through the retry path, under the lock), whole (a flush "
+            "in one hold: inline, grow, mesh or composite planes)",
+            labels=("outcome",))
+        self.device_flush_inflight_waits = Counter(
+            "antidote_device_flush_inflight_waits_total",
+            "Threads that waited for a plane whose flush was out of "
+            "the partition lock (its state donated, or their keys in "
+            "it)")
         # ---- batched inter-DC shipping plane (ISSUE 6,
         # antidote_tpu/interdc/sender.py + wire.py): the wire's frame
         # and byte economy.  Txns per batch frame (up) and encoded
@@ -1046,6 +1060,7 @@ class Registry:
                 self.ingest_flushes, self.ingest_dispatches,
                 self.ingest_coalesced_ops, self.ingest_h2d_bytes,
                 self.ingest_ops_per_dispatch,
+                self.device_flush_split, self.device_flush_inflight_waits,
                 self.ship_frames, self.ship_txns, self.ship_bytes,
                 self.ship_piggybacked_pings, self.ship_queue_depth,
                 self.ship_txns_per_frame, self.ship_bytes_per_txn,

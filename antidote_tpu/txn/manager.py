@@ -62,12 +62,11 @@ class PartitionRetired(Exception):
 class DeviceFlusher:
     """One background thread draining scheduled device flush/GC jobs —
     group commit for the data plane: the committing transaction only
-    STAGES (list append); the XLA dispatch runs here, under the owning
-    partition's lock with readers quiesced — exactly the conditions the
-    inline path had, minus the committing client waiting out the
-    flush.  (The reference materializer applies its op cache outside
-    the commit reply path the same way,
-    src/materializer_vnode.erl:620-647.)"""
+    STAGES (list append); the XLA dispatch runs here
+    (:meth:`PartitionManager.flush_scheduled`), its dispatch and its
+    fetch outside the owning partition's lock.  (The reference
+    materializer applies its op cache outside the commit reply path
+    the same way, src/materializer_vnode.erl:620-647.)"""
 
     def __init__(self):
         #: the running thread's own queue; stop() leaves a new one
@@ -98,9 +97,7 @@ class DeviceFlusher:
             with self._lock:
                 self._queued.discard(key)
             try:
-                with pm._locked:
-                    pm._wait_device_quiesce()
-                    plane.flush_gc_now()
+                pm.flush_scheduled(plane)
             except Exception:  # noqa: BLE001 — the drain must not die
                 import logging as _logging
 
@@ -337,6 +334,10 @@ class PartitionManager:
         self._stable_cached_at = 0.0
         self._lock = _SiteCondition()
         self._locked = _TimedLock(self._lock, partition)
+        if device_plane is not None:
+            # a flight out of the lock settles under it and wakes the
+            # threads waiting for it (_await_flight, checkpoint_now)
+            device_plane.set_settle_notify(self._lock.notify_all)
         #: set (under self._lock) by the handoff cutover at the moment
         #: the final log tail is snapshot: appends require self._lock,
         #: so checking this flag in the same critical section as the
@@ -762,6 +763,88 @@ class PartitionManager:
             while self._dev_readers:
                 self._lock.wait()
 
+    def _await_flight(self, plane, keys) -> None:
+        """Block (under self._lock, giving it back on its own
+        condition) while ``plane``'s flight out of the lock holds the
+        plane's state, carries one of ``keys``, or would have to land
+        before their staged rows can flush; the flight's settle wakes
+        the waiters.  Recorded as ``device_quiesce_wait`` with
+        ``flush=1``.  The caller holds no ``_dev_readers`` count."""
+        if not self._flight_in_way(plane, keys):
+            return
+        stats.registry.device_flush_inflight_waits.inc()
+        with tracer.wait_span("device_quiesce_wait", "manager",
+                              partition=self.partition, flush=1):
+            while self._flight_in_way(plane, keys):
+                self._lock.wait()
+
+    @staticmethod
+    def _flight_in_way(plane, keys) -> bool:
+        return plane.flight_blocks(keys) or (
+            getattr(plane, "_flight", None) is not None
+            and not plane.pending_keys.isdisjoint(keys))
+
+    def flush_scheduled(self, plane) -> None:
+        """The flusher thread's work on ``plane`` (DeviceFlusher): a due
+        flush, a due GC fold, the speculative grow.  The routine flush
+        of a plane that can split (``plane.split_flush``) holds the lock
+        twice and briefly — :meth:`_flush_begin` takes its rows out,
+        :meth:`_flush_settle` accounts for them — and its dispatch and
+        fetch run between the two, holding no partition lock (the
+        plane's flight: readers treat its keys as pending, and nothing
+        captures the state while the dispatch donates it).  Everything
+        else runs in one hold."""
+        flight = self._flush_begin(plane)
+        if flight is None:
+            return
+        try:
+            with tracer.span(f"device_flush:{plane.type_name}", "device",
+                             rows=len(flight.rows)):
+                plane.dispatch_flight(flight)
+                self._nudge()
+                plane.fetch_flight(flight)
+        finally:
+            self._flush_settle(plane, flight)
+
+    def _nudge(self) -> None:
+        """Wake the reads asleep on a donation that just ended, if the
+        lock is free this instant; else the settle wakes them."""
+        if self._lock.acquire(False):
+            try:
+                self._lock.notify_all()
+            finally:
+                self._lock.release()
+
+    def _flush_begin(self, plane):
+        """:meth:`flush_scheduled`'s first hold: the flight, or None
+        once the work ran here in one hold."""
+        with self._locked:
+            self._wait_device_quiesce()
+            kind = plane.flush_due() if plane.split_flush else None
+            if kind is None:
+                plane.flush_gc_now()
+                return None
+            return plane.begin_flight(kind)
+
+    def _flush_settle(self, plane, flight) -> None:
+        """:meth:`flush_scheduled`'s second hold: settle the flight
+        (unless a thread that had to touch the plane did), then the
+        due GC fold and grow."""
+        with self._locked:
+            ov = flight.overflow
+            if not flight.settled and ov is not None and ov.any():
+                # the retry path donates the state again: no new
+                # capture, and the captures of the post-append state
+                # drain first
+                flight.donating = True
+                self._wait_device_quiesce()
+            plane.settle_flight(flight, ov)
+            if flight.error is None and plane.gc_grow_due():
+                # the fold and the grow donate the state that reads
+                # captured once the dispatch had returned
+                self._wait_device_quiesce()
+                plane.gc_grow_now()
+
     def _migrate_key_to_host(self, key, type_name: str,
                              state=None) -> None:
         """Device-plane eviction handler: rebuild the key's host-store
@@ -1181,8 +1264,10 @@ class PartitionManager:
         nothing when it returns: the clock wait, the prepared
         transactions that may still commit one of the keys below the
         snapshot (TimeoutError at ``deadline``, a time.monotonic()
-        reading), and the flush — with its quiesce wait — of every
-        plane that holds pending operations for a key.  The caller
+        reading), the flush out of the lock of a plane whose state it
+        donates or which carries a key (:meth:`_await_flight`), and the
+        flush — with its quiesce wait — of every plane that holds
+        pending operations for a key.  The caller
         must hold no partition's reader count (read_requests).  What
         it found is not a promise: read_many_begin checks again."""
         if time.monotonic() >= deadline:
@@ -1203,11 +1288,17 @@ class PartitionManager:
                     by_type.setdefault(type_name, []).append(key)
             for type_name, keys_t in by_type.items():
                 plane = self.device.planes[type_name]
-                if not plane.pending_keys.isdisjoint(keys_t):
+                while True:
+                    self._await_flight(plane, keys_t)
+                    if plane.pending_keys.isdisjoint(keys_t):
+                        break
                     # a flush donates buffers: readers of older
-                    # captures drain first
+                    # captures drain first — and a wait gives the lock
+                    # back, so a flight may have begun meanwhile
                     self._wait_device_quiesce()
-                    plane.flush()
+                    if not self._flight_in_way(plane, keys_t):
+                        plane.flush()
+                        break
 
     def read_many_begin(self, items, snapshot_vc, txid=None,
                         exact_state: bool = False):
@@ -1225,7 +1316,9 @@ class PartitionManager:
         snapshot, a prepared transaction may still commit one of the
         keys below it (Clock-SI: a read at ``s`` sees every commit at
         or below ``s``), or a plane holds pending operations for a key
-        it would fold — it returns None, having taken and counted
+        it would fold, or that plane's flush is out of the lock with its
+        state donated or the key aboard — it returns None, having taken
+        and counted
         nothing: the caller releases what it holds, waits in read_gate
         and captures again (:func:`read_requests`).  Both checks are
         made in the lock hold that makes the closures, whatever a gate
@@ -1269,8 +1362,10 @@ class PartitionManager:
                 else:
                     host_items.append((key, type_name))
             for type_name, pairs in by_type.items():
-                if not self.device.planes[type_name].pending_keys \
-                        .isdisjoint([k for k, _fr, _ex in pairs]):
+                plane = self.device.planes[type_name]
+                keys_t = [k for k, _fr, _ex in pairs]
+                if not plane.pending_keys.isdisjoint(keys_t) \
+                        or plane.flight_blocks(keys_t):
                     return None
             if cache_hits:
                 stats.registry.read_cache_hits.inc(cache_hits)
@@ -1446,12 +1541,19 @@ class PartitionManager:
                 # (A publish that is not deferred has no such window:
                 # commit() waits for the readers before it appends.)
                 # Readers drain for the capture too: its flushes
-                # donate buffers.
-                if self._dev_readers or self._defer_unpublished:
+                # donate buffers; and a flight out of the lock lands
+                # first, for the same reason.
+                def flying():
+                    return self.device is not None and self.device.flying()
+
+                if self._dev_readers or self._defer_unpublished \
+                        or flying():
+                    if flying():
+                        stats.registry.device_flush_inflight_waits.inc()
                     with tracer.wait_span("ckpt_quiesce_wait", "oplog",
                                           partition=self.partition):
                         while self._dev_readers \
-                                or self._defer_unpublished:
+                                or self._defer_unpublished or flying():
                             self._lock.wait()
                 doc = self.log.capture_cut()
                 dirty, self._ckpt_dirty = self._ckpt_dirty, {}

@@ -6,7 +6,6 @@ sentinel, and ``stop()`` joined a thread that slept in ``get()`` for
 good: ``tests/cluster/test_causal_federation.py``'s restart never came
 back ("writer starved by certification aborts")."""
 
-import contextlib
 import threading
 
 from antidote_tpu.txn.manager import DeviceFlusher
@@ -15,10 +14,8 @@ from antidote_tpu.txn.manager import DeviceFlusher
 class _Pm:
     """What the flusher touches of a partition manager."""
 
-    _locked = contextlib.nullcontext()
-
-    def _wait_device_quiesce(self):
-        pass
+    def flush_scheduled(self, plane):
+        plane.flush_gc_now()
 
 
 class _Plane:

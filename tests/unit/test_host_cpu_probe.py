@@ -48,6 +48,10 @@ def test_a_rehearsed_window_gives_the_whole_account(tmp_path, capsys):
         1000 * sum(acc["gc_pause_s"].values()) / acc["length_s"])
     assert all(n >= 0 for n in acc["gc_collections"].values())
     assert "| pm._lock site |" in text and "| handlers |" in text
+    # the flusher's routine flushes left the lock, and are counted so
+    fl = acc["flushes"]
+    assert fl["clean"] > 0 and fl["inflight_waits"] >= 0
+    assert "flushes: clean" in text
 
 
 def test_a_tree_without_the_account_is_refused(tmp_path, monkeypatch):

@@ -19,7 +19,10 @@ account (``antidote_tpu/obs/host.py``) says of the window:
 - every partition's ``pm._lock`` summed by acquiring site: holds,
   waits and the time a site slept on the condition with the lock given
   back;
-- the cyclic collector's passes and pauses by generation.
+- the cyclic collector's passes and pauses by generation;
+- the device flushes by how long they held the partition lock
+  (``antidote_device_flush_split_total{outcome}``) and the waits for a
+  flush out of the lock, where the program counts them.
 
 The same accounts are the registry's families at ``/metrics``
 (``process_cpu_seconds_total``, ``antidote_thread_cpu_seconds_total``,
@@ -68,6 +71,7 @@ def run(args) -> dict:
         cell.mix = dataclasses.replace(cell.mix, clients=args.clients)
     dep = harness.Deployment(cell, args.seed)
     snaps: list = []
+    flushes: list = []
     try:
         dep.open()
         # the window's bounds are its two counter readings
@@ -77,6 +81,7 @@ def run(args) -> dict:
 
         def counters_and_account():
             snaps.append(host.account())
+            flushes.append(flush_account())
             return counters()
 
         dep.counters = counters_and_account
@@ -86,6 +91,8 @@ def run(args) -> dict:
         dep.close()
     reduced = harness.reduce_reading(cell, reading, dep.history)
     account = host.difference(snaps[-2], snaps[-1])
+    account["flushes"] = {k: flushes[-1][k] - flushes[-2][k]
+                          for k in flushes[-1]}
     answered = sum(reduced["detail"]["answered"].values())
     e2e = reduced["end_to_end"]
 
@@ -111,6 +118,21 @@ def run(args) -> dict:
     return account
 
 
+def flush_account() -> dict:
+    """The flushes by outcome and the waits for a flush out of the
+    lock, so far; empty for a program that does not count them."""
+    from antidote_tpu import stats
+
+    reg = stats.registry
+    split = getattr(reg, "device_flush_split", None)
+    if split is None:
+        return {}
+    out = {o: split.value(outcome=o)
+           for o in ("clean", "overflow", "whole")}
+    out["inflight_waits"] = reg.device_flush_inflight_waits.value()
+    return out
+
+
 def report(acc: dict) -> str:
     w = acc["length_s"]
     missed = (f" (limits not kept: {', '.join(acc['not_kept'])})"
@@ -134,6 +156,14 @@ def report(acc: dict) -> str:
             f"{acc['gc_pause_s'][g]:.3f} s" for g in sorted(
                 acc["gc_pause_s"])),
         "", "| thread kind | CPU-s | share |", "| --- | --- | --- |"]
+    fl = acc.get("flushes")
+    if fl:
+        n = fl["clean"] + fl["overflow"] + fl["whole"]
+        lines.insert(-3, (
+            f"flushes: clean {fl['clean']:.0f}, overflow "
+            f"{fl['overflow']:.0f}, whole {fl['whole']:.0f} (clean "
+            f"{100 * fl['clean'] / n if n else 0:.1f} % of {n:.0f}); "
+            f"waits on a flush out of the lock {fl['inflight_waits']:.0f}"))
     total = acc["python_threads_cpu_s"] or 1.0
     for kind, s in sorted(acc["thread_cpu_s"].items(),
                           key=lambda kv: -kv[1]):

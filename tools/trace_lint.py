@@ -110,8 +110,8 @@ ENTRY_POINTS: Dict[str, Dict[str, List[str]]] = {
     },
     "antidote_tpu/mat/device_plane.py": {
         "DevicePlane": ["stage", "read_many", "gc", "flush"],
-        "_PlaneBase": ["_append_rows", "read_many_begin", "_many_reader",
-                       "flush", "gc"],
+        "_PlaneBase": ["_dispatch_rows", "_fetch_overflow",
+                       "read_many_begin", "_many_reader", "flush", "gc"],
     },
     "antidote_tpu/mat/sharded.py": {
         "_ShardedBase": ["append", "read", "read_keys"],
