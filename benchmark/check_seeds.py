@@ -8,7 +8,7 @@ One set-up (the load is most of a run), then for every seed fresh
 clients, a short warm-up, a window at the cell's own load, the read-back
 and the comparison — the program's numbers (the lower readings) and the
 control's (the upper ones): the reference put in the program's place
-with one stated guarantee broken (reference.StaleHistory).  The
+with one stated guarantee broken (``reference.control_numbers``).  The
 benchmark's own runs never run this.
 """
 

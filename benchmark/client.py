@@ -1,4 +1,6 @@
-"""One closed-loop client, a process of its own.
+"""One closed-loop client, a process of its own: a writer sends the
+mix's transactions to the origin DC; a prober commits one update there
+and reads the same keys back at a second DC at the returned clock.
 
 Started by harness.py with its parameters as one JSON argument; obeys
 lines on stdin (``run <phase> <t_start> <t_end>`` on the machine's
@@ -36,6 +38,7 @@ from benchmark.traffic import (  # noqa: E402
     ClientStream,
     Keyspace,
     Mix,
+    Txn,
 )
 
 
@@ -54,17 +57,25 @@ class Client:
         self.stream = ClientStream(self.mix, self.ks, p["seed"],
                                    p["client"])
         self.cl = PbClient(port=p["port"], timeout=p["timeout_s"])
+        #: a prober's second server: the DC it reads its own writes at
+        self.remote = (PbClient(port=p["remote_port"],
+                                timeout=p["timeout_s"])
+                       if p.get("remote_port") else None)
         self.clock = None  # the session's causal clock
 
     def close(self) -> None:
         self.cl.close()
+        if self.remote is not None:
+            self.remote.close()
 
-    def one(self, txn) -> dict:
-        """Send one transaction and wait for its answer; one that
-        certification aborts is sent again after a pause, and its
-        latency runs from the first send to the last answer."""
-        ks, cl = self.ks, self.cl
-        rec = {"client": self.p["client"], "kind": txn.kind, "ok": False,
+    def one(self, txn, cl=None, dc: str = ORIGIN_DC) -> dict:
+        """Send one transaction to ``cl`` (the origin DC's server by
+        default) and wait for its answer; one that certification aborts
+        is sent again after a pause, and its latency runs from the
+        first send to the last answer."""
+        ks, cl = self.ks, cl or self.cl
+        rec = {"client": self.p["client"], "dc": dc, "kind": txn.kind,
+               "ok": False,
                "read_keys": txn.read_keys, "updates": txn.updates,
                "values": None, "snapshot_time": None, "commit_time": None,
                "clock_sent": (self.clock.get_dc(ORIGIN_DC)
@@ -99,17 +110,39 @@ class Client:
         rec["t_done"] = time.monotonic()
         return rec
 
+    def probe(self) -> list:
+        """A prober's loop: one update at the origin DC and, once it is
+        acknowledged, one read of the same keys at the other DC at the
+        commit clock it returned; the read's ``t_acked`` is the
+        update's acknowledgement.  The session stays the origin's: the
+        next update carries that commit clock, not the other DC's
+        answer, whose entry for that DC only its heartbeat would
+        bring to the origin (a wait of up to a heartbeat period)."""
+        update = self.one(self.stream.next("update_only_txn"))
+        if not update["ok"]:
+            return [update]
+        session = self.clock
+        keys = [k for k, _op, _arg in update["updates"]]
+        read = self.one(Txn("read_only_txn", keys, []), self.remote,
+                        self.p["remote_dc"])
+        read["t_acked"] = update["t_done"]
+        self.clock = session
+        return [update, read]
+
     def run(self, phase: str, t_start: float, t_end: float) -> list:
         # a warm-up's read phase sends the mix's reads only: the read
-        # path is where the program keeps a program per pattern
+        # path is where the program keeps a program per pattern; a
+        # prober runs its loop in every phase
         kind = "read_only_txn" if phase == "warmreads" else None
         while time.monotonic() < t_start:
             time.sleep(min(0.0005, max(t_start - time.monotonic(), 0)))
         records = []
         while time.monotonic() < t_end:
-            rec = self.one(self.stream.next(kind))
-            records.append(rec)
-            if rec["error"] and "PbServerError" not in rec["error"]:
+            recs = (self.probe() if self.remote is not None
+                    else [self.one(self.stream.next(kind))])
+            records += recs
+            if any(r["error"] and "PbServerError" not in r["error"]
+                   for r in recs):
                 break  # the stream cannot be trusted any more
         return records
 

@@ -5,8 +5,11 @@ mix or one per-layer metric is in a file of its own; nothing here names
 one.
 
 One process (this one) holds the chip, the node and the ``PbServer``,
-and traces.  The clients are children that never open
-the chip (client.py).  Set-up is everything before the window opens:
+and traces.  A configuration with ``dcs`` 2 or more deploys that many
+``DataCenter``s in this process, each with its own ``TcpTransport`` on
+loopback, its own planes on the chip and its own ``PbServer``; every
+write originates at the first.  The clients are children that never
+open the chip (client.py).  Set-up is everything before the window opens:
 imports, compile-cache loads, the load of every key, the clients'
 start and a warm-up with the cell's own generator until no new program
 appears.
@@ -53,6 +56,11 @@ def say(msg: str) -> None:
     print(f"[bench] {msg}", file=sys.stderr, flush=True)
 
 
+def dc_name(i: int) -> str:
+    """The id of a deployment's ``i``-th DC: the origin first."""
+    return ORIGIN_DC if i == 0 else f"dc{i + 1}"
+
+
 class BenchError(Exception):
     """The run cannot give a result."""
 
@@ -75,6 +83,11 @@ class Cell:
     def keyspace(self) -> Keyspace:
         return Keyspace(int(self.config["partitions"]),
                         int(self.config["keys_per_partition"]))
+
+    @property
+    def dcs(self) -> int:
+        """How many DCs the configuration deploys; one without ``dcs``."""
+        return int(self.config.get("dcs", 1))
 
 
 def _find(root: str, paths: list, *parts: str) -> str:
@@ -128,6 +141,9 @@ def load_cell(root: str, workload: str) -> Cell:
     # the mix meets its keyspace here: a key generator that cannot draw
     # over it refuses now, before set-up, not in a client after the load
     ClientStream(cell.mix, cell.keyspace, 0, 0)
+    if cell.mix.probers and cell.dcs < 2:
+        raise BenchError(f"{mix_file}: probers read at a second DC, and "
+                         f"{cfg_entry['file']} deploys {cell.dcs}")
     return cell
 
 
@@ -204,23 +220,27 @@ def device_record() -> dict:
 
 
 class Clients:
-    """The cell's client processes for one seed."""
+    """The cell's client processes for one seed: the mix's ``clients``
+    at the origin DC's server, then its ``probers``, each with the
+    second DC's server as well."""
 
-    def __init__(self, cell: Cell, seed: int, port: int, workdir: str):
+    def __init__(self, cell: Cell, seed: int, ports: list, workdir: str):
         self.cell, self.workdir = cell, workdir
         n = cell.mix.clients
         ks = cell.keyspace
         env = dict(os.environ, JAX_PLATFORMS="cpu")
         self.outs, self.procs = [], []
-        for c in range(n):
+        for c in range(n + cell.mix.probers):
             out = os.path.join(workdir, f"client{seed}_{c}")
             params = {
-                "client": c, "seed": seed, "port": port,
+                "client": c, "seed": seed, "port": ports[0],
                 "n_partitions": ks.n_partitions,
                 "keys_per_partition": ks.keys_per_partition,
                 "mix_file": cell.mix_file, "out": out,
                 "timeout_s": CLIENT_TIMEOUT_S,
             }
+            if c >= n:
+                params.update(remote_port=ports[1], remote_dc=dc_name(1))
             self.outs.append(out)
             self.procs.append(subprocess.Popen(
                 [sys.executable, os.path.join(HERE, "client.py"),
@@ -302,14 +322,20 @@ class Deployment:
         self.compiles = CompileWatch()
         self.watch = LogWatch()
         self.db = self.server = None
+        #: every DC's node and server, the origin's first (``db`` and
+        #: ``server`` are the origin's)
+        self.dbs: list = []
+        self.servers: list = []
 
     # -- up --------------------------------------------------------------
 
-    def _node_config(self):
+    def _node_config(self, dc: str | None = None):
         from antidote_tpu.config import Config
 
+        obs = os.path.join(self.workdir, "obs")
         return Config(n_partitions=self.ks.n_partitions,
-                      flight_recorder_dir=os.path.join(self.workdir, "obs"),
+                      flight_recorder_dir=(obs if dc is None
+                                           else os.path.join(obs, dc)),
                       **self.cell.config.get("config", {}))
 
     def open(self) -> None:
@@ -318,15 +344,60 @@ class Deployment:
 
         self.watch.start()
         gc.collect()
-        self.db = AntidoteTPU(
-            dc_id=ORIGIN_DC, config=self._node_config(),
-            data_dir=os.path.join(self.workdir, ORIGIN_DC))
-        self._load()
-        self.server = PbServer(self.db, port=0).start()
-        self._warm_patterns()
+        if self.cell.dcs == 1:
+            self.dbs = [AntidoteTPU(
+                dc_id=ORIGIN_DC, config=self._node_config(),
+                data_dir=os.path.join(self.workdir, ORIGIN_DC))]
+        else:
+            self._open_dcs()
+        self.db = self.dbs[0]
+        clock = self._load()
+        for db in self.dbs[1:]:
+            self._await_replica(db, clock)
+        self.servers = [PbServer(db, port=0).start() for db in self.dbs]
+        self.server = self.servers[0]
+        for server in self.servers:
+            self._warm_patterns(server.port)
 
-    def _load(self) -> None:
-        """Every key written once, through the API."""
+    def _open_dcs(self) -> None:
+        """The configuration's DCs, each on a ``TcpTransport`` of its
+        own over loopback, the full mesh connected before the load
+        (upstream's ``connect_cluster``), then their background
+        processes: delivery, heartbeats."""
+        from antidote_tpu.interdc.dc import DataCenter, connect_dcs
+        from antidote_tpu.interdc.tcp import TcpTransport
+
+        t0 = time.monotonic()
+        for i in range(self.cell.dcs):
+            name, bus = dc_name(i), TcpTransport()
+            try:
+                self.dbs.append(DataCenter(
+                    name, bus, config=self._node_config(name),
+                    data_dir=os.path.join(self.workdir, name)))
+            except BaseException:
+                bus.close()
+                raise
+        connect_dcs(self.dbs)
+        for dc in self.dbs:
+            dc.start_bg_processes()
+        say(f"{len(self.dbs)} DCs up and connected in "
+            f"{time.monotonic() - t0:.1f} s")
+
+    def _await_replica(self, db, clock, timeout_s: float = 600.0) -> None:
+        """Until ``db``'s stable snapshot covers the load's last commit:
+        every loaded key is then readable there."""
+        t0, want = time.monotonic(), clock.get_dc(ORIGIN_DC)
+        while db.node.stable_vc().get_dc(ORIGIN_DC) < want:
+            if time.monotonic() - t0 > timeout_s:
+                raise BenchError(f"{db.node.dc_id} did not cover the "
+                                 f"load within {timeout_s:.0f} s")
+            time.sleep(0.05)
+        say(f"{db.node.dc_id} covered the load "
+            f"{time.monotonic() - t0:.1f} s after it ended")
+
+    def _load(self):
+        """Every key written once, through the API at the origin DC;
+        returns the last commit clock."""
         ks, t0 = self.ks, time.monotonic()
         clock = None
         for lo in range(0, ks.n_keys, LOAD_TXN):
@@ -334,8 +405,9 @@ class Deployment:
                 ks.load_update(k, self.incs, self.masks)
                 for k in range(lo, min(lo + LOAD_TXN, ks.n_keys))])
         say(f"loaded {ks.n_keys} keys in {time.monotonic() - t0:.1f} s")
+        return clock
 
-    def _warm_patterns(self) -> None:
+    def _warm_patterns(self, port: int) -> None:
         """Every read program the window can ask for, once, before it.
         A read is one program per multiset of store calls
         (device_plane.py ``fused_read``): with equal plane shapes, one
@@ -347,7 +419,8 @@ class Deployment:
         read a few times over the wire, with keys drawn from the seed
         (some are answered by the value cache, hence several tries);
         then one batched read per bucket that a checkpoint's fold of
-        dirty keys can reach."""
+        dirty keys can reach.  Each DC's planes keep programs of their
+        own, so it runs against every DC's server."""
         from antidote_tpu.pb.client import PbClient
 
         ks, t0 = self.ks, time.monotonic()
@@ -365,8 +438,7 @@ class Deployment:
 
         n_parts = ks.n_partitions
         reads = 0
-        with PbClient(port=self.server.port,
-                      timeout=CLIENT_TIMEOUT_S) as cl:
+        with PbClient(port=port, timeout=CLIENT_TIMEOUT_S) as cl:
             for c in range(n_parts + 1):
                 for s in range(n_parts + 1):
                     for _ in range(PATTERN_TRIES if c + s else 0):
@@ -416,7 +488,22 @@ class Deployment:
         out["jax_programs_compiled"] = self.compiles.programs
         self.kernel_misses = {n: k["compile_misses"]
                               for n, k in kernels.items()}
+        if self.cell.dcs > 1:
+            # the registry is the process's: every DC feeds it, and
+            # only the origin ships transactions, only the others apply
+            # them.  ``Histogram`` shows its sum to its exposition only
+            wait = reg.depgate_wait
+            out["depgate_wait_count"] = int(wait.count)
+            out["depgate_wait_us"] = int(round(wait._sum * 1e6))
+            out["ship_txns"] = int(reg.ship_txns.value())
+            out["ship_frames"] = int(reg.ship_frames.value(kind="batch"))
         return out
+
+    def remote_pending(self) -> int:
+        """The transactions the DCs past the origin have received and
+        not yet applied: their dependency gates' queues."""
+        return sum(g.pending() for db in self.dbs[1:]
+                   for g in db.dep_gates)
 
     # -- one seed ----------------------------------------------------------
 
@@ -427,7 +514,8 @@ class Deployment:
         the acknowledged writes, and the comparison.  Returns the raw
         reading; ``result_line`` makes the line of it."""
         cell = self.cell
-        clients = Clients(cell, seed, self.server.port, self.workdir)
+        clients = Clients(cell, seed, [s.port for s in self.servers],
+                          self.workdir)
         try:
             warm = self._warm(clients, warm_max_s)
             t_start = time.monotonic() + 0.25
@@ -436,11 +524,13 @@ class Deployment:
             time.sleep(max(t_start - time.monotonic(), 0))
             c0 = self.counters()
             misses0 = self.kernel_misses
+            queued = [self.remote_pending()]
             traced_slice = None
             if traced:
                 traced_slice = self._trace_slice(t_start, seconds)
             time.sleep(max(t_end - time.monotonic(), 0))
             c1 = self.counters()
+            queued.append(self.remote_pending())
             window = clients.collect("window", t_end)
             device = device_record()
             reference.feed(self.history, [
@@ -457,6 +547,9 @@ class Deployment:
             "records": records, "counters": counters,
             "device": device, "readback": readback,
             "error_logs": len(self.watch.errors),
+            # the other DCs' received-but-unapplied transactions at the
+            # window's open and close: whether they keep up
+            "remote_queued": queued if cell.dcs > 1 else None,
             "warm_phases": len(warm), "trace": None,
             # what the window compiled, by name (should be nothing)
             "compiled": {
@@ -510,6 +603,10 @@ class Deployment:
         return {"log_dir": log_dir, "t0": t0, "t1": t0 + slice_s}
 
     def _reduce(self, sl: dict, records: list, device: dict) -> dict:
+        """The trace's numbers, and the bytes the work answered in the
+        slice needs: the keys read at any DC, and each acknowledged
+        operation once in every DC's planes (the origin appends it,
+        every other DC applies it)."""
         out = trace.reduce_xplane(trace.xplane_of(sl["log_dir"]))
         ks = self.ks
         keys_read: dict = {}
@@ -522,7 +619,7 @@ class Deployment:
                 keys_read[t] = keys_read.get(t, 0) + 1
             for k, _op, _arg in r["updates"]:
                 t = ks.type_of(k)
-                ops[t] = ops.get(t, 0) + 1
+                ops[t] = ops.get(t, 0) + self.cell.dcs
         out["needed_bytes"] = trace.needed_bytes(
             trace.plane_row_bytes(self.db), keys_read, ops)
         out["hbm_bytes_per_s"] = trace.peaks_for(
@@ -533,9 +630,9 @@ class Deployment:
         """Once the window has closed: a seeded sample of the keys this
         seed's clients wrote, with each client's last transaction in it,
         read at the newest commit clock through the same entry the
-        window drove: ten keys a static read, over the wire."""
+        window drove: ten keys a static read, over the wire, at every
+        DC (``remote`` holds the others' counts)."""
         from antidote_tpu.clocks import VC
-        from antidote_tpu.pb.client import PbClient
 
         written: dict = {}
         last: dict = {}
@@ -560,10 +657,20 @@ class Deployment:
         keys += [int(k) for k in rng.integers(0, self.ks.n_keys,
                                               len(keys) // 4 + 10)]
         clock = VC({ORIGIN_DC: newest}) if newest else None
+        out = self._read_back_at(self.server, keys, clock)
+        out["keys"] = keys
+        if self.cell.dcs > 1:
+            out["remote"] = [self._read_back_at(server, keys, clock)
+                             for server in self.servers[1:]]
+        return out
+
+    def _read_back_at(self, server, keys: list, clock) -> dict:
+        from antidote_tpu.pb.client import PbClient
+
         compared = wrong = 0
         first: list = []
-        with PbClient(port=self.server.port,
-                      timeout=CLIENT_TIMEOUT_S) as cl:
+        dc = server.db.node.dc_id
+        with PbClient(port=server.port, timeout=CLIENT_TIMEOUT_S) as cl:
             for lo in range(0, len(keys), 10):
                 part = keys[lo:lo + 10]
                 values, snap = cl.read_objects_static(
@@ -576,19 +683,22 @@ class Deployment:
                         wrong += 1
                         if len(first) < 3:
                             first.append(
-                                f"read-back key {key} at {at}: read "
-                                f"{got!r}, the reference holds {want!r}")
-        return {"compared": compared, "wrong": wrong, "first": first,
-                "keys": keys}
+                                f"read-back at {dc} key {key} at {at}: "
+                                f"read {got!r}, the reference holds "
+                                f"{want!r}")
+        return {"compared": compared, "wrong": wrong, "first": first}
 
     # -- down ------------------------------------------------------------
 
     def close(self) -> None:
-        if self.server is not None:
-            self.server.stop()
-        if self.db is not None:
-            self.db.close()
+        for server in self.servers:
+            server.stop()
+        for db in self.dbs:
+            db.close()
+            if self.cell.dcs > 1:
+                db.bus.close()
         self.db = self.server = None
+        self.dbs, self.servers = [], []
         self.watch.stop()
         shutil.rmtree(self.workdir, ignore_errors=True)
 
@@ -604,6 +714,12 @@ class WindowView:
     answered: dict
     update_ops: int
     trace: dict | None
+    #: the probers' lags, acknowledgement at the origin to the answer
+    #: at another DC, in seconds (none in a deployment of one DC)
+    vis_lag_s: list = field(default_factory=list)
+    #: the DCs that hold every acknowledged operation in their planes:
+    #: the origin appends it, each other DC applies it
+    dcs: int = 1
 
 
 def _p95_ms(samples: list):
@@ -613,10 +729,17 @@ def _p95_ms(samples: list):
 def reduce_reading(cell: Cell, reading: dict,
                    history=None) -> dict:
     """The window's records to numbers: the end-to-end metrics, the
-    per-layer metrics, the counts compared and a detail record."""
+    per-layer metrics, the counts compared and a detail record.  A
+    record answered at another DC than the origin is a prober's read:
+    its lag from the acknowledgement at the origin is a sample of
+    ``vis_lag_p95_ms`` (and of the per-layer ``vis_lag_p50_ms``), and it
+    is compared as a remote read."""
     t_start, t_end = reading["t_start"], reading["t_end"]
     records = reading["records"]
     lat = {"read_only_txn": [], "update_only_txn": []}
+    #: lags from the acknowledgement at the origin to the answer at
+    #: another DC
+    vis = []
     answered, in_window = {}, 0
     update_ops = failed = aborts = 0
     gaps = []
@@ -627,7 +750,11 @@ def reduce_reading(cell: Cell, reading: dict,
             gaps.append(r["t_send"] - prev_done[c])
         prev_done[c] = r["t_done"]
         # a tail is the tail of all requests: one that failed waited too
-        lat[r["kind"]].append(r["t_done"] - r["t_send"])
+        local = r.get("dc", ORIGIN_DC) == ORIGIN_DC
+        if local:
+            lat[r["kind"]].append(r["t_done"] - r["t_send"])
+        else:
+            vis.append(r["t_done"] - r["t_acked"])
         aborts += r["aborts"]
         if not r["ok"]:
             failed += 1
@@ -636,23 +763,27 @@ def reduce_reading(cell: Cell, reading: dict,
                     f"{r['t_done'] - r['t_send']:.3f} s: {r['error']}")
         elif r["t_done"] <= t_end:
             in_window += 1
-            answered[r["kind"]] = answered.get(r["kind"], 0) + 1
+            kind = r["kind"] if local else "remote_" + r["kind"]
+            answered[kind] = answered.get(kind, 0) + 1
             update_ops += len(r["updates"])
     seconds = t_end - t_start
     end_to_end = {
         "txn_per_s": in_window / seconds,
         "read_p95_ms": _p95_ms(lat["read_only_txn"]),
         "update_p95_ms": _p95_ms(lat["update_only_txn"]),
+        "vis_lag_p95_ms": _p95_ms(vis),
         "setup_s": reading["setup_s"],
     }
     view = WindowView(counters=reading["counters"], answered=answered,
-                      update_ops=update_ops, trace=reading["trace"])
+                      update_ops=update_ops, trace=reading["trace"],
+                      vis_lag_s=vis, dcs=cell.dcs)
     per_layer = {name: read(view) for name, read in cell.readers.items()}
 
     numbers = []
     if history is not None:
-        compared, wrong, first = reference.wrong_reads(history, records)
-        carried, behind = reference.behind_session(records)
+        local, remote = reference.by_dc(records)
+        compared, wrong, first = reference.wrong_reads(history, local)
+        carried, behind = reference.behind_session(local)
         numbers += [("reads_compared", compared, ">=", 1),
                     ("reads_wrong", wrong, "<=", 0),
                     ("session_clocks_sent", carried, ">=", 1),
@@ -668,6 +799,24 @@ def reduce_reading(cell: Cell, reading: dict,
              reading["counters"]["read_cache_misses"], ">=", 1),
             ("error_logs", reading["error_logs"], "<=", 0),
         ]
+        if cell.dcs > 1:
+            # every prober read at another DC, against the history at
+            # that snapshot's origin entry; its snapshot may not lie
+            # below the commit it was sent with; and the read-back at
+            # every other DC
+            r_compared, r_wrong, r_first = reference.wrong_reads(
+                history, remote)
+            _sent, r_behind = reference.behind_session(remote)
+            numbers += [
+                ("remote_reads_compared", r_compared, ">=", 1),
+                ("remote_reads_wrong", r_wrong, "<=", 0),
+                ("remote_snapshots_behind_commit", r_behind, "<=", 0),
+                ("remote_acks_read_back",
+                 sum(x["compared"] for x in rb["remote"]), ">=", 1),
+                ("remote_acks_unreadable",
+                 sum(x["wrong"] for x in rb["remote"]), "<=", 0),
+            ]
+            first += r_first + [f for x in rb["remote"] for f in x["first"]]
         for line in first + rb["first"]:
             say("differs: " + line)
     detail = {
@@ -688,6 +837,9 @@ def reduce_reading(cell: Cell, reading: dict,
         "compiled_in_window": reading.get("compiled"),
         "counters": reading["counters"],
     }
+    if cell.dcs > 1:
+        detail.update(vis_lag_samples=len(vis),
+                      remote_gate_queued_open_close=reading["remote_queued"])
     return {"end_to_end": end_to_end, "per_layer": per_layer,
             "numbers": numbers, "attempted": len(records),
             "failed": failed, "detail": detail}

@@ -22,7 +22,7 @@ from __future__ import annotations
 
 import bisect
 
-from benchmark.traffic import ELEMS
+from benchmark.traffic import ELEMS, ORIGIN_DC
 
 
 class PlainHistory:
@@ -94,6 +94,15 @@ class StaleHistory(PlainHistory):
         return super().at(key, before)
 
 
+def by_dc(records: list) -> tuple:
+    """(the records answered at the origin DC, those answered at any
+    other: the probers' reads).  A record without ``dc`` is the
+    origin's."""
+    local = [r for r in records if r.get("dc", ORIGIN_DC) == ORIGIN_DC]
+    return local, [r for r in records
+                   if r.get("dc", ORIGIN_DC) != ORIGIN_DC]
+
+
 def feed(history: PlainHistory, records: list) -> None:
     """Apply every acknowledged update of all the clients' records, in
     the order of the commit times."""
@@ -149,23 +158,37 @@ def control_numbers(history: PlainHistory, records: list,
     every key of the read-back answered by ``StaleHistory`` over the
     same histories.  Causal sessions broken: every transaction answered
     at the clock the session sent with the transaction before it, one
-    answer late."""
+    answer late.  Where probers read at another DC (``dc`` on a record
+    other than the origin), replication broken: each such read answered
+    at the commit clock it was sent with, less one: the write it should
+    see not yet applied there (``remote_reads_wrong``).  The other
+    remote numbers are held by faults driven under the harness
+    (tests/benchmark/test_bb2dc.py), not by this control: a snapshot
+    below its commit clock is what the control answers by construction,
+    and the read-back at another DC asks the keys the origin's does."""
+    local, remote = by_dc(records)
     stale = StaleHistory(history.ks, history._incs, history._masks)
     stale._hist = history._hist
     _c, wrong, _f = wrong_reads(
-        history, records,
+        history, local,
         answers=lambda rec: [stale.at(k, rec["snapshot_time"])
                              for k in rec["read_keys"]])
     unreadable = sum(stale.at(k) != history.at(k)
                      for k in readback["keys"])
     behind, last = 0, {}
-    for rec in records:  # each client's records are in its own order
+    for rec in local:  # each client's records are in its own order
         before = last.get(rec["client"])
         last[rec["client"]] = sent = rec["clock_sent"]
         behind += (rec["ok"] and sent is not None
                    and before is not None and before < sent)
-    return {"reads_wrong": wrong, "acks_unreadable": unreadable,
-            "snapshots_behind_session": behind}
+    out = {"reads_wrong": wrong, "acks_unreadable": unreadable,
+           "snapshots_behind_session": behind}
+    if "remote" in readback:
+        _c, out["remote_reads_wrong"], _f = wrong_reads(
+            history, remote,
+            answers=lambda rec: [history.at(k, rec["clock_sent"] - 1)
+                                 for k in rec["read_keys"]])
+    return out
 
 
 def judge(numbers: list) -> bool:

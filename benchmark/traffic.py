@@ -135,6 +135,11 @@ class Mix:
     #: ``retry_pause_ms``, until ``retry_for_s`` after its first send
     retry_for_s: float = 0.0
     retry_pause_ms: float = 0.0
+    #: closed-loop probers beside the ``clients``: each commits one
+    #: update of ``num_updates`` keys at the origin DC and, once it is
+    #: acknowledged, reads the same keys at the next DC at the commit
+    #: clock it returned (a configuration with ``dcs`` 2 or more)
+    probers: int = 0
 
     KINDS = ("read_only_txn", "update_only_txn")
 
@@ -157,7 +162,8 @@ class Mix:
                    num_updates=int(doc["num_updates"]),
                    key_generator=dict(doc["key_generator"]),
                    retry_for_s=float(doc.get("retry_for_s", 0.0)),
-                   retry_pause_ms=float(doc.get("retry_pause_ms", 0.0)))
+                   retry_pause_ms=float(doc.get("retry_pause_ms", 0.0)),
+                   probers=int(doc.get("probers", 0)))
 
 
 @dataclass

@@ -172,6 +172,15 @@ def test_an_aborted_transaction_is_sent_again_and_commits(
     assert got["acks_unreadable"] == 0
 
 
+#: at most this share of a window's reads may reach the device when the
+#: value cache holds the whole keyspace: a read that races a commit of
+#: its key past the cached frontier dispatches, 1-2 a 2 s window on
+#: average and up to 5 (PERF.md, section 7), of about 1,600-2,400 reads
+#: on an idle machine, where a window whose reads do reach the planes
+#: dispatches about once a read
+CACHED_DISPATCH_SHARE = 0.05
+
+
 def test_a_run_whose_reads_never_reach_the_device_is_not_correct(
         tiny_root, monkeypatch):
     # at this size the 65,536-entry value cache answers every read
@@ -179,6 +188,7 @@ def test_a_run_whose_reads_never_reach_the_device_is_not_correct(
     _c, _r, reduced, _h = one_window(tiny_root, monkeypatch)
     got = numbers(reduced)
     assert got["reads_wrong"] == 0 == got["acks_unreadable"]
-    assert got["device_read_dispatches"] < 5
+    reads = reduced["detail"]["answered"]["read_only_txn"]
+    assert got["device_read_dispatches"] <= CACHED_DISPATCH_SHARE * reads
     if got["device_read_dispatches"] == 0:
         assert reference.judge(reduced["numbers"]) is False

@@ -77,16 +77,19 @@ def test_a_whole_run_of_every_cell(tiny_root, on_the_cpu, capsys,
             assert m["name"] not in line["metrics"]
     declared = harness.load_cell(tiny_root, workload)
     declared = declared.per_layer if traced else declared.end_to_end
-    assert set(line["metrics"]) == {m["name"] for m in declared}
+    names = {m["name"] for m in declared}
+    assert set(line["metrics"]) == names
     if traced:
         assert 0 < line["device"]["busy_s"] <= line["device"]["window_s"]
-        for name in ("compiles_in_window", "ops_per_flush",
-                     "kernels_roofline", "device_idle_pct"):
+        for name in {"compiles_in_window", "ops_per_flush",
+                     "kernels_roofline", "device_idle_pct"} & names:
             assert line["metrics"][name]["value"] >= 0, name
-        assert 0 < line["metrics"]["kernels_roofline"]["value"] < 100
+        if "kernels_roofline" in names:
+            assert 0 < line["metrics"]["kernels_roofline"]["value"] < 100
     else:
-        for name in ("txn_per_s", "update_p95_ms", "setup_s"):
+        for name in {"txn_per_s", "update_p95_ms", "setup_s"} & names:
             assert line["metrics"][name]["value"] > 0, name
+        assert {"txn_per_s", "setup_s"} <= names
         assert "busy_s" not in line["device"]
     assert cell["chips"] == 1
     # the numbers compared close standard error, each beside its limit

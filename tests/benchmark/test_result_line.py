@@ -28,19 +28,34 @@ DEVICE = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1,
           "memory_peak_bytes": 1_400_000_000}
 
 
+#: what a traced slice's span summary holds, for the readers of spans
+CAPTURE = {"length_s": 3.0, "requests_answered": 60, "host_busy_s": 2.0,
+           "spans": {"depgate_admit": {"cat": "interdc", "kind": "work",
+                                       "count": 40, "total_s": 0.08,
+                                       "self_s": 0.08, "p95_s": 0.003}}}
+
+
 def synthetic_reading(cell, traced):
     """What a window hands back: a few transactions of every kind the
-    cell's clients send, and counter deltas that all moved."""
+    cell's clients send (with probers, each update of theirs followed by
+    a read at the second DC), and counter deltas that all moved."""
     records, t = [], 100.0
     kinds = ["read_only_txn"] * 30 + ["update_only_txn"] * 30
+    if cell.mix.probers:
+        kinds += ["update_only_txn", "remote"] * 10
     for i, kind in enumerate(kinds):
-        rec = {"client": i % 4, "kind": kind, "ok": True,
+        remote = kind == "remote"
+        kind = "read_only_txn" if remote else kind
+        rec = {"client": i % 4, "dc": "dc2" if remote else "dc1",
+               "kind": kind, "ok": True,
                "read_keys": [] if kind == "update_only_txn" else [1, 2],
                "updates": [] if kind == "read_only_txn"
                else [(1, "increment", 1), (2, "increment", 1)],
                "values": [0, 0], "snapshot_time": 5, "commit_time": 5,
                "clock_sent": 5 if i else None, "aborts": 0,
                "t_send": t, "t_done": t + 0.010 + 0.0001 * i}
+        if remote:
+            rec["t_acked"] = records[-1]["t_done"]
         records.append(rec)
         t += 0.02
     counters = {k: 7 for k in (
@@ -48,16 +63,26 @@ def synthetic_reading(cell, traced):
         "read_serve_groups", "ingest_dispatches", "log_fsyncs",
         "log_group_records", "ingest_flushes", "gc_folds", "kernel_calls",
         "kernel_compile_misses", "jax_programs_compiled")}
+    readback = {"compared": 10, "wrong": 0, "first": []}
+    if cell.dcs > 1:
+        counters.update(depgate_wait_count=40, depgate_wait_us=90_000,
+                        ship_txns=40,
+                        ship_frames=8)
+        readback["remote"] = [dict(readback)]
     return {"seed": 1, "seconds": 10.0, "t_start": 100.0, "t_end": 110.0,
             "setup_s": 120.5, "records": records, "counters": counters,
             "device": DEVICE, "error_logs": 0, "warm_phases": 3,
-            "readback": {"compared": 10, "wrong": 0, "first": []},
+            "readback": readback,
+            "remote_queued": [0, 0] if cell.dcs > 1 else None,
             "trace": TRACE if traced else None}
 
 
 @pytest.mark.parametrize("traced", [False, True])
 @pytest.mark.parametrize("workload", CELLS)
-def test_the_last_line_meets_the_contract(workload, traced):
+def test_the_last_line_meets_the_contract(workload, traced, monkeypatch):
+    from antidote_tpu.obs import prof
+
+    monkeypatch.setattr(prof, "last_capture", lambda: CAPTURE)
     cell = harness.load_cell(ROOT, workload)
     reading = synthetic_reading(cell, traced)
     reduced = harness.reduce_reading(cell, reading)
@@ -326,9 +351,13 @@ def test_a_later_pr_adds_files_and_entries_and_edits_none(tiny_root):
     assert cell.mix.operations == {"read_only_txn": 1,
                                    "update_only_txn": 1}
     # it reports the new metric, and every metric declared for all
-    # cells; an old cell does not report the new one
+    # cells that report what it moves; an old cell does not report the
+    # new one
     names = {m["name"] for m in cell.per_layer}
-    assert "gc_folds_per_s" in names and "ops_per_flush" in names
+    e2e = {m["name"] for m in cell.end_to_end}
+    for_all = {m["name"] for m in bench["per_layer"]
+               if "workloads" not in m and m["moves"] in e2e}
+    assert names == for_all | {"gc_folds_per_s"}
     reading = synthetic_reading(cell, True)
     reduced = harness.reduce_reading(cell, reading)
     line = harness.result_line(cell, True, reduced, DEVICE, TRACE)
