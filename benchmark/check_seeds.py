@@ -8,8 +8,10 @@ One set-up (the load is most of a run), then for every seed fresh
 clients, a short warm-up, a window at the cell's own load, the read-back
 and the comparison — the program's numbers (the lower readings) and the
 control's (the upper ones): the reference put in the program's place
-with one stated guarantee broken (``reference.control_numbers``).  The
-benchmark's own runs never run this.
+with one stated guarantee broken (``reference.control_numbers``; a
+record answered one field one write late).  It takes any configuration
+``load_cell`` takes, record types included, with the keyspace and the
+reference a run builds.  The benchmark's own runs never run this.
 """
 
 from __future__ import annotations

@@ -52,7 +52,8 @@ ABORT_MARKS = ("committed after snapshot", "prepared by concurrent txn")
 class Client:
     def __init__(self, p: dict):
         self.p = p
-        self.ks = Keyspace(p["n_partitions"], p["keys_per_partition"])
+        self.ks = Keyspace.of(p["n_partitions"], p["keys_per_partition"],
+                              p["types"])
         self.mix = Mix.from_file(p["mix_file"])
         self.stream = ClientStream(self.mix, self.ks, p["seed"],
                                    p["client"])

@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import gc
 import importlib.util
+import itertools
 import json
 import logging
 import os
@@ -78,11 +79,13 @@ class Cell:
     end_to_end: list
     per_layer: list
     readers: dict = field(default_factory=dict)
+    config_file: str = "configuration"
 
     @property
     def keyspace(self) -> Keyspace:
-        return Keyspace(int(self.config["partitions"]),
-                        int(self.config["keys_per_partition"]))
+        return Keyspace.of(int(self.config["partitions"]),
+                           int(self.config["keys_per_partition"]),
+                           self.config.get("types"), self.config_file)
 
     @property
     def dcs(self) -> int:
@@ -137,9 +140,10 @@ def load_cell(root: str, workload: str) -> Cell:
     cell = Cell(name=workload, chips=int(w["chips"]), config=config,
                 mix=Mix.from_file(mix_file), mix_file=mix_file,
                 end_to_end=end_to_end, per_layer=per_layer,
-                readers=readers)
-    # the mix meets its keyspace here: a key generator that cannot draw
-    # over it refuses now, before set-up, not in a client after the load
+                readers=readers, config_file=cfg_entry["file"])
+    # the types and the mix meet their keyspace here: a type the harness
+    # cannot load, update and judge, or a key generator that cannot draw
+    # over it, refuses now, before set-up, not after the load
     ClientStream(cell.mix, cell.keyspace, 0, 0)
     if cell.mix.probers and cell.dcs < 2:
         raise BenchError(f"{mix_file}: probers read at a second DC, and "
@@ -236,6 +240,7 @@ class Clients:
                 "client": c, "seed": seed, "port": ports[0],
                 "n_partitions": ks.n_partitions,
                 "keys_per_partition": ks.keys_per_partition,
+                "types": cell.config["types"],
                 "mix_file": cell.mix_file, "out": out,
                 "timeout_s": CLIENT_TIMEOUT_S,
             }
@@ -316,9 +321,8 @@ class Deployment:
         self.ks = cell.keyspace
         self.workdir = tempfile.mkdtemp(prefix="bench_")
         self.data_seed = data_seed
-        self.incs, self.masks = self.ks.load_values(data_seed)
-        self.history = reference.PlainHistory(self.ks, self.incs,
-                                              self.masks)
+        self.load = self.ks.load_values(data_seed)
+        self.history = reference.PlainHistory(self.ks, self.load)
         self.compiles = CompileWatch()
         self.watch = LogWatch()
         self.db = self.server = None
@@ -402,7 +406,7 @@ class Deployment:
         clock = None
         for lo in range(0, ks.n_keys, LOAD_TXN):
             clock = self.db.update_objects_static(clock, [
-                ks.load_update(k, self.incs, self.masks)
+                ks.load_update(k, self.load)
                 for k in range(lo, min(lo + LOAD_TXN, ks.n_keys))])
         say(f"loaded {ks.n_keys} keys in {time.monotonic() - t0:.1f} s")
         return clock
@@ -411,21 +415,23 @@ class Deployment:
         """Every read program the window can ask for, once, before it.
         A read is one program per multiset of store calls
         (device_plane.py ``fused_read``): with equal plane shapes, one
-        per (counter planes touched, set planes touched).  Ten uniform
-        keys reach every such combination, the rare ones once in
-        thousands of reads, and each first use costs 0.2-0.6 s even
-        from the persistent cache (my chip run, PR 24) — so traffic
-        alone leaves some for the window.  Here each combination is
-        read a few times over the wire, with keys drawn from the seed
-        (some are answered by the value cache, hence several tries);
-        then one batched read per bucket that a checkpoint's fold of
-        dirty keys can reach.  Each DC's planes keep programs of their
-        own, so it runs against every DC's server."""
+        per count of partitions touched in each type's planes (a
+        record's type reads its fields' planes).  Ten uniform keys
+        reach every such combination, the rare ones once in thousands
+        of reads, and each first use costs 0.2-0.6 s even from the
+        persistent cache (measured on one v5e chip) — so traffic alone leaves
+        some for the window.  Here each combination a read of the mix's
+        keys can touch is read a few times over the wire, with keys
+        drawn from the seed (some are answered by the value cache,
+        hence several tries); then one batched read a type per bucket
+        that a checkpoint's fold of dirty keys can reach.  Each DC's
+        planes keep programs of their own, so it runs against every
+        DC's server."""
         from antidote_tpu.pb.client import PbClient
 
         ks, t0 = self.ks, time.monotonic()
         rng = rng_for(self.data_seed, 3)
-        rows: dict = {"counter_pn": [], "set_aw": []}
+        rows: dict = {t: [] for t in dict.fromkeys(ks.layout)}
         for r in range(ks.keys_per_partition):
             rows[ks.type_of(r * ks.n_partitions)].append(r)
 
@@ -437,20 +443,23 @@ class Deployment:
                     for i in picked]
 
         n_parts = ks.n_partitions
+        mix = self.cell.mix
+        reach = max(mix.num_reads, mix.num_updates if mix.probers else 0)
         reads = 0
         with PbClient(port=port, timeout=CLIENT_TIMEOUT_S) as cl:
-            for c in range(n_parts + 1):
-                for s in range(n_parts + 1):
-                    for _ in range(PATTERN_TRIES if c + s else 0):
+            for counts in itertools.product(range(n_parts + 1),
+                                            repeat=len(rows)):
+                if not 0 < sum(counts) <= reach:
+                    continue
+                for _ in range(PATTERN_TRIES):
+                    keys = []
+                    for type_name, n in zip(rows, counts):
                         parts = rng.permutation(n_parts)
-                        keys = [k for p in parts[:c] for k in
-                                keys_of("counter_pn", int(p), 1)]
-                        parts = rng.permutation(n_parts)
-                        keys += [k for p in parts[:s] for k in
-                                 keys_of("set_aw", int(p), 1)]
-                        cl.read_objects_static(
-                            None, [ks.bound(k) for k in keys])
-                        reads += 1
+                        keys += [k for p in parts[:n] for k in
+                                 keys_of(type_name, int(p), 1)]
+                    cl.read_objects_static(
+                        None, [ks.bound(k) for k in keys])
+                    reads += 1
             for type_name in rows:
                 for bucket in BATCH_BUCKETS:
                     # the bucket below ends at a quarter; ask for five
@@ -608,18 +617,10 @@ class Deployment:
         operation once in every DC's planes (the origin appends it,
         every other DC applies it)."""
         out = trace.reduce_xplane(trace.xplane_of(sl["log_dir"]))
-        ks = self.ks
-        keys_read: dict = {}
-        ops: dict = {}
-        for r in records:
-            if not r["ok"] or not sl["t0"] <= r["t_done"] <= sl["t1"]:
-                continue
-            for k in r["read_keys"]:
-                t = ks.type_of(k)
-                keys_read[t] = keys_read.get(t, 0) + 1
-            for k, _op, _arg in r["updates"]:
-                t = ks.type_of(k)
-                ops[t] = ops.get(t, 0) + self.cell.dcs
+        keys_read, ops = trace.rows_of_work(
+            self.ks, [r for r in records
+                      if r["ok"] and sl["t0"] <= r["t_done"] <= sl["t1"]],
+            self.cell.dcs)
         out["needed_bytes"] = trace.needed_bytes(
             trace.plane_row_bytes(self.db), keys_read, ops)
         out["hbm_bytes_per_s"] = trace.peaks_for(

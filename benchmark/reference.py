@@ -1,12 +1,13 @@
 """The plain reference and the comparison that decides ``correct``.
 
-Python ints and sets, one history per written key, fed the operations
-that all the clients report as acknowledged, in the order of their
-commit times: any client may write any key, and under write-write
-certification two transactions that wrote one key never overlap, so the
-commit times order each key's writes.  It imports nothing of the
-program.  What the configuration's guarantees say a read must return is
-then exact: a Clock-SI snapshot read at local time ``s`` sees every
+Python ints, sets and dicts of field values, one history per written
+key, fed the operations that all the clients report as acknowledged, in
+the order of their commit times: any client may write any key, and
+under write-write certification two transactions that wrote one key
+never overlap, so the commit times order each key's writes (and a
+register's own last-writer order agrees with them).  It imports nothing
+of the program.  What the configuration's guarantees say a read must
+return is then exact: a Clock-SI snapshot read at local time ``s`` sees every
 write that committed at or before ``s`` and none after, and a session's
 transaction is answered at or after the clock the session sent.
 
@@ -22,23 +23,29 @@ from __future__ import annotations
 
 import bisect
 
-from benchmark.traffic import ELEMS, ORIGIN_DC
+from benchmark.traffic import ELEMS, ORIGIN_DC, field_value
 
 
 class PlainHistory:
-    """Every key's value over commit time."""
+    """Every key's value over commit time, from the load's values
+    (``traffic.Load``) on."""
 
-    def __init__(self, keyspace, incs, masks):
+    def __init__(self, keyspace, load):
         self.ks = keyspace
-        self._incs, self._masks = incs, masks
+        self.load = load
         #: key -> ([commit times], [state after that commit])
         self._hist: dict = {}
 
     def _loaded(self, key: int):
-        if self.ks.type_of(key) == "counter_pn":
-            return int(self._incs[key])
-        m = int(self._masks[key])
-        return frozenset(e for i, e in enumerate(ELEMS) if m >> i & 1)
+        t = self.ks.type_of(key)
+        if t == "counter_pn":
+            return int(self.load.incs[key])
+        if t == "set_aw":
+            m = int(self.load.masks[key])
+            return frozenset(e for i, e in enumerate(ELEMS) if m >> i & 1)
+        rec = self.ks.record(t)
+        return {f: field_value(self.load.seed, key, f[0], rec.field_bytes)
+                for f in rec.fields}
 
     def write(self, key: int, commit_time: int, op: str, arg) -> None:
         times, states = self._hist.setdefault(key, ([], []))
@@ -51,6 +58,15 @@ class PlainHistory:
             state = state | {arg}
         elif op == "remove":
             state = state - {arg}
+        elif op == "update":
+            # a record's fields, each assigned whole: a register's value
+            # is its last write committed, since two writers of one key
+            # that overlap cannot both commit
+            state = dict(state)
+            for f, (nested, value) in arg:
+                if nested != "assign":
+                    raise ValueError(f"unknown field operation {nested!r}")
+                state[f] = value
         else:
             raise ValueError(f"unknown operation {op!r}")
         if times and times[-1] == commit_time:
@@ -66,7 +82,9 @@ class PlainHistory:
     def at(self, key: int, snapshot_time: int | None = None):
         """The value a read of ``key`` must return at a snapshot (the
         newest value where none is given), in the form the wire gives
-        it: an int, or a sorted list of elements."""
+        it: an int; a sorted list of elements; for a record, a dict from
+        each ``(field, field type)`` to its value, a ``register_mv``'s
+        as a list of its one value."""
         hist = self._hist.get(key)
         if hist is None:
             state = self._loaded(key)
@@ -75,13 +93,17 @@ class PlainHistory:
             i = len(times) if snapshot_time is None \
                 else bisect.bisect_right(times, snapshot_time)
             state = states[i - 1] if i else self._loaded(key)
+        if isinstance(state, dict):
+            return {f: [v] if f[1] == "register_mv" else v
+                    for f, v in state.items()}
         return state if isinstance(state, int) else sorted(state)
 
 
 class StaleHistory(PlainHistory):
     """The control: the reference put in the program's place with one
     stated guarantee broken — an acknowledged write is not yet readable
-    at its commit clock; it shows one write of that key late."""
+    at its commit clock; it shows one write of that key late (of a
+    record, the one field that write assigned)."""
 
     def at(self, key: int, snapshot_time: int | None = None):
         hist = self._hist.get(key)
@@ -167,7 +189,7 @@ def control_numbers(history: PlainHistory, records: list,
     below its commit clock is what the control answers by construction,
     and the read-back at another DC asks the keys the origin's does."""
     local, remote = by_dc(records)
-    stale = StaleHistory(history.ks, history._incs, history._masks)
+    stale = StaleHistory(history.ks, history.load)
     stale._hist = history._hist
     _c, wrong, _f = wrong_reads(
         history, local,

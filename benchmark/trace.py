@@ -186,7 +186,9 @@ def reduce_xplane(path: str, top: int = 10) -> dict:
 def plane_row_bytes(db) -> dict:
     """Per plane type: the bytes of one key's row over all the plane's
     per-key tables, and of one operation's row in its op ring — from
-    the planes' own shapes."""
+    the planes' own shapes.  A map keeps no table of its own
+    (``MapPlane`` has no ``st``): its work is counted in its fields'
+    planes (``rows_of_work``)."""
     import jax
 
     lanes = db.node.config.device_lanes
@@ -209,6 +211,47 @@ def plane_row_bytes(db) -> dict:
             out[name] = {"key_row": key_row + lanes * op_row,
                          "op_row": op_row}
     return out
+
+
+#: a record type -> the plane that keeps which fields a record holds,
+#: read with them (mat/device_plane.py ``MapPlane``: a ``map_go``'s
+#: presence is a ``set_go`` plane; a ``map_rr`` field is visible by its
+#: own state)
+PRESENCE = {"map_go": "set_go"}
+
+
+def rows_of_work(ks, records: list, dcs: int = 1) -> tuple:
+    """(key rows read, operation rows appended) by plane type over the
+    answered ``records``, whatever kernel does the work: a flat key is
+    one row of its type's plane; a record is a row of each field's
+    plane and one of its presence plane; an update is one operation row
+    of its type's plane, of a record one of its field's plane for each
+    field it assigns; each operation once in every DC's planes."""
+    keys_read: dict = {}
+    ops: dict = {}
+
+    def add(into: dict, t: str, n: int) -> None:
+        into[t] = into.get(t, 0) + n
+
+    for r in records:
+        for k in r["read_keys"]:
+            t = ks.type_of(k)
+            rec = ks.record(t)
+            if rec is None:
+                add(keys_read, t, 1)
+                continue
+            for _f, field_type in rec.fields:
+                add(keys_read, field_type, 1)
+            if t in PRESENCE:
+                add(keys_read, PRESENCE[t], 1)
+        for k, _op, arg in r["updates"]:
+            t = ks.type_of(k)
+            if ks.record(t) is None:
+                add(ops, t, dcs)
+                continue
+            for (_f, field_type), _assign in arg:
+                add(ops, field_type, dcs)
+    return keys_read, ops
 
 
 def needed_bytes(rows: dict, keys_read: dict, ops_appended: dict) -> float:

@@ -2,26 +2,37 @@
 and one seeded transaction stream per client.
 
 A mix is a JSON file under ``benchmark/traffic/`` that names what
-basho_bench's ``antidote_pb`` driver names: closed-loop workers, weighted
-operations, keys per transaction, a key generator.  Nothing here knows a
-mix by name; a later PR adds a mix as a file.
+basho_bench's ``antidote_pb`` driver and YCSB's CoreWorkload name:
+closed-loop workers, weighted operations, keys per transaction, a key
+generator.  Nothing here knows a mix or a configuration by name; a
+later PR adds either as a file.
 
 Every client draws the keys it reads and the keys it updates through
-the mix's one key generator over the whole keyspace, as the source's
+the mix's one key generator over the whole keyspace, as the sources'
 workers do, so two writers can meet on a key and write-write
 certification can abort one of them; the client sends an aborted
 transaction again (client.py).
 
-The key generators are basho_bench's (``basho_bench_keygen.erl``), as
-the source spells them and with the source's constants: a mix names a
-kind and sets nothing: ``uniform_int``, and ``pareto_int``, whose draw
-is the key itself, so low keys are hot.
+The key generators are data: a mix names a kind and sets nothing, each
+kind taken with its source's constants.  basho_bench's
+(``basho_bench_keygen.erl``): ``uniform_int``, and ``pareto_int``, whose
+draw is the key itself, so low keys are hot.  YCSB's
+(``ScrambledZipfianGenerator``): ``ycsb_zipfian``, a Zipfian draw of
+constant 0.99 over the source's fixed ten billion items, scrambled onto
+the keyspace by a hash, so the hot keys lie anywhere.
+
+The keyspace's types are data too: the configuration's ``types`` lays
+them on rows by weight, and a type may be a record, a map of fields
+(``RECORD_TYPES``, ``FIELD_TYPES``): a YCSB record is a map of ten
+100-byte registers.
 """
 
 from __future__ import annotations
 
+import hashlib
 import json
 from dataclasses import dataclass, field
+from typing import NamedTuple
 
 import numpy as np
 
@@ -30,9 +41,6 @@ BUCKET = "bench"
 #: the DC every write originates at: the reference keeps its histories
 #: on this entry of the commit and snapshot clocks
 ORIGIN_DC = "dc1"
-#: a key's row within its partition decides its type: the fourth of
-#: every four rows is a set, so counters and sets stand 3:1 everywhere
-TYPE_PERIOD = 4
 
 
 def rng_for(seed: int, *stream: int) -> np.random.Generator:
@@ -77,47 +85,214 @@ def _pareto_int(n_keys: int):
     return draw
 
 
+#: ``ycsb_zipfian``'s constants are the source's own
+#: (``ScrambledZipfianGenerator``: ``ITEM_COUNT``, ``ZETAN``,
+#: ``USED_ZIPFIAN_CONSTANT``; ``Utils.fnvhash64``): a mix does not set
+#: them.  The Zipfian draw runs over ``ZipfianGenerator(0, ITEM_COUNT)``,
+#: whose item count is ``max - min + 1``
+ZIPF_ITEMS = 10_000_000_000 + 1
+ZIPF_THETA = 0.99
+ZIPF_ZETAN = 26.46902820178302
+FNV_OFFSET_BASIS_64 = 0xCBF29CE484222325
+FNV_PRIME_64 = 1099511628211
+
+
+def fnvhash64(x: np.ndarray) -> np.ndarray:
+    """YCSB's ``Utils.fnvhash64`` of each value: 64-bit FNV-1a over its
+    eight bytes, low first, then Java's ``Math.abs`` of the signed
+    result."""
+    h = np.full(x.shape, FNV_OFFSET_BASIS_64, dtype=np.uint64)
+    v = x.astype(np.uint64)
+    for _ in range(8):
+        h ^= v & np.uint64(0xFF)
+        h *= np.uint64(FNV_PRIME_64)
+        v >>= np.uint64(8)
+    return np.abs(h.view(np.int64))
+
+
+def _ycsb_zipfian(n_keys: int):
+    """The source's ``requestdistribution=zipfian``,
+    ``ScrambledZipfianGenerator(0, n_keys - 1)``: Gray et al.'s Zipfian
+    draw (SIGMOD 1994) of constant 0.99 over ``ZIPF_ITEMS`` with the
+    precomputed zeta, then ``fnvhash64(x) mod n_keys``.  The hottest
+    key takes ``1 / ZIPF_ZETAN`` = 3.78 % of the draws, the second
+    ``0.5 ** 0.99`` of that; which keys they are the hash decides.
+    Written from memory of YCSB's ``ScrambledZipfianGenerator``,
+    ``ZipfianGenerator`` and ``Utils.fnvhash64``: no copy of the source
+    is in this repository to hold it to."""
+    n, theta, zetan = ZIPF_ITEMS, ZIPF_THETA, ZIPF_ZETAN
+    zeta2 = 1.0 + 0.5 ** theta
+    alpha = 1.0 / (1.0 - theta)
+    eta = (1.0 - (2.0 / n) ** (1.0 - theta)) / (1.0 - zeta2 / zetan)
+
+    def draw(rng, k):
+        u = rng.random(size=k)
+        uz = u * zetan
+        x = np.trunc(n * (eta * u - eta + 1.0) ** alpha).astype(np.int64)
+        x = np.where(uz < 1.0, 0, np.where(uz < zeta2, 1, x))
+        return fnvhash64(x) % n_keys
+
+    return draw
+
+
 #: kind -> the draw over a keyspace of ``n_keys``: ``draw(rng, n)`` gives
 #: up to ``n`` keys.  A mix's entry is ``{"kind": <kind>}`` and no more
-KEY_GENERATORS = {"uniform_int": _uniform_int, "pareto_int": _pareto_int}
+KEY_GENERATORS = {"uniform_int": _uniform_int, "pareto_int": _pareto_int,
+                  "ycsb_zipfian": _ycsb_zipfian}
+
+#: the flat types a configuration's ``types`` may name: the harness
+#: loads, updates and judges these
+FLAT_TYPES = ("counter_pn", "set_aw")
+#: the record types, maps of fields: ``map_go`` keeps a field once
+#: written; ``map_rr`` resets a field on remove, so its fields' type
+#: has to have a reset (crdt/maps.py ``MapRR``, antidote_crdt_map_rr)
+RECORD_TYPES = ("map_go", "map_rr")
+#: a record's field types, with whether each has a reset: the harness
+#: assigns them whole values of ``field_bytes`` bytes
+FIELD_TYPES = {"register_lww": False, "register_mv": True}
+RECORD_KEYS = ("fields", "field_bytes", "weight")
+
+
+@dataclass(frozen=True)
+class Record:
+    """A record type: its ``fields``, ``(name, field type)`` pairs
+    named ``field0``, ``field1``, ... as YCSB names them, each holding
+    ``field_bytes`` bytes."""
+
+    fields: tuple
+    field_bytes: int
+
+
+def field_value(seed: int, key: int, field_name: str, n: int) -> bytes:
+    """The ``n`` bytes the load writes to a record's field: fixed by
+    ``--seed`` and the key."""
+    return hashlib.shake_128(
+        f"{int(seed)}/{key}/{field_name}".encode()).digest(n)
+
+
+class Load(NamedTuple):
+    """What the load writes, from ``--seed``: a counter's first
+    increment and a set's first elements (as a bit mask over ``ELEMS``)
+    by key; a record's fields from ``field_value``."""
+
+    incs: np.ndarray
+    masks: np.ndarray
+    seed: int
+
+
+def _refuse(path: str, what: str):
+    raise ValueError(f"{path}: {what}")
 
 
 @dataclass(frozen=True)
 class Keyspace:
     """Integer keys ``0 .. n_keys``; key ``k`` lives in partition
     ``k % n_partitions`` (txn/node.py ``partition_index``) at row
-    ``k // n_partitions``."""
+    ``k // n_partitions``, and its row decides its type: ``layout``
+    holds each type of the configuration's ``types`` as many times as
+    its weight, in the file's order, and row ``r`` takes
+    ``layout[r % len(layout)]``.  ``{"counter_pn": 3, "set_aw": 1}``
+    makes three rows of every four counters and the fourth a set."""
 
     n_partitions: int
     keys_per_partition: int
+    layout: tuple
+    #: ``(type, Record)`` of each record type in ``layout``
+    records: tuple = ()
+
+    @classmethod
+    def of(cls, n_partitions: int, keys_per_partition: int, types,
+           path: str = "types") -> "Keyspace":
+        """The keyspace a configuration's ``types`` describe; a type the
+        harness cannot load, update and judge is refused with ``path``.
+        A flat type's entry is its weight; a record type's is
+        ``{"fields": {<field type>: <count>}, "field_bytes": <n>}``, with
+        ``"weight"`` (default 1) where it shares the rows."""
+        if not isinstance(types, dict) or not types:
+            _refuse(path, f"types {types!r} names no type")
+        layout, records = [], []
+        for name, spec in types.items():
+            if name in FLAT_TYPES:
+                weight = spec
+            elif name in RECORD_TYPES:
+                records.append((name, cls._record(name, spec, path)))
+                weight = spec.get("weight", 1)
+            else:
+                _refuse(path, f"type {name!r} is none of "
+                              f"{FLAT_TYPES + RECORD_TYPES}")
+            if type(weight) is not int or weight < 1:
+                _refuse(path, f"type {name!r}: weight {weight!r} is not "
+                              "a whole number of rows")
+            layout += [name] * weight
+        return cls(n_partitions, keys_per_partition, tuple(layout),
+                   tuple(records))
+
+    @staticmethod
+    def _record(name: str, spec, path: str) -> Record:
+        if not isinstance(spec, dict) or set(spec) - set(RECORD_KEYS) \
+                or not {"fields", "field_bytes"} <= set(spec):
+            _refuse(path, f"record type {name!r}: {spec!r} is not "
+                          f"fields and field_bytes (and a weight)")
+        fields = spec["fields"]
+        size = spec["field_bytes"]
+        if not isinstance(fields, dict) or not fields:
+            _refuse(path, f"record type {name!r} has no fields")
+        if type(size) is not int or size < 1:
+            _refuse(path, f"record type {name!r}: field_bytes {size!r}")
+        named = []
+        for field_type, count in fields.items():
+            if field_type not in FIELD_TYPES:
+                _refuse(path, f"record type {name!r}: field type "
+                              f"{field_type!r} is none of "
+                              f"{tuple(FIELD_TYPES)}")
+            if name == "map_rr" and not FIELD_TYPES[field_type]:
+                _refuse(path, f"record type {name!r}: map_rr resets a "
+                              f"removed field and {field_type!r} has no "
+                              "reset, so every update of such a record "
+                              "is refused; a map_go keeps its fields")
+            if type(count) is not int or count < 1:
+                _refuse(path, f"record type {name!r}: {count!r} fields "
+                              f"of {field_type!r}")
+            named += [(f"field{len(named) + i}", field_type)
+                      for i in range(count)]
+        return Record(tuple(named), size)
 
     @property
     def n_keys(self) -> int:
         return self.n_partitions * self.keys_per_partition
 
     def type_of(self, key: int) -> str:
-        row = key // self.n_partitions
-        return "set_aw" if row % TYPE_PERIOD == TYPE_PERIOD - 1 \
-            else "counter_pn"
+        return self.layout[key // self.n_partitions % len(self.layout)]
+
+    def record(self, type_name: str) -> Record | None:
+        """The record type ``type_name``; ``None`` for a flat type."""
+        return next((r for t, r in self.records if t == type_name), None)
 
     def bound(self, key: int) -> tuple:
         return (key, self.type_of(key), BUCKET)
 
-    def load_values(self, seed: int):
-        """What the load writes to every key: a counter's first
-        increment, a set's first one to four elements (as a bit mask
-        over ``ELEMS``)."""
+    def load_values(self, seed: int) -> Load:
         rng = rng_for(seed, 0)
         incs = rng.integers(1, 1000, size=self.n_keys)
         masks = rng.integers(1, 16, size=self.n_keys)
-        return incs, masks
+        return Load(incs, masks, int(seed))
 
-    def load_update(self, key: int, incs, masks) -> tuple:
-        if self.type_of(key) == "counter_pn":
-            return (self.bound(key), "increment", int(incs[key]))
-        m = int(masks[key])
-        return (self.bound(key), "add_all",
-                [e for i, e in enumerate(ELEMS) if m >> i & 1])
+    def load_update(self, key: int, load: Load) -> tuple:
+        """The load's write of ``key``: a counter incremented, a set
+        given one to four elements, a record inserted as YCSB inserts
+        one, every field in one update."""
+        t = self.type_of(key)
+        if t == "counter_pn":
+            return (self.bound(key), "increment", int(load.incs[key]))
+        if t == "set_aw":
+            m = int(load.masks[key])
+            return (self.bound(key), "add_all",
+                    [e for i, e in enumerate(ELEMS) if m >> i & 1])
+        rec = self.record(t)
+        return (self.bound(key), "update", [
+            (f, ("assign", field_value(load.seed, key, f[0],
+                                       rec.field_bytes)))
+            for f in rec.fields])
 
 
 @dataclass(frozen=True)
@@ -199,11 +374,19 @@ class ClientStream:
         return list(out)
 
     def _update(self, key: int) -> tuple:
-        if self.ks.type_of(key) == "counter_pn":
+        t = self.ks.type_of(key)
+        if t == "counter_pn":
             op = "increment" if self.rng.random() < 0.7 else "decrement"
             return (key, op, int(self.rng.integers(1, 100)))
-        op = "add" if self.rng.random() < 0.5 else "remove"
-        return (key, op, ELEMS[int(self.rng.integers(len(ELEMS)))])
+        if t == "set_aw":
+            op = "add" if self.rng.random() < 0.5 else "remove"
+            return (key, op, ELEMS[int(self.rng.integers(len(ELEMS)))])
+        # a record: one field of its own, chosen alike, a fresh value
+        # (YCSB's update with writeallfields=false)
+        rec = self.ks.record(t)
+        f = rec.fields[int(self.rng.integers(len(rec.fields)))]
+        return (key, "update",
+                [(f, ("assign", self.rng.bytes(rec.field_bytes)))])
 
     def next(self, kind: str | None = None) -> Txn:
         """The next transaction; ``kind`` forces one of the mix's

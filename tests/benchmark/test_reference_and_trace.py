@@ -11,12 +11,12 @@ from benchmark.traffic import Keyspace
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
 RECORDED = os.path.join(ROOT, "benchmark", "data", "v5e_micro.xplane.pb")
-KS = Keyspace(2, 64)
+KS = Keyspace.of(2, 64, {"counter_pn": 3, "set_aw": 1})
 
 
 def history():
-    incs, masks = KS.load_values(1)
-    return reference.PlainHistory(KS, incs, masks), incs
+    load = KS.load_values(1)
+    return reference.PlainHistory(KS, load), load.incs
 
 
 def test_a_read_sees_every_write_at_or_before_its_snapshot():

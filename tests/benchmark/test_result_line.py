@@ -126,7 +126,7 @@ def checked(cell, reading):
     from benchmark import reference
 
     ks = cell.keyspace
-    history = reference.PlainHistory(ks, *ks.load_values(1))
+    history = reference.PlainHistory(ks, ks.load_values(1))
     for r in reading["records"]:  # answers equal to the reference's
         r["values"] = [history.at(k, r["snapshot_time"])
                        for k in r["read_keys"]]

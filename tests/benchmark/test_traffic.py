@@ -13,9 +13,11 @@ import pytest
 
 from benchmark.traffic import ELEMS, ClientStream, Keyspace, Mix
 
-KS = Keyspace(n_partitions=4, keys_per_partition=1024)
+#: the accepted cells' types (benchmark/configs/bb1dc.json)
+TYPES = {"counter_pn": 3, "set_aw": 1}
+KS = Keyspace.of(n_partitions=4, keys_per_partition=1024, types=TYPES)
 #: the accepted cells' keyspace (benchmark/configs/bb1dc.json)
-BB1DC = Keyspace(n_partitions=4, keys_per_partition=131072)
+BB1DC = Keyspace.of(n_partitions=4, keys_per_partition=131072, types=TYPES)
 N_CLIENTS = 16
 PARETO = {"kind": "pareto_int"}
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
@@ -202,7 +204,7 @@ def test_a_malformed_key_generator_is_refused_with_the_files_path(
 
 def test_a_mean_under_one_key_is_refused_when_the_cell_is_loaded(tmp_path):
     with pytest.raises(ValueError, match="under one key"):
-        stream(tmp_path, ks=Keyspace(1, 4), key_generator=PARETO)
+        stream(tmp_path, ks=Keyspace.of(1, 4, TYPES), key_generator=PARETO)
     # and before set-up: where the mix and the keyspace first meet
     from bench_tiny import tiny_tree
 
@@ -299,5 +301,5 @@ def test_the_load_is_fixed_by_the_seed():
     a, b = KS.load_values(2**31 + 5), KS.load_values(2**31 + 5)
     assert (a[0] == b[0]).all() and (a[1] == b[1]).all()
     assert (KS.load_values(1)[0] != a[0]).any()
-    bound, op, arg = KS.load_update(3 * KS.n_partitions, *a)
+    bound, op, arg = KS.load_update(3 * KS.n_partitions, a)
     assert bound[1] == "set_aw" and op == "add_all" and 1 <= len(arg) <= 4
