@@ -494,12 +494,13 @@ class DurableLog:
         in flight leads — holds the window open (``group_us``) only
         while OTHER committers are waiting, writes the whole staged
         queue as one batch and fsyncs once; everyone whose ticket the
-        new watermark covers returns.  Returns ``{led, records}`` for
-        the caller's instrumentation."""
+        new watermark covers returns.  Returns ``{led, slept,
+        records}`` for the caller's instrumentation: ``slept`` says the
+        waiter slept on a drain in flight at least once."""
         if self._group is None:
             return {"led": False, "records": 0}
         deadline = time.monotonic() + timeout
-        info = {"led": False, "records": 0}
+        info = {"led": False, "slept": False, "records": 0}
         while True:
             lead = False
             with self._lock:
@@ -515,6 +516,7 @@ class DurableLog:
                             raise TimeoutError(
                                 "durability ticket never covered "
                                 "(drain leader wedged?)")
+                        info["slept"] = True
                         self._lock.wait(min(remaining, 0.1))
                     if self._synced_end >= ticket:
                         return info

@@ -313,17 +313,27 @@ class PartitionLog:
         the window, and the per-committer wait feeds the
         ``log_sync_wait`` histogram and, as a wait span of the same
         name, the request's tree: the committer sleeps here, so the
-        commit spans above it do not count the disk as their own."""
+        commit spans above it do not count the disk as their own.
+
+        Each redemption counts by what it met
+        (``antidote_log_sync_redeemed_total{outcome}``): ``covered``,
+        the watermark already covered the ticket; ``led``, this
+        committer drained; ``woken``, it slept until another
+        committer's drain covered it."""
         if ticket is None:
             return
         t0 = time.perf_counter()
         with tracer.wait_span("log_sync_wait", "oplog", txid=txid,
                               partition=self.partition) as span:
             info = self.log.wait_durable(ticket)
+            outcome = ("led" if info["led"] else
+                       "woken" if info["slept"] else "covered")
             if span is not None:
                 span.args.update(led=info["led"],
-                                 records=info["records"])
+                                 records=info["records"],
+                                 covered=outcome == "covered")
         stats.registry.log_sync_wait.observe(time.perf_counter() - t0)
+        stats.registry.log_sync_redeemed.inc(outcome=outcome)
 
     def append_abort(self, dc, txid) -> LogRecord:
         rec = abort_record(self._next_op_id(dc), txid)

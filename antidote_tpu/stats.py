@@ -605,6 +605,13 @@ class Registry:
             "Commit-path wait from durability-ticket issue (partition "
             "lock already released) to the synced watermark covering "
             "it", buckets=lat_buckets)
+        self.log_sync_redeemed = Counter(
+            "antidote_log_sync_redeemed_total",
+            "Durability tickets redeemed by what the committer met "
+            "(covered = the synced watermark already covered it, no "
+            "sleep; led = the committer drained; woken = it slept "
+            "until another committer's drain covered it)",
+            labels=("outcome",))
         self.log_staged_records = Gauge(
             "antidote_log_staged_records",
             "Log records currently staged (framed, not yet written "
@@ -1074,7 +1081,8 @@ class Registry:
                 self.read_waiters_per_dispatch,
                 self.log_fsyncs, self.log_group_records,
                 self.log_group_drains, self.log_group_size,
-                self.log_sync_wait, self.log_staged_records,
+                self.log_sync_wait, self.log_sync_redeemed,
+                self.log_staged_records,
                 self.log_records_per_fsync,
                 self.log_retained_bytes, self.log_file_bytes,
                 self.log_truncated_bytes, self.ckpt_writes,

@@ -146,7 +146,7 @@ def _fresh_txid_suffix() -> str:
     return f"{_TXID_PREFIX}{next(_TXID_SEQ):x}"
 
 
-def _fan_out(pairs, fn, spec=None):
+def _fan_out(pairs, fn, spec=None, local=None):
     """Run ``fn(p, pm)`` for every 2PC participant, overlapping the
     REMOTE ones (their cost is a fabric round trip whose wait releases
     the GIL — the reference broadcasts prepare/commit and collects
@@ -167,7 +167,12 @@ def _fan_out(pairs, fn, spec=None):
     mask the others' prepare times); a whole-batch refusal
     (resize parking, an older peer) self-heals per participant on the
     synchronous path.  Otherwise remote calls fall back to a thread
-    per participant."""
+    per participant.
+
+    ``local(pairs) -> results``, when given, runs the LOCAL
+    participants as one step, in their order, in place of ``fn`` on
+    each; the remote ones are started before it and collected after
+    it, as without it."""
     import threading as _threading
 
     remote = [(i, p, pm) for i, (p, pm) in enumerate(pairs)
@@ -175,6 +180,27 @@ def _fan_out(pairs, fn, spec=None):
     results: list = [None] * len(pairs)
     errs: list = []
     handles = []
+
+    def run(i, p, pm):
+        try:
+            results[i] = fn(p, pm)
+        except BaseException as e:  # noqa: BLE001 — re-raised below
+            errs.append(e)
+
+    def run_local():
+        mine = [(i, p, pm) for i, (p, pm) in enumerate(pairs)
+                if not getattr(pm, "deferred_stage", False)]
+        if local is None:
+            for i, p, pm in mine:
+                run(i, p, pm)
+            return
+        try:
+            got = local([(p, pm) for _i, p, pm in mine])
+            for (i, _p, _pm), r in zip(mine, got):
+                results[i] = r
+        except BaseException as e:  # noqa: BLE001 — re-raised below
+            errs.append(e)
+
     if spec is not None and remote:
         link = remote[0][2].link
         if hasattr(link, "finish_many") and all(
@@ -197,12 +223,7 @@ def _fan_out(pairs, fn, spec=None):
                 link.abandon([h for _o, _c, h in handles])
                 raise
     if handles:
-        for i, (p, pm) in enumerate(pairs):
-            if not getattr(pm, "deferred_stage", False):
-                try:
-                    results[i] = fn(p, pm)
-                except BaseException as e:  # noqa: BLE001 — below
-                    errs.append(e)
+        run_local()
 
         def heal(i):
             # moved/draining mid-round (cross-node handoff): the
@@ -251,29 +272,55 @@ def _fan_out(pairs, fn, spec=None):
         if errs:
             raise errs[0]
         return results
-    if len(remote) <= 1:
+    if len(remote) <= 1 and local is None:
         for i, (p, pm) in enumerate(pairs):
             results[i] = fn(p, pm)
         return results
-
-    def run(i, p, pm):
-        try:
-            results[i] = fn(p, pm)
-        except BaseException as e:  # noqa: BLE001 — re-raised below
-            errs.append(e)
 
     threads = [_threading.Thread(target=run, args=(i, p, pm))
                for i, p, pm in remote]
     for t in threads:
         t.start()
-    for i, (p, pm) in enumerate(pairs):
-        if not getattr(pm, "deferred_stage", False):
-            run(i, p, pm)
+    run_local()
     for t in threads:
         t.join()
     if errs:
         raise errs[0]
     return results
+
+
+def _commit_local(pms, txid, commit_time: int, snapshot_vc: VC,
+                  certified: bool) -> list:
+    """The 2PC commit on the local participants: every partition's
+    locked half (``commit(..., redeem=False)``: commit record appended,
+    durability ticket taken, effects published, in one hold of its
+    lock) in partition order, then every unlocked half (``redeem``:
+    ticket redeemed, deferred publish).  A committer that staged all
+    its records before it sleeps on any fsync leaves each log's drain
+    leader company to group, and finds its later tickets already
+    covered by other committers' drains.  Returns only once every
+    ticket is covered.
+
+    A locked half that fails stops the staging there; the partitions
+    staged before it are still redeemed (their records are in the log,
+    and their deferred publishes must run).  A redemption that fails
+    does not skip the others'.  The first error then propagates."""
+    begun, errs = [], []
+    for pm in pms:
+        try:
+            begun.append((pm, pm.commit(txid, commit_time, snapshot_vc,
+                                        certified, redeem=False)))
+        except BaseException as e:  # noqa: BLE001 — re-raised below
+            errs.append(e)
+            break
+    for pm, staged in begun:
+        try:
+            pm.redeem(staged)
+        except BaseException as e:  # noqa: BLE001 — re-raised below
+            errs.append(e)
+    if errs:
+        raise errs[0]
+    return [None] * len(pms)
 
 
 class Coordinator:
@@ -790,7 +837,10 @@ class Coordinator:
                                                  certified=certify),
                         spec=lambda _p, _pm: (
                             "commit", (tx.txid, ct, tx.snapshot_vc),
-                            {"certified": certify}))
+                            {"certified": certify}),
+                        local=lambda local: _commit_local(
+                            [pm for _p, pm in local], tx.txid, ct,
+                            tx.snapshot_vc, certify))
             except Exception as e:
                 # post-decision failure: some partitions may hold a
                 # durable commit record — reporting an abort here would

@@ -288,6 +288,22 @@ def _warm_cheap(state) -> bool:
     return _approx_size(state, _WARM_STATE_MAX) <= _WARM_STATE_MAX
 
 
+class StagedCommit(NamedTuple):
+    """What ``PartitionManager.commit(..., redeem=False)`` leaves for
+    :meth:`PartitionManager.redeem`: the commit's arguments, the
+    GC horizon its publish uses, its durability ticket (None when
+    there is nothing to wait on) and whether its publish waits for
+    the ticket."""
+
+    txid: Any
+    commit_time: int
+    snapshot_vc: VC
+    certified: bool
+    stable: Optional[VC]
+    ticket: Optional[int]
+    defer: bool
+
+
 class PartitionManager:
     def __init__(self, partition: int, dc_id, log: PartitionLog,
                  clock: HybridClock, read_wait_timeout: float = 5.0,
@@ -910,7 +926,8 @@ class PartitionManager:
             else None
 
     def commit(self, txid, commit_time: int, snapshot_vc: VC,
-               certified: bool = True) -> None:
+               certified: bool = True, redeem: bool = True
+               ) -> Optional[StagedCommit]:
         """Log the commit (fsync per config), publish the effects to the
         materializer store, release prepared state and wake blocked
         readers (reference commit handler src/clocksi_vnode.erl:499-531,
@@ -925,7 +942,15 @@ class PartitionManager:
         degenerating to its disk's fsync rate.  The commit is acked
         (this method returns) only once the ticket is covered; the
         legacy path (``Config.log_group=False``) keeps the inline
-        fsync under the lock exactly as before."""
+        fsync under the lock exactly as before.
+
+        ``redeem=False`` runs the locked half alone — the record
+        appended, its ticket taken, the effects published (or the
+        deferred publish counted) in one hold — and returns what
+        :meth:`redeem` needs for the unlocked half.  A 2PC commit over
+        several local partitions stages every partition so before it
+        redeems any ticket.  The caller MUST redeem what it was given,
+        also when a later partition fails: the record is in the log."""
         stable = self._stable_for_gc()  # before the lock (see __init__)
         with self._locked:
             # BEFORE the record is in the log: a publish waits for
@@ -951,6 +976,17 @@ class PartitionManager:
                 self._publish_commit_locked(txid, commit_time,
                                             snapshot_vc, certified,
                                             stable)
+        staged = StagedCommit(txid, commit_time, snapshot_vc, certified,
+                              stable, ticket, defer)
+        if not redeem:
+            return staged
+        self.redeem(staged)
+        return None
+
+    def redeem(self, staged: StagedCommit) -> None:
+        """The unlocked half of :meth:`commit`: redeem the durability
+        ticket, run the deferred publish, maybe checkpoint.  Returns
+        only once the ticket is covered (the commit's ack point)."""
         # durability gate OUTSIDE the partition lock: readers and other
         # committers proceed while this committer waits out the shared
         # fsync (its effects are already published — group commit
@@ -965,14 +1001,15 @@ class PartitionManager:
         # prepared entry behind would wedge every conflicting reader
         # forever; the error still propagates (the ack fails).
         try:
-            self.log.wait_durable(ticket, txid=txid)
+            self.log.wait_durable(staged.ticket, txid=staged.txid)
         finally:
-            if defer:
+            if staged.defer:
                 with self._lock:
                     try:
-                        self._publish_commit_locked(txid, commit_time,
-                                                    snapshot_vc,
-                                                    certified, stable)
+                        self._publish_commit_locked(
+                            staged.txid, staged.commit_time,
+                            staged.snapshot_vc, staged.certified,
+                            staged.stable)
                     finally:
                         self._defer_unpublished -= 1
                         self._lock.notify_all()

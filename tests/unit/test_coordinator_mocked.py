@@ -77,9 +77,16 @@ class FakePartition:
                 raise RuntimeError("mocked vnode crash")
         return self.prepare_time
 
-    def commit(self, txid, commit_time, snapshot_vc, certified=True):
+    def commit(self, txid, commit_time, snapshot_vc, certified=True,
+               redeem=True):
         self.calls.append(("commit", txid, commit_time))
         self.staged.pop(txid, None)
+        if not redeem:    # the 2PC commit redeems after every partition
+            return txid
+        self.redeem(txid)
+
+    def redeem(self, txid):
+        self.calls.append(("redeem", txid))
 
     def single_commit(self, txid, snapshot_vc, certify=True):
         self.prepare(txid, snapshot_vc, certify)
@@ -235,7 +242,7 @@ class TestTwoPhaseCommit:
         k0 = _keys_on(node, 0)[0]
         k3 = _keys_on(node, 3)[0]
 
-        def failing_commit(txid, ct, snap):
+        def failing_commit(txid, ct, snap, certified=True, redeem=True):
             raise OSError("disk full")
 
         node.partitions[3].commit = failing_commit
@@ -251,6 +258,8 @@ class TestTwoPhaseCommit:
                 max(node.partitions[0].prepare_time,
                     node.partitions[3].prepare_time)) \
             in node.partitions[0].calls
+        # and its durability ticket was still redeemed
+        assert ("redeem", tx.txid) in node.partitions[0].calls
         for pm in node.partitions:
             assert ("abort", tx.txid) not in pm.calls
 

@@ -54,6 +54,23 @@ def test_a_rehearsed_window_gives_the_whole_account(tmp_path, capsys):
     assert "flushes: clean" in text
 
 
+def test_a_rehearsed_durable_window_counts_the_redemptions(capsys):
+    """Under ``sync_log`` every commit redeems a ticket a partition it
+    wrote; the account says what each met, beside the fsyncs."""
+    probe.main(["--workload", "bb1dc-sync.update90-uniform", "--seed",
+                "2140000533", "--seconds", "2", "--partitions", "2",
+                "--keys-per-partition", "1024", "--clients", "3"])
+    text = capsys.readouterr().out
+    acc = json.loads(text.strip().splitlines()[-1][len("PROBE "):])
+    assert acc["failed"] == 0 and acc["answered"] > 0
+    lg = acc["log"]
+    assert lg["fsyncs"] > 0 and lg["records"] >= lg["fsyncs"]
+    assert set(lg) == {"fsyncs", "records", "covered", "led", "woken"}
+    assert lg["led"] > 0
+    assert min(lg["covered"], lg["woken"]) >= 0
+    assert "tickets redeemed: covered" in text
+
+
 def test_a_tree_without_the_account_is_refused(tmp_path, monkeypatch):
     """A parent before the account is read with the tool as it was: the
     tool says so instead of reading half of it."""

@@ -22,7 +22,11 @@ account (``antidote_tpu/obs/host.py``) says of the window:
 - the cyclic collector's passes and pauses by generation;
 - the device flushes by how long they held the partition lock
   (``antidote_device_flush_split_total{outcome}``) and the waits for a
-  flush out of the lock, where the program counts them.
+  flush out of the lock, where the program counts them;
+- the durable log's fsyncs, the records they covered, and the
+  durability tickets redeemed by what they met
+  (``antidote_log_sync_redeemed_total{outcome}``), where the program
+  counts them.
 
 The same accounts are the registry's families at ``/metrics``
 (``process_cpu_seconds_total``, ``antidote_thread_cpu_seconds_total``,
@@ -72,6 +76,7 @@ def run(args) -> dict:
     dep = harness.Deployment(cell, args.seed)
     snaps: list = []
     flushes: list = []
+    logs: list = []
     try:
         dep.open()
         # the window's bounds are its two counter readings
@@ -82,6 +87,7 @@ def run(args) -> dict:
         def counters_and_account():
             snaps.append(host.account())
             flushes.append(flush_account())
+            logs.append(log_account())
             return counters()
 
         dep.counters = counters_and_account
@@ -93,6 +99,7 @@ def run(args) -> dict:
     account = host.difference(snaps[-2], snaps[-1])
     account["flushes"] = {k: flushes[-1][k] - flushes[-2][k]
                           for k in flushes[-1]}
+    account["log"] = {k: logs[-1][k] - logs[-2][k] for k in logs[-1]}
     answered = sum(reduced["detail"]["answered"].values())
     e2e = reduced["end_to_end"]
 
@@ -133,6 +140,22 @@ def flush_account() -> dict:
     return out
 
 
+def log_account() -> dict:
+    """The durable log's fsyncs, the records they covered and the
+    tickets redeemed by outcome, so far; a program that does not count
+    redemptions gives the first two alone."""
+    from antidote_tpu import stats
+
+    reg = stats.registry
+    out = {"fsyncs": reg.log_fsyncs.value(),
+           "records": reg.log_group_records.value()}
+    redeemed = getattr(reg, "log_sync_redeemed", None)
+    if redeemed is not None:
+        for outcome in ("covered", "led", "woken"):
+            out[outcome] = redeemed.value(outcome=outcome)
+    return out
+
+
 def report(acc: dict) -> str:
     w = acc["length_s"]
     missed = (f" (limits not kept: {', '.join(acc['not_kept'])})"
@@ -164,6 +187,14 @@ def report(acc: dict) -> str:
             f"{fl['overflow']:.0f}, whole {fl['whole']:.0f} (clean "
             f"{100 * fl['clean'] / n if n else 0:.1f} % of {n:.0f}); "
             f"waits on a flush out of the lock {fl['inflight_waits']:.0f}"))
+    lg = acc.get("log")
+    if lg and lg["fsyncs"]:
+        line = (f"durable log: {lg['fsyncs']:.0f} fsyncs, "
+                f"{lg['records'] / lg['fsyncs']:.2f} records an fsync")
+        if "covered" in lg:
+            line += (f"; tickets redeemed: covered {lg['covered']:.0f}, "
+                     f"led {lg['led']:.0f}, woken {lg['woken']:.0f}")
+        lines.insert(-3, line)
     total = acc["python_threads_cpu_s"] or 1.0
     for kind, s in sorted(acc["thread_cpu_s"].items(),
                           key=lambda kv: -kv[1]):
